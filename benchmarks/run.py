@@ -113,6 +113,9 @@ def main() -> None:
         os.environ["BENCH_QUICK"] = "1"
     global QUICK_RUN
     QUICK_RUN = args.quick
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
 
     from benchmarks import (batched_engine, chaos_serve, claim21,
                             decode_fused, fig3_lub_sweep, fleet_compile,

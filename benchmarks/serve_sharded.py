@@ -18,11 +18,15 @@ streams are asserted **bitwise identical** to the sharded run before any
 row is emitted (the GSPMD partitioning and the padded-bucket prefill must
 not change a single token).
 
-The sweep itself runs in a subprocess with
-``--xla_force_host_platform_device_count=8`` (the tests' dry-run isolation
-rule: the parent process keeps seeing one device); rows come back over
-stdout and land in ``artifacts/bench/serve_sharded_{offline,online}.json``,
-folded into ``BENCH_10.json`` by ``benchmarks.run``.
+This sweep is a CPU rehearsal of the mesh path, not a device
+measurement: it runs in a subprocess pinned to the CPU backend
+(``JAX_PLATFORMS=cpu``) with ``--xla_force_host_platform_device_count=8``
+virtual devices, so it never contends for an accelerator the parent (or
+anything else on the host) holds, and its times are host-CPU times. Rows
+come back over stdout and land in
+``artifacts/bench/serve_sharded_{offline,online}.json``, folded into
+``BENCH_10.json`` by ``benchmarks.run``. The mesh path on real chips is
+``python chip_smoke.py --chips 4``.
 """
 from __future__ import annotations
 
@@ -154,7 +158,7 @@ def _worker() -> None:
 
 
 def run() -> None:
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.serve_sharded", "--worker"],

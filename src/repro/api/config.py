@@ -41,7 +41,7 @@ DEFAULT_ENVELOPE_CACHE = 64
 DEFAULT_FLEET = True
 
 # kind -> (in_bits, spec kwargs, lookup_bits). Widths are chosen so every
-# coefficient fits int32 and the one-hot LUT contraction is exact in fp32.
+# coefficient fits int32 (the largest default coefficient is below 2^22).
 DEFAULTS: dict[str, tuple[int, dict, int]] = {
     "exp2neg": (12, {"out_bits": 13}, 6),
     "recip": (12, {}, 6),

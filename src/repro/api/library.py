@@ -359,7 +359,7 @@ class InterpLibrary:
         byte-stable programs for v1 artifacts — while the presence of any
         segmented slot switches the call onto the generalized ROM walk
         (``library_walk``): per-function walk rows plus per-leaf datapath
-        rows as kernel operands, same one-hot gathers and fixed-point
+        rows as kernel operands, same ROM selects and fixed-point
         tail, bit-identical per slot to the specialized paths.
         """
         if any(m.seg_depth for m in self.metas):

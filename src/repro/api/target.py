@@ -192,6 +192,6 @@ class PallasTpuTarget:
 
     def decoder_estimate(self, n_leaves: int, depth: int) -> AreaDelay:
         """VMEM is already counted via ``rows`` (the packed table rides in
-        the slot); the marginal cost is the extra one-hot gather contraction,
+        the slot); the marginal cost is the extra segment-index ROM select,
         whose width scales with the 2^depth cell count."""
         return AreaDelay(area=0.0, delay=float(depth))

@@ -17,6 +17,11 @@ which map onto the TPU as: L/U rows padded to 3N and resident in VMEM
 offset e with always-in-bounds dynamic slices plus per-lane validity masks.
 O(N^2) work with unit-stride vector loads and no scatters — the TPU-native
 replacement for the paper's PyPy scalar loops.
+
+These kernels run in interpret mode only: Mosaic rejects the (1, 3n) row
+blocks (the (8, 128) tiling rule) and the per-offset lane
+``dynamic_slice``s, so ``kernels/dspace/ops.py`` refuses to dispatch them
+on a TPU (tests/kernels/test_tpu_compile.py keeps the rejection pinned).
 """
 from __future__ import annotations
 
@@ -25,6 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 TILE = 128
 BIG = 3.4e38  # python float: becomes an inline constant, not a captured array
@@ -97,7 +104,7 @@ def _envelope_kernel_fleet(l_ref, u_ref, me_ref, mo_ref, be_ref, bo_ref, *,
 
 
 def envelopes_parity(l_arr: jax.Array, u_arr: jax.Array,
-                     interpret: bool = True) -> tuple[jax.Array, ...]:
+                     interpret: bool | None = None) -> tuple[jax.Array, ...]:
     """Returns (m_even, m_odd, M_even, M_odd), each (N,) float32.
 
     Entries without any valid pair hold +/-3.4e38 sentinels.
@@ -115,13 +122,13 @@ def envelopes_parity(l_arr: jax.Array, u_arr: jax.Array,
         in_specs=[pl.BlockSpec((1, 3 * n), lambda i: (0, 0))] * 2,
         out_specs=[out_spec] * 4,
         out_shape=[shape] * 4,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(l2, u2)
     return me[0], mo[0], be[0], bo[0]
 
 
 def envelopes_parity_fleet(l_arr: jax.Array, u_arr: jax.Array,
-                           interpret: bool = True) -> tuple[jax.Array, ...]:
+                           interpret: bool | None = None) -> tuple[jax.Array, ...]:
     """Fleet-stacked variant: ``(P, B, n)`` probe stacks in, four
     ``(P, B, n)`` parity envelopes out of ONE ``pallas_call`` with grid
     ``(probe, region, n // TILE)``.
@@ -143,12 +150,12 @@ def envelopes_parity_fleet(l_arr: jax.Array, u_arr: jax.Array,
         in_specs=[pl.BlockSpec((1, 1, 3 * n), lambda q, r, i: (q, r, 0))] * 2,
         out_specs=[out_spec] * 4,
         out_shape=[shape] * 4,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(l2, u2)
 
 
 def envelopes_parity_batched(l_arr: jax.Array, u_arr: jax.Array,
-                             interpret: bool = True) -> tuple[jax.Array, ...]:
+                             interpret: bool | None = None) -> tuple[jax.Array, ...]:
     """Batched-region variant: ``(B, n)`` rows in, four ``(B, n)`` parity
     envelopes out of ONE ``pallas_call`` with grid ``(B, n // TILE)``.
 
@@ -168,5 +175,5 @@ def envelopes_parity_batched(l_arr: jax.Array, u_arr: jax.Array,
         in_specs=[pl.BlockSpec((1, 3 * n), lambda r, i: (r, 0))] * 2,
         out_specs=[out_spec] * 4,
         out_shape=[shape] * 4,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(l2, u2)

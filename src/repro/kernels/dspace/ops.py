@@ -7,8 +7,13 @@ implementations freely (``impl="pallas"`` in benchmarks).
 ``region_envelopes_device`` is the batched-engine entry point: one
 ``pallas_call`` over a grid of regions plus an on-device parity merge,
 Eqn 9 feasibility, and the Eqn 7-8 a-interval divided-difference reduction —
-the whole §II front half for all ``2^R`` regions in a single compiled
-program (compiled on TPU, interpret elsewhere).
+the whole §II front half for all ``2^R`` regions in a single program.
+
+The envelope kernels do not compile for a TPU yet (see
+``kernels/dspace/kernel.py``): every entry point here runs them in
+interpret mode and raises on a TPU backend rather than fall back to the
+interpreter there. The default ``batched`` engine is host numpy and is
+unaffected.
 """
 from __future__ import annotations
 
@@ -18,10 +23,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.dspace.kernel import (BIG, TILE, envelopes_parity,
                                          envelopes_parity_batched,
                                          envelopes_parity_fleet)
 from repro.kernels.dspace.ref import envelopes_parity_ref
+
+def _interpret_only(interpret: bool | None) -> bool:
+    """The dspace kernels' mode: interpret, never on a TPU backend."""
+    if not interpret_mode(interpret):
+        raise NotImplementedError(
+            "the dspace envelope kernels do not compile for a TPU (Mosaic "
+            "rejects their (1, 3n) row blocks and lane dynamic_slices); use "
+            "engine='batched' (host numpy) for exploration on a TPU host")
+    return True
+
 
 _PAD_L = -(2.0 ** 30)  # pad-lane sentinels: see envelopes_pallas docstring
 _PAD_U = 2.0 ** 30
@@ -41,7 +57,8 @@ def _interleave(me, mo, be, bo, n: int):
     return big_m, m
 
 
-def envelopes_pallas(L: np.ndarray, U: np.ndarray, interpret: bool = True
+def envelopes_pallas(L: np.ndarray, U: np.ndarray,
+                     interpret: bool | None = None
                      ) -> tuple[np.ndarray, np.ndarray]:
     """Drop-in replacement for core.designspace.envelopes via the kernel.
 
@@ -49,6 +66,7 @@ def envelopes_pallas(L: np.ndarray, U: np.ndarray, interpret: bool = True
     (y) operand of a kept-lane pair, so L[pad] = -2^30 / U[pad] = +2^30 make
     every pad-touching divided difference lose its min/max reduction.
     """
+    interpret = _interpret_only(interpret)
     n = len(L)
     if n < 2:
         return np.full(1, -np.inf), np.full(1, np.inf)
@@ -123,8 +141,7 @@ def region_envelopes_device(L: np.ndarray, U: np.ndarray,
                             ) -> tuple[np.ndarray, ...]:
     """§II front half for ALL regions: (M, m, a_lo, a_hi, feas9) arrays.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpret elsewhere
-    (the CPU Pallas lowering only exists in interpret mode). M/m come back
+    Interpret mode only (raises on a TPU backend). M/m come back
     float64 in the core layout (index 0 placeholder, sentinels -> inf);
     envelope arithmetic itself runs in float32 — see DESIGN.md §9.
     """
@@ -132,8 +149,7 @@ def region_envelopes_device(L: np.ndarray, U: np.ndarray,
     U = np.asarray(U)
     b, n = L.shape
     assert n >= 3, "trivial region widths are handled by the numpy engine"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _interpret_only(interpret)
     n_pad = max(-(-n // TILE) * TILE, TILE)
     lp = np.full((b, n_pad), _PAD_L)
     up = np.full((b, n_pad), _PAD_U)
@@ -175,33 +191,21 @@ def _fleet_impl(l3: jax.Array, u3: jax.Array, *, n_real: int,
             a_lo.reshape(p, b), a_hi.reshape(p, b), feas9.reshape(p, b))
 
 
-def _resolve_shard_map():
-    try:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-    except ImportError:  # pragma: no cover - moved out of experimental
-        return getattr(jax, "shard_map", None)
-
-
 @functools.lru_cache(maxsize=32)
 def _fleet_fn(shards: int, n_real: int, interpret: bool):
     """Compiled fleet front half for a device count (1 = single program;
-    > 1 = shard_map over the probe axis). When shard_map is unavailable the
-    single vectorized program stands in — the batched grid already covers
-    every (probe, region) row, it just runs on one device."""
+    > 1 = ``jax.shard_map`` over the probe axis)."""
     impl = functools.partial(_fleet_impl, n_real=n_real, interpret=interpret)
-    shard_map = _resolve_shard_map() if shards > 1 else None
-    if shards <= 1 or shard_map is None:
+    if shards <= 1:
         return jax.jit(impl)
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:shards]), ("probe",))
     spec = P("probe")
-    # check_rep=False: the replication checker cannot see through
+    # check_vma=False: the replication checker cannot see through
     # pallas_call; every output is honestly probe-sharded anyway
-    return jax.jit(shard_map(impl, mesh=mesh, in_specs=(spec, spec),
-                             out_specs=(spec,) * 5, check_rep=False))
+    return jax.jit(jax.shard_map(impl, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=(spec,) * 5, check_vma=False))
 
 
 def fleet_region_envelopes_device(L3, U3, shards: int | None = None,
@@ -222,8 +226,7 @@ def fleet_region_envelopes_device(L3, U3, shards: int | None = None,
     U3 = np.asarray(U3)
     p, b, n = L3.shape
     assert n >= 3, "trivial region widths are handled by the numpy engine"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _interpret_only(interpret)
     shards = 1 if shards is None else max(1, min(int(shards),
                                                  len(jax.devices())))
     n_pad = max(-(-n // TILE) * TILE, TILE)

@@ -4,7 +4,7 @@ The structural answer to the §Perf Cell-B memory term: the score block,
 mask, exponential, running renormalization and PV product live entirely in
 VMEM — HBM sees only Q/K/V reads and one output write per tile. Both
 transcendentals come from the paper's certified tables (the same `_lut`
-one-hot-MXU datapath as kernels/softmax), so the fused kernel *is* the
+SMEM ROM-select datapath as kernels/softmax), so the fused kernel *is* the
 generated hardware of Fig. 1 dropped into the attention hot loop.
 
 Tiling: grid (N heads-batch, Sq/BLOCK_Q); per step the q tile (BLOCK_Q, D)
@@ -21,8 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.interp.kernel import _lut_rom
-from repro.kernels.softmax.kernel import _lut
+from repro.kernels import interpret_mode
+from repro.kernels.interp.kernel import (_lut, _lut_rom, flat_rom, pow2,
+                                         rom_spec)
 
 BLOCK_Q = 128
 BLOCK_K = 128
@@ -42,7 +43,7 @@ def _table_exp_neg(t, lut, meta):
     codes = jnp.clip(jnp.round(frac * (1 << eb)).astype(jnp.int32),
                      0, (1 << eb) - 1)
     tab = lut(codes).astype(jnp.float32)
-    return tab * (2.0 ** -meta["out_bits"]) * jnp.exp2(-n)
+    return tab * (2.0 ** -meta["out_bits"]) * pow2(-n)
 
 
 def _table_recip(s, lut, meta):
@@ -55,7 +56,7 @@ def _table_recip(s, lut, meta):
     rcodes = jnp.clip(jax.lax.shift_right_logical(mant + half, 23 - rb),
                       0, (1 << rb) - 1)
     rtab = lut(rcodes).astype(jnp.float32)
-    return rtab * (2.0 ** -(rb + 1)) * jnp.exp2(-expo.astype(jnp.float32))
+    return rtab * (2.0 ** -(rb + 1)) * pow2(-expo)
 
 
 def _flash_loop(q, k_ref, v_ref, out_ref, lut_exp, lut_recip, exp_meta: dict,
@@ -75,9 +76,9 @@ def _flash_loop(q, k_ref, v_ref, out_ref, lut_exp, lut_recip, exp_meta: dict,
 
     def body(j, carry):
         m_i, l_i, acc = carry
-        kb = jax.lax.dynamic_slice_in_dim(k_ref[0], j * block_k, block_k
-                                          ).astype(jnp.float32)  # (BK, D)
-        vb = jax.lax.dynamic_slice_in_dim(v_ref[0], j * block_k, block_k)
+        start = pl.multiple_of(j * block_k, block_k)
+        kb = k_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)  # (BK, D)
+        vb = v_ref[0, pl.ds(start, block_k), :]
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (BQ, BK)
         s = mask_chunk(j, s)
@@ -127,8 +128,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, ecoef_ref, rcoef_ref, out_ref, *,
         return (j * block_k) <= (qi * bq + bq - 1)
 
     _flash_loop(q, k_ref, v_ref, out_ref,
-                lambda c: _lut(c, ecoef_ref[...], **exp_meta["eval"]),
-                lambda c: _lut(c, rcoef_ref[...], **recip_meta["eval"]),
+                lambda c: _lut(c, ecoef_ref, **exp_meta["eval"]),
+                lambda c: _lut(c, rcoef_ref, **recip_meta["eval"]),
                 exp_meta, recip_meta, block_k, mask_chunk, chunk_live)
 
 
@@ -144,23 +145,24 @@ def _flash_lib_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, rom_ref,
     ``kpos_ref`` carry *absolute* positions per row: decode against a
     partially-filled KV cache masks dead slots (pos < 0), applies causality
     by position (not buffer index), and honors a sliding window — the same
-    contract as ``models.attention._mask``.
+    contract as ``models.attention._mask``. Query positions arrive as a
+    (BQ, 1) column and key positions as one (1, BK) row per kv chunk, the
+    layouts the (8, 128) tiling rule accepts.
     """
     q = q_ref[0].astype(jnp.float32) * scale  # (BQ, D)
-    qp = qpos_ref[0]  # (BQ,) int32, -1 = padded query row
-    rom = rom_ref[...]
+    qp = qpos_ref[0]  # (BQ, 1) int32, -1 = padded query row
     imax = jnp.iinfo(jnp.int32).max
 
     def kpos(j):
-        return jax.lax.dynamic_slice_in_dim(kpos_ref[0], j * block_k, block_k)
+        return kpos_ref[0, pl.ds(j, 1), :]  # (1, BK)
 
     def mask_chunk(j, s):
         kpb = kpos(j)
-        ok = (kpb >= 0)[None, :]
+        ok = kpb >= 0
         if causal:
-            ok = jnp.logical_and(ok, qp[:, None] >= kpb[None, :])
+            ok = jnp.logical_and(ok, qp >= kpb)
         if window is not None:
-            ok = jnp.logical_and(ok, qp[:, None] - kpb[None, :] < window)
+            ok = jnp.logical_and(ok, qp - kpb < window)
         return jnp.where(ok, s, NEG)
 
     def chunk_live(j):
@@ -168,7 +170,7 @@ def _flash_lib_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, rom_ref,
         # B1 by grid index can't see cache occupancy): dead if every slot is
         # empty, entirely in the causal future, or outside the window
         kpb = kpos(j)
-        need = jnp.any(kpb >= 0)
+        need = jnp.max(kpb) >= 0
         if causal:
             need = jnp.logical_and(
                 need, jnp.min(jnp.where(kpb < 0, imax, kpb)) <= jnp.max(qp))
@@ -178,9 +180,9 @@ def _flash_lib_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, rom_ref,
         return need
 
     _flash_loop(q, k_ref, v_ref, out_ref,
-                lambda c: _lut_rom(c, rom, fid=exp_meta["fid"], r_max=r_max,
-                                   **exp_meta["eval"]),
-                lambda c: _lut_rom(c, rom, fid=recip_meta["fid"],
+                lambda c: _lut_rom(c, rom_ref, fid=exp_meta["fid"],
+                                   r_max=r_max, **exp_meta["eval"]),
+                lambda c: _lut_rom(c, rom_ref, fid=recip_meta["fid"],
                                    r_max=r_max, **recip_meta["eval"]),
                 exp_meta, recip_meta, block_k, mask_chunk, chunk_live)
 
@@ -190,7 +192,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     exp_meta: dict, recip_meta: dict, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (N, Sq, D); k, v: (N, Sk, D). N = batch x heads (GQA expansion is
     the caller's contract). Sq % block_q == 0, Sk % block_k == 0."""
     n, sq, d = q.shape
@@ -200,7 +202,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                exp_meta=exp_meta, recip_meta=recip_meta,
                                block_k=block_k)
-    ne, nr = exp_coeffs.shape[0], recip_coeffs.shape[0]
     return pl.pallas_call(
         kernel,
         grid=(n, sq // block_q),
@@ -208,13 +209,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((ne, 3), lambda i, j: (0, 0)),
-            pl.BlockSpec((nr, 3), lambda i, j: (0, 0)),
+            rom_spec(),
+            rom_spec(),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, d), v.dtype),
-        interpret=interpret,
-    )(q, k, v, exp_coeffs, recip_coeffs)
+        interpret=interpret_mode(interpret),
+    )(q, k, v, flat_rom(exp_coeffs), flat_rom(recip_coeffs))
 
 
 def flash_attention_lib(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -223,14 +224,14 @@ def flash_attention_lib(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True, window: int | None = None,
                         scale: float | None = None, kv_group: int = 1,
                         block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool | None = None) -> jax.Array:
     """q: (N, Sq, D); k: (N // kv_group, Sk, Dk); v: (N // kv_group, Sk,
     Dv); q_pos: (N, Sq) int32 (-1 = padded row); kv_pos: (N // kv_group,
-    Sk) int32 (-1 = dead cache slot); rom: the library ROM flattened to
-    (F * r_max, 3). N = batch x query heads; GQA is expressed through
-    ``kv_group`` = heads per kv head — query program i reads kv stripe
-    ``i // kv_group`` via the BlockSpec index map, so grouped K/V are
-    never materialized per query head. Sq % block_q == 0, Sk % block_k == 0.
+    Sk) int32 (-1 = dead cache slot); rom: the library ROM as (F * r_max,
+    3). N = batch x query heads; GQA is expressed through ``kv_group`` =
+    heads per kv head — query program i reads kv stripe ``i // kv_group``
+    via the BlockSpec index map, so grouped K/V are never materialized per
+    query head. Sq % block_q == 0, Sk % block_k == 0.
     """
     n, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[-1]
@@ -240,11 +241,11 @@ def flash_attention_lib(q: jax.Array, k: jax.Array, v: jax.Array,
     assert q_pos.shape == (n, sq) and kv_pos.shape == (n // g, sk), \
         (q_pos.shape, kv_pos.shape)
     scale = (d ** -0.5) if scale is None else scale
+    nk = sk // block_k
     kernel = functools.partial(_flash_lib_kernel, causal=causal,
                                window=window, scale=scale, r_max=r_max,
                                exp_meta=exp_meta, recip_meta=recip_meta,
                                block_k=block_k)
-    n_rows = rom.shape[0]
     return pl.pallas_call(
         kernel,
         grid=(n, sq // block_q),
@@ -252,11 +253,12 @@ def flash_attention_lib(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, sk, k.shape[-1]), lambda i, j: (i // g, 0, 0)),
             pl.BlockSpec((1, sk, dv), lambda i, j: (i // g, 0, 0)),
-            pl.BlockSpec((1, block_q), lambda i, j: (i, j)),
-            pl.BlockSpec((1, sk), lambda i, j: (i // g, 0)),
-            pl.BlockSpec((n_rows, 3), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, nk, block_k), lambda i, j: (i // g, 0, 0)),
+            rom_spec(),
         ],
         out_specs=pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, dv), v.dtype),
-        interpret=interpret,
-    )(q, k, v, q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32), rom)
+        interpret=interpret_mode(interpret),
+    )(q, k, v, q_pos.astype(jnp.int32).reshape(n, sq, 1),
+      kv_pos.astype(jnp.int32).reshape(n // g, nk, block_k), flat_rom(rom))

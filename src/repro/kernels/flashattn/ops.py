@@ -4,12 +4,14 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.table import TableDesign
 from repro.kernels.flashattn.kernel import flash_attention, flash_attention_lib
 from repro.kernels.flashattn.ref import (flash_attention_lib_ref,
                                          flash_attention_ref)
 from repro.kernels.softmax.ops import _meta, lib_meta
+from repro.launch.sharding import local_map, rule_spec
 from repro.api import get_table
 
 
@@ -40,51 +42,65 @@ def attention_fused_library(q: jax.Array, k: jax.Array, v: jax.Array,
     materializing the expansion); Dk may differ from Dv (MLA).
     ``use_kernel=None`` picks the Pallas kernel on TPU and the unchunked
     jnp oracle elsewhere; the kernel path pads Sq/Sk to tile multiples
-    with masked (-1) positions.
+    with masked (-1) positions, and on a mesh runs per device on its batch
+    rows and kv-head groups (``local_map``).
     """
     b, sq, h, d = q.shape
     sk, kvh, dv = k.shape[1], k.shape[2], v.shape[-1]
     assert h % kvh == 0, (h, kvh)
-    g = h // kvh
     em, rm = lib_meta(library, "exp2neg"), lib_meta(library, "recip")
     if q_pos is None:
         q_pos = jnp.broadcast_to(jnp.arange(sq, dtype=jnp.int32), (b, sq))
     if kv_pos is None:
         kv_pos = jnp.broadcast_to(jnp.arange(sk, dtype=jnp.int32), (b, sk))
-    qn = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kn = k.transpose(0, 2, 1, 3).reshape(b * kvh, sk, k.shape[-1])
-    vn = v.transpose(0, 2, 1, 3).reshape(b * kvh, sk, dv)
-    qp = jnp.repeat(q_pos.astype(jnp.int32), h, axis=0)  # (B*H, Sq)
-    kp = jnp.repeat(kv_pos.astype(jnp.int32), kvh, axis=0)
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         # the unchunked oracle takes one kv stripe per query row
-        if g > 1:
-            kn = jnp.repeat(kn.reshape(b, kvh, sk, -1), g, axis=1
-                            ).reshape(b * h, sk, -1)
-            vn = jnp.repeat(vn.reshape(b, kvh, sk, -1), g, axis=1
-                            ).reshape(b * h, sk, -1)
-            kp = jnp.repeat(kp, g, axis=0)
+        g = h // kvh
+        qn = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        kn = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1
+                        ).reshape(b * h, sk, -1)
+        vn = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1
+                        ).reshape(b * h, sk, dv)
+        qp = jnp.repeat(q_pos.astype(jnp.int32), h, axis=0)  # (B*H, Sq)
+        kp = jnp.repeat(kv_pos.astype(jnp.int32), h, axis=0)
         o = flash_attention_lib_ref(qn, kn, vn, qp, kp, library.coeffs, em,
                                     rm, causal=causal, window=window,
                                     scale=scale)
         return o.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
-    pad_q, pad_k = (-sq) % 8, (-sk) % 8
-    if pad_q:
-        qn = jnp.pad(qn, ((0, 0), (0, pad_q), (0, 0)))
-        qp = jnp.pad(qp, ((0, 0), (0, pad_q)), constant_values=-1)
-    if pad_k:
-        kn = jnp.pad(kn, ((0, 0), (0, pad_k), (0, 0)))
-        vn = jnp.pad(vn, ((0, 0), (0, pad_k), (0, 0)))
-        kp = jnp.pad(kp, ((0, 0), (0, pad_k)), constant_values=-1)
-    interpret = (jax.default_backend() != "tpu") if interpret is None else interpret
-    o = flash_attention_lib(
-        qn, kn, vn, qp, kp, library.coeffs.reshape(-1, 3), em, rm,
-        r_max=library.coeffs.shape[1], causal=causal, window=window,
-        scale=scale, kv_group=g, block_q=_block(sq + pad_q),
-        block_k=_block(sk + pad_k), interpret=interpret)
-    return o[:, :sq].reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+
+    def kernel(q, k, v, q_pos, kv_pos, coeffs):
+        b, _, h, _ = q.shape
+        kvh = k.shape[2]
+        qn = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        kn = k.transpose(0, 2, 1, 3).reshape(b * kvh, sk, k.shape[-1])
+        vn = v.transpose(0, 2, 1, 3).reshape(b * kvh, sk, dv)
+        qp = jnp.repeat(q_pos.astype(jnp.int32), h, axis=0)  # (B*H, Sq)
+        kp = jnp.repeat(kv_pos.astype(jnp.int32), kvh, axis=0)
+        pad_q, pad_k = (-sq) % 8, (-sk) % 8
+        if pad_q:
+            qn = jnp.pad(qn, ((0, 0), (0, pad_q), (0, 0)))
+            qp = jnp.pad(qp, ((0, 0), (0, pad_q)), constant_values=-1)
+        if pad_k:
+            kn = jnp.pad(kn, ((0, 0), (0, pad_k), (0, 0)))
+            vn = jnp.pad(vn, ((0, 0), (0, pad_k), (0, 0)))
+            kp = jnp.pad(kp, ((0, 0), (0, pad_k)), constant_values=-1)
+        o = flash_attention_lib(
+            qn, kn, vn, qp, kp, coeffs.reshape(-1, 3), em, rm,
+            r_max=coeffs.shape[1], causal=causal, window=window,
+            scale=scale, kv_group=h // kvh, block_q=_block(sq + pad_q),
+            block_k=_block(sk + pad_k), interpret=interpret)
+        return o[:, :sq].reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+
+    # on a mesh: batch rows and whole kv-head groups are independent, so
+    # query heads shard exactly where their kv heads do
+    kv_spec = rule_spec(("batch", None, "kv_heads", None), k.shape)
+    bax, hax = (kv_spec[0], kv_spec[2]) if len(kv_spec) == 4 else (None, None)
+    q_spec, pos_spec = P(bax, None, hax, None), P(bax, None)
+    return local_map(kernel, (q, k, v, q_pos, kv_pos, library.coeffs),
+                     (q_spec, q_spec, q_spec, pos_spec, pos_spec, P()),
+                     q_spec)
 
 
 def attention_fused(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -109,7 +125,6 @@ def attention_fused(q: jax.Array, k: jax.Array, v: jax.Array, *,
         o = flash_attention_ref(qn, kn, vn, exp_design, recip_design,
                                 causal=causal, scale=scale)
     else:
-        interpret = (jax.default_backend() != "tpu") if interpret is None else interpret
         ec = exp_design.device_coeffs(checked=True)
         rc = recip_design.device_coeffs(checked=True)
         o = flash_attention(qn, kn, vn, ec, rc, _meta(exp_design),

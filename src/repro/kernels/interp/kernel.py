@@ -2,15 +2,19 @@
 
 This is the TPU rendering of the paper's Figure-1 datapath:
 
-  * the coefficient ROM lives in VMEM (2^R x 3 int32 — at most a few KiB);
-  * the LUT read is a one-hot contraction (a ROM mux tree maps naturally onto
-    the MXU: ``onehot(r) @ coeffs``), not a serial gather;
+  * the coefficient ROM lives in SMEM, flattened row-major to int32
+    scalars (``(2^R, 3)`` -> ``(3 * 2^R,)``; a whole library is a few KiB);
+  * the LUT read is a select-accumulate over the ROM rows on the VPU — the
+    software form of the ROM mux tree: for each row, every lane whose index
+    matches takes that row's scalars. It is exact for any int32 coefficient
+    (no MXU pass, no float rounding) and needs no reshape of the (8, 128)
+    tile, both of which Mosaic rejects for the one-hot contraction;
   * the squarer operates on the truncated ``x[W-1:i]`` exactly like the RTL;
   * evaluation is int32 throughout, final arithmetic shift by k.
 
 Tiling: input codes are reshaped to (rows, 128) lanes; the grid walks row
 blocks of 8, so each program touches an (8, 128) VREG-aligned tile while the
-full table stays resident in VMEM.
+whole ROM stays resident in SMEM.
 """
 from __future__ import annotations
 
@@ -19,248 +23,234 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 BLOCK_ROWS = 8
 LANES = 128
+_UNROLL_ROWS = 64  # ROM reads over at most this many rows unroll fully
 
 
-def _interp_kernel(codes_ref, coeffs_ref, out_ref, *, eval_bits: int, k: int,
-                   sq_trunc: int, lin_trunc: int, n_regions: int, degree: int):
-    codes = codes_ref[...]  # (BLOCK_ROWS, LANES) int32
-    coeffs = coeffs_ref[...]  # (n_regions, 3) int32
-    r = jax.lax.shift_right_logical(codes, eval_bits)
-    x = jnp.bitwise_and(codes, (1 << eval_bits) - 1)
-    # one-hot LUT read: (8*128, n_regions) @ (n_regions, 3) on the MXU
-    flat_r = r.reshape(-1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (flat_r.shape[0], n_regions), 1)
-    onehot = (flat_r[:, None] == iota).astype(jnp.int32)
-    sel = jax.lax.dot_general(
-        onehot, coeffs, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    ).reshape(codes.shape + (3,))
-    out_ref[...] = poly_tail(sel, x, k=k, sq_trunc=sq_trunc,
-                             lin_trunc=lin_trunc, degree=degree)
+def pow2(n: jax.Array) -> jax.Array:
+    """``2.0 ** n`` (float32) for integer-valued float ``n``, built from the
+    exponent bits: exact on every backend — ``exp2`` is not (XLA's CPU
+    lowering is off by ulps at integer arguments and flushes 2^-126 to 0).
+    ``n`` is clamped to the normal range [-126, 127]. The table glue's
+    power-of-two scalings all go through this one helper, in the kernels
+    and in their jnp oracles alike."""
+    e = jnp.clip(n.astype(jnp.int32) + 127, 1, 254)
+    return jax.lax.bitcast_convert_type(jax.lax.shift_left(e, 23),
+                                        jnp.float32)
 
 
-def poly_tail(sel: jax.Array, x: jax.Array, *, k: int, sq_trunc: int,
-              lin_trunc: int, degree: int) -> jax.Array:
+def rom_spec() -> pl.BlockSpec:
+    """BlockSpec of a ROM-side operand: the whole flattened int32 array,
+    resident in SMEM for every grid step."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def flat_rom(rom: jax.Array) -> jax.Array:
+    """A ROM-side operand as the kernels take it: 1-D int32, row-major."""
+    return rom.reshape(-1).astype(jnp.int32)
+
+
+def rom_read(idx: jax.Array, rom_ref, *, n: int, width: int = 3,
+             base: int = 0) -> tuple[jax.Array, ...]:
+    """In-kernel ROM read: ``width`` int32 arrays shaped like ``idx`` with
+    ``out[c][e] = rom_ref[base + idx[e] * width + c]`` for ``idx[e]`` in
+    ``[0, n)`` and 0 elsewhere — the one-hot contraction's semantics, as a
+    VPU select over the ``n`` rows (scalar SMEM loads broadcast per row)."""
+
+    def row(r, acc):
+        hit = idx == r
+        off = base + r * width
+        return tuple(jnp.where(hit, rom_ref[off + c], a)
+                     for c, a in enumerate(acc))
+
+    acc = (jnp.zeros(idx.shape, jnp.int32),) * width
+    if n <= _UNROLL_ROWS:  # one table slot: static rows, static offsets
+        for r in range(n):
+            acc = row(r, acc)
+        return acc
+    # a whole library: a loop over groups of rows (Mosaic unrolls a loop
+    # fully or not at all, so the group is unrolled by hand)
+    u = 8 if n % 8 == 0 else 1
+
+    def group(i, acc):
+        for t in range(u):
+            acc = row(i * u + t, acc)
+        return acc
+
+    return jax.lax.fori_loop(0, n // u, group, acc)
+
+
+def poly_tail(c0: jax.Array, c1: jax.Array, c2: jax.Array, x: jax.Array, *,
+              k, sq_trunc, lin_trunc, degree) -> jax.Array:
     """The Figure-1 fixed-point tail shared by every in-kernel table read:
     truncated square/linear terms, int32 Horner accumulate, arithmetic
-    shift by k. One copy — the per-table (`_lut`) and library-ROM
-    (`_lut_rom`) gathers feed the same datapath and cannot drift."""
+    shift by k. The datapath constants are Python ints (one static table)
+    or per-element int32 arrays (library reads, where each element names
+    its own function or leaf); one copy serves both, so no two reads can
+    drift."""
     xs = jax.lax.shift_left(jax.lax.shift_right_logical(x, sq_trunc), sq_trunc)
-    xl = jax.lax.shift_left(jax.lax.shift_right_logical(x, lin_trunc), lin_trunc)
-    acc = sel[..., 1] * xl + sel[..., 2]
-    if degree == 2:
-        acc = acc + sel[..., 0] * xs * xs
+    xl = jax.lax.shift_left(jax.lax.shift_right_logical(x, lin_trunc),
+                            lin_trunc)
+    acc = c1 * xl + c2
+    if not isinstance(degree, int):
+        xs = jnp.where(degree == 2, xs, 0)  # degree-1 rows skip the squarer
+        acc = acc + c0 * xs * xs
+    elif degree == 2:
+        acc = acc + c0 * xs * xs
     return jax.lax.shift_right_arithmetic(acc, k)
 
 
-def _lut(codes: jax.Array, coeffs: jax.Array, *, eval_bits: int, k: int,
-         sq_trunc: int, lin_trunc: int, degree: int) -> jax.Array:
-    """One-hot table evaluation on int32 codes (any 2-D shape): region
-    index from the code's top bits, a one-hot MXU contraction over the
-    coefficient rows, then the shared fixed-point tail."""
-    n_regions = coeffs.shape[0]
+def _lut(codes: jax.Array, rom_ref, *, eval_bits: int, k: int,
+         sq_trunc: int, lin_trunc: int, degree: int, row0: int = 0,
+         n_rows: int | None = None) -> jax.Array:
+    """Uniform table evaluation on int32 codes (any 2-D shape): region
+    index from the code's top bits, a ROM read of rows ``[row0, row0 +
+    n_rows)`` (default: the whole ROM), then the shared fixed-point
+    tail."""
+    if n_rows is None:
+        n_rows = rom_ref.shape[0] // 3 - row0
     r = jax.lax.shift_right_logical(codes, eval_bits)
     x = jnp.bitwise_and(codes, (1 << eval_bits) - 1)
-    flat_r = r.reshape(-1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (flat_r.shape[0], n_regions), 1)
-    onehot = (flat_r[:, None] == iota).astype(jnp.int32)
-    sel = jax.lax.dot_general(onehot, coeffs, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32
-                              ).reshape(codes.shape + (3,))
-    return poly_tail(sel, x, k=k, sq_trunc=sq_trunc, lin_trunc=lin_trunc,
-                     degree=degree)
+    c0, c1, c2 = rom_read(r, rom_ref, n=n_rows, base=3 * row0)
+    return poly_tail(c0, c1, c2, x, k=k, sq_trunc=sq_trunc,
+                     lin_trunc=lin_trunc, degree=degree)
 
 
-def _lut_seg(codes: jax.Array, rows: jax.Array, *, seg: tuple) -> jax.Array:
-    """Non-uniform (ROM v2) slot evaluation: segment-index gather, then the
+def _lut_seg(codes: jax.Array, rom_ref, *, row0: int, seg: tuple) -> jax.Array:
+    """Non-uniform (ROM v2) slot evaluation: segment-index read, then the
     per-leaf fixed-point tail.
 
-    ``rows`` is one function's slot of a v2 library ROM: rows ``[0, S)``
-    hold the S per-leaf coefficient triples and rows ``[S, S + ceil(2^D/3))``
-    the segment-index table packed 3 int32 entries per row. ``seg`` is the
-    static ``FuncMeta.seg_spec()`` tuple ``(in_bits, depth, n_leaves,
+    The slot starts at ROM row ``row0``: rows ``[0, S)`` hold the S
+    per-leaf coefficient triples and rows ``[S, S + ceil(2^D/3))`` the
+    segment-index table packed 3 int32 entries per row — row-major, so
+    entry ``c`` is flat word ``3 * (row0 + S) + c``. ``seg`` is the static
+    ``FuncMeta.seg_spec()`` tuple ``(in_bits, depth, n_leaves,
     leaf_meta)`` with one ``(eval_bits, k, sq_trunc, lin_trunc, degree)``
     row per leaf — this is the address decoder the paper's uniform layout
     avoids: the top D input bits index a 2^D table that names the leaf, and
     the leaf supplies both the coefficient row and the datapath constants.
-    Both gathers are one-hot MXU contractions like the uniform kernels; the
-    shifts take per-element amounts (vector shifts), exactly as in
-    ``_library_kernel``. Degenerate segmentations (every leaf at depth R)
-    reproduce the uniform ``_lut`` bitwise: the cell index equals the
-    region index, every leaf row carries the uniform datapath constants,
-    and the int32 accumulate is order-insensitive (wrapping adds commute).
+    The leaf constants are scalar literals selected per element (a
+    materialized meta matrix would be a captured constant, which Pallas
+    rejects). Degenerate segmentations (every leaf at depth R) reproduce
+    the uniform ``_lut`` bitwise: the cell index equals the region index,
+    every leaf row carries the uniform datapath constants, and the int32
+    accumulate is order-insensitive (wrapping adds commute).
     """
     in_bits, depth, n_leaves, leaf_meta = seg
-    n_cells = 1 << depth
-    n_table_rows = (n_cells + 2) // 3
-    # unpack the segment-index table: (T, 3) rows -> flat 2^D leaf ids
-    table = jax.lax.slice_in_dim(rows, n_leaves, n_leaves + n_table_rows)
-    seg_tab = jax.lax.slice_in_dim(table.reshape(-1), 0, n_cells)
-    flat_cell = jax.lax.shift_right_logical(
-        codes, in_bits - depth).reshape(-1)
-    n = flat_cell.shape[0]
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (n, n_cells), 1)
-    onehot_c = (flat_cell[:, None] == iota_c).astype(jnp.int32)
-    leaf = jax.lax.dot_general(
-        onehot_c, seg_tab[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)[:, 0]
-    # per-leaf datapath constants: unrolled scalar-literal selection off the
-    # leaf one-hot. A materialized (S, 5) meta matrix would be a captured
-    # constant — which Pallas rejects — while scalar literals fold into the
-    # jaxpr; S is static and small, so the unroll is a handful of vector
-    # multiply-adds.
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (n, n_leaves), 1)
-    onehot_l = (leaf[:, None] == iota_l).astype(jnp.int32)
+    cell = jax.lax.shift_right_logical(codes, in_bits - depth)
+    (leaf,) = rom_read(cell, rom_ref, n=1 << depth, width=1,
+                       base=3 * (row0 + n_leaves))
 
     def pick(j: int) -> jax.Array:
-        acc = onehot_l[:, 0] * leaf_meta[0][j]
-        for i in range(1, n_leaves):
-            acc = acc + onehot_l[:, i] * leaf_meta[i][j]
-        return acc.reshape(codes.shape)
+        acc = jnp.zeros(codes.shape, jnp.int32)
+        for i in range(n_leaves):
+            acc = jnp.where(leaf == i, leaf_meta[i][j], acc)
+        return acc
 
     eb, k, sq, lin, deg = (pick(j) for j in range(5))
-    one = jnp.int32(1)
-    x = jnp.bitwise_and(codes, jax.lax.shift_left(one, eb) - 1)
-    sel = jax.lax.dot_general(
-        onehot_l, jax.lax.slice_in_dim(rows, 0, n_leaves),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32).reshape(codes.shape + (3,))
-    xs = jax.lax.shift_left(jax.lax.shift_right_logical(x, sq), sq)
-    xl = jax.lax.shift_left(jax.lax.shift_right_logical(x, lin), lin)
-    xs = jnp.where(deg == 2, xs, 0)
-    acc = sel[..., 0] * xs * xs + sel[..., 1] * xl + sel[..., 2]
-    return jax.lax.shift_right_arithmetic(acc, k)
+    x = jnp.bitwise_and(codes, jax.lax.shift_left(jnp.int32(1), eb) - 1)
+    c0, c1, c2 = rom_read(leaf, rom_ref, n=n_leaves, base=3 * row0)
+    return poly_tail(c0, c1, c2, x, k=k, sq_trunc=sq, lin_trunc=lin,
+                     degree=deg)
 
 
-def _lut_rom(codes: jax.Array, rom: jax.Array, *, fid: int, r_max: int,
+def _lut_rom(codes: jax.Array, rom_ref, *, fid: int, r_max: int,
              eval_bits: int, k: int, sq_trunc: int, lin_trunc: int,
              degree: int, seg: tuple | None = None) -> jax.Array:
     """Table evaluation against a library ROM (static function id).
 
-    ``rom`` is an :class:`repro.api.InterpLibrary` coefficient ROM flattened
-    to ``(F * r_max, 3)`` int32; rows ``[fid * r_max, fid * r_max + 2^R)``
-    hold the function's ``packed_coeffs`` and the padding rows are zero.
-    ``fid``/``r_max`` are static, so the function's rows are a *static
-    slice* of the ROM operand and the read is exactly ``_lut`` on them —
-    bit-identical to the per-table kernels, and the one-hot contraction
-    pays r_max columns, not F·r_max. The consuming fused kernels (softmax /
-    rmsnorm / flashattn) thread the whole library ROM as ONE operand and
-    evaluate each transcendental in-registers instead of launching a
-    standalone table kernel between ops.
+    ``rom_ref`` is an :class:`repro.api.InterpLibrary` coefficient ROM
+    flattened to ``(F * r_max * 3,)`` int32; rows ``[fid * r_max, fid *
+    r_max + 2^R)`` hold the function's ``packed_coeffs`` and the padding
+    rows are zero. ``fid``/``r_max`` are static, so the read selects over
+    the function's r_max rows only, and is exactly ``_lut`` on them —
+    bit-identical to the per-table kernels. The consuming fused kernels
+    (softmax / rmsnorm / flashattn) thread the whole library ROM as ONE
+    operand and evaluate each transcendental in-registers instead of
+    launching a standalone table kernel between ops.
 
     ``seg`` (a static ``FuncMeta.seg_spec()`` tuple) switches the slot to
     the non-uniform ROM-v2 datapath: the per-call eval_bits/k/truncation
     scalars are ignored (each leaf carries its own) and the rows decode
     through :func:`_lut_seg` instead of :func:`_lut`.
     """
-    rows = jax.lax.slice_in_dim(rom, fid * r_max, (fid + 1) * r_max)
     if seg is not None:
-        return _lut_seg(codes, rows, seg=seg)
-    return _lut(codes, rows, eval_bits=eval_bits, k=k, sq_trunc=sq_trunc,
-                lin_trunc=lin_trunc, degree=degree)
+        return _lut_seg(codes, rom_ref, row0=fid * r_max, seg=seg)
+    return _lut(codes, rom_ref, eval_bits=eval_bits, k=k, sq_trunc=sq_trunc,
+                lin_trunc=lin_trunc, degree=degree, row0=fid * r_max,
+                n_rows=r_max)
 
 
-def _rom_kernel(codes_ref, rom_ref, out_ref, *, fid: int, r_max: int,
-                eval_bits: int, k: int, sq_trunc: int, lin_trunc: int,
-                degree: int, seg: tuple | None = None):
-    out_ref[...] = _lut_rom(codes_ref[...], rom_ref[...], fid=fid,
-                            r_max=r_max, eval_bits=eval_bits, k=k,
-                            sq_trunc=sq_trunc, lin_trunc=lin_trunc,
-                            degree=degree, seg=seg)
+def _rom_kernel(codes_ref, rom_ref, out_ref, **lut_kw):
+    out_ref[...] = _lut_rom(codes_ref[...], rom_ref, **lut_kw)
+
+
+def _tiled_call(kernel, tiles: tuple, roms: tuple,
+                interpret: bool | None) -> jax.Array:
+    """Run ``kernel`` over (rows, 128) int32 tile operands in (8, 128)
+    blocks with every ROM-side operand whole in SMEM."""
+    rows, lanes = tiles[0].shape
+    assert lanes == LANES and rows % BLOCK_ROWS == 0, tiles[0].shape
+    assert all(t.shape == tiles[0].shape for t in tiles), \
+        [t.shape for t in tiles]
+    tile = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(rows // BLOCK_ROWS,),
+        in_specs=[tile] * len(tiles) + [rom_spec()] * len(roms),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        interpret=interpret_mode(interpret),
+    )(*tiles, *(flat_rom(r) for r in roms))
 
 
 def rom_eval_2d(codes: jax.Array, rom: jax.Array, *, fid: int, r_max: int,
                 eval_bits: int, k: int, sq_trunc: int, lin_trunc: int,
                 degree: int, seg: tuple | None = None,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """Golden-test harness for ``_lut_rom``: evaluate one function of a
-    flattened ``(F * r_max, 3)`` ROM on (rows, 128) codes through the same
+    ``(F * r_max, 3)`` library ROM on (rows, 128) codes through the same
     in-kernel datapath the fused consumers use."""
-    rows, lanes = codes.shape
-    assert lanes == LANES and rows % BLOCK_ROWS == 0, codes.shape
-    n_rows = rom.shape[0]
     kernel = functools.partial(_rom_kernel, fid=fid, r_max=r_max,
                                eval_bits=eval_bits, k=k, sq_trunc=sq_trunc,
                                lin_trunc=lin_trunc, degree=degree, seg=seg)
-    return pl.pallas_call(
-        kernel,
-        grid=(rows // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((n_rows, 3), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        interpret=interpret,
-    )(codes, rom)
+    return _tiled_call(kernel, (codes,), (rom,), interpret)
 
 
-def _library_kernel(codes_ref, fids_ref, coeffs_ref, meta_ref, out_ref, *,
+def _library_kernel(codes_ref, fids_ref, rom_ref, meta_ref, out_ref, *,
                     n_funcs: int, r_max: int):
-    """Fused multi-function table evaluation: gather by (func_id, region).
+    """Fused multi-function table evaluation: read by (func_id, region).
 
-    ``coeffs_ref`` is the library's padded ROM flattened to
-    ``(n_funcs * r_max, 3)``; ``meta_ref`` is the per-function static
-    datapath ``(n_funcs, 5)`` int32: eval_bits, k, sq_trunc, lin_trunc,
-    degree. Both LUT reads are one-hot MXU contractions like the
-    single-table kernel; the shifts take per-element amounts, which Mosaic
-    lowers as vector shifts.
+    ``rom_ref`` is the library's padded ROM flattened to ``(n_funcs *
+    r_max * 3,)``; ``meta_ref`` the per-function datapath ``(n_funcs * 5,)``:
+    eval_bits, k, sq_trunc, lin_trunc, degree. Both reads are ROM selects
+    like the single-table kernel; the shifts take per-element amounts,
+    which Mosaic lowers as vector shifts.
     """
     codes = codes_ref[...]  # (BLOCK_ROWS, LANES) int32
     fids = fids_ref[...]
-    n = codes.size
-    # per-element datapath params: onehot(fid) @ meta
-    flat_f = fids.reshape(-1)
-    iota_f = jax.lax.broadcasted_iota(jnp.int32, (n, n_funcs), 1)
-    onehot_f = (flat_f[:, None] == iota_f).astype(jnp.int32)
-    m = jax.lax.dot_general(
-        onehot_f, meta_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    eb, k, sq, lin, deg = (m[:, i].reshape(codes.shape) for i in range(5))
-    one = jnp.int32(1)
+    eb, k, sq, lin, deg = rom_read(fids, meta_ref, n=n_funcs, width=5)
     r = jax.lax.shift_right_logical(codes, eb)
-    x = jnp.bitwise_and(codes, jax.lax.shift_left(one, eb) - 1)
+    x = jnp.bitwise_and(codes, jax.lax.shift_left(jnp.int32(1), eb) - 1)
     # fused ROM read: row index = func_id * r_max + region
-    row = (fids * r_max + r).reshape(-1)
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (n, n_funcs * r_max), 1)
-    onehot_r = (row[:, None] == iota_r).astype(jnp.int32)
-    sel = jax.lax.dot_general(
-        onehot_r, coeffs_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    ).reshape(codes.shape + (3,))
-    xs = jax.lax.shift_left(jax.lax.shift_right_logical(x, sq), sq)
-    xl = jax.lax.shift_left(jax.lax.shift_right_logical(x, lin), lin)
-    xs = jnp.where(deg == 2, xs, 0)  # degree-1 rows skip the squarer
-    acc = sel[..., 0] * xs * xs + sel[..., 1] * xl + sel[..., 2]
-    out_ref[...] = jax.lax.shift_right_arithmetic(acc, k)
+    c0, c1, c2 = rom_read(fids * r_max + r, rom_ref, n=n_funcs * r_max)
+    out_ref[...] = poly_tail(c0, c1, c2, x, k=k, sq_trunc=sq, lin_trunc=lin,
+                             degree=deg)
 
 
 def library_eval_2d(codes: jax.Array, fids: jax.Array, coeffs: jax.Array,
-                    meta: jax.Array, *, interpret: bool = True) -> jax.Array:
+                    meta: jax.Array, *,
+                    interpret: bool | None = None) -> jax.Array:
     """codes/fids: (rows, 128) int32, rows % 8 == 0; coeffs: (F, R_max, 3);
     meta: (F, 5) int32 rows of (eval_bits, k, sq_trunc, lin_trunc, degree)."""
-    rows, lanes = codes.shape
-    assert lanes == LANES and rows % BLOCK_ROWS == 0, codes.shape
-    assert fids.shape == codes.shape, (fids.shape, codes.shape)
     n_funcs, r_max, _ = coeffs.shape
-    flat = coeffs.reshape(n_funcs * r_max, 3)
     kernel = functools.partial(_library_kernel, n_funcs=n_funcs, r_max=r_max)
-    return pl.pallas_call(
-        kernel,
-        grid=(rows // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((n_funcs * r_max, 3), lambda i: (0, 0)),
-            pl.BlockSpec((n_funcs, 5), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        interpret=interpret,
-    )(codes, fids, flat, meta)
+    return _tiled_call(kernel, (codes, fids), (coeffs, meta), interpret)
 
 
 def _library_walk_kernel(codes_ref, fids_ref, rom_ref, walk_ref, dp_ref,
@@ -278,11 +268,11 @@ def _library_walk_kernel(codes_ref, fids_ref, rom_ref, walk_ref, dp_ref,
     cell`` needs no integer division by the 3-per-row packing — while a
     uniform element's leaf IS its cell. The coefficient row is then
     ``fid*r_max + leaf`` for both layouts, and the per-element datapath
-    constants gather from ``dp_ref`` at ``leaf_base (+ leaf)``: one row
-    per uniform function, one per segmented leaf. Every gather is a
-    one-hot MXU contraction and the fixed-point tail is the same
-    vector-shift datapath as ``_library_kernel``/``_lut_seg``, so each
-    slot evaluates bit-identically to its specialized path.
+    constants come from ``dp_ref`` at ``leaf_base (+ leaf)``: one row
+    per uniform function, one per segmented leaf. Every read is a ROM
+    select and the fixed-point tail is the same vector-shift datapath as
+    ``_library_kernel``/``_lut_seg``, so each slot evaluates
+    bit-identically to its specialized path.
 
     Unlike ``_lut_seg`` (whose leaf meta must fold into the jaxpr as
     scalar literals), the walk and datapath tables here are real kernel
@@ -290,97 +280,44 @@ def _library_walk_kernel(codes_ref, fids_ref, rom_ref, walk_ref, dp_ref,
     """
     codes = codes_ref[...]  # (BLOCK_ROWS, LANES) int32
     fids = fids_ref[...]
-    rom = rom_ref[...]  # (n_funcs * r_max, 3) int32
-    n = codes.size
-    shape = codes.shape
-    one = jnp.int32(1)
-    flat_f = fids.reshape(-1)
-    iota_f = jax.lax.broadcasted_iota(jnp.int32, (n, n_funcs), 1)
-    onehot_f = (flat_f[:, None] == iota_f).astype(jnp.int32)
-    w = jax.lax.dot_general(
-        onehot_f, walk_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    in_b, depth, segf, lbase, nlv = (w[:, i].reshape(shape) for i in range(5))
+    in_b, depth, segf, lbase, nlv = rom_read(fids, walk_ref, n=n_funcs,
+                                             width=5)
     cell = jax.lax.shift_right_logical(codes, in_b - depth)
     # segment-index read (garbage for uniform elements, masked below)
-    eidx = ((fids * r_max + nlv) * 3 + cell).reshape(-1)
-    iota_e = jax.lax.broadcasted_iota(jnp.int32, (n, n_funcs * r_max * 3), 1)
-    onehot_e = (eidx[:, None] == iota_e).astype(jnp.int32)
-    leaf_seg = jax.lax.dot_general(
-        onehot_e, rom.reshape(-1, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)[:, 0].reshape(shape)
+    (leaf_seg,) = rom_read((fids * r_max + nlv) * 3 + cell, rom_ref,
+                           n=n_funcs * r_max * 3, width=1)
     leaf = jnp.where(segf == 1, leaf_seg, cell)
     # coefficient read: row = fid * r_max + leaf for both layouts
-    row = (fids * r_max + leaf).reshape(-1)
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (n, n_funcs * r_max), 1)
-    onehot_r = (row[:, None] == iota_r).astype(jnp.int32)
-    sel = jax.lax.dot_general(
-        onehot_r, rom, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32).reshape(shape + (3,))
+    c0, c1, c2 = rom_read(fids * r_max + leaf, rom_ref, n=n_funcs * r_max)
     # per-element datapath constants
-    drow = (lbase + jnp.where(segf == 1, leaf, 0)).reshape(-1)
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (n, n_dp), 1)
-    onehot_d = (drow[:, None] == iota_d).astype(jnp.int32)
-    dp = jax.lax.dot_general(
-        onehot_d, dp_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    eb, k, sq, lin, deg = (dp[:, i].reshape(shape) for i in range(5))
-    x = jnp.bitwise_and(codes, jax.lax.shift_left(one, eb) - 1)
-    xs = jax.lax.shift_left(jax.lax.shift_right_logical(x, sq), sq)
-    xl = jax.lax.shift_left(jax.lax.shift_right_logical(x, lin), lin)
-    xs = jnp.where(deg == 2, xs, 0)
-    acc = sel[..., 0] * xs * xs + sel[..., 1] * xl + sel[..., 2]
-    out_ref[...] = jax.lax.shift_right_arithmetic(acc, k)
+    eb, k, sq, lin, deg = rom_read(lbase + jnp.where(segf == 1, leaf, 0),
+                                   dp_ref, n=n_dp, width=5)
+    x = jnp.bitwise_and(codes, jax.lax.shift_left(jnp.int32(1), eb) - 1)
+    out_ref[...] = poly_tail(c0, c1, c2, x, k=k, sq_trunc=sq, lin_trunc=lin,
+                             degree=deg)
 
 
 def library_walk_2d(codes: jax.Array, fids: jax.Array, coeffs: jax.Array,
                     walk: jax.Array, dp: jax.Array, *,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """codes/fids: (rows, 128) int32, rows % 8 == 0; coeffs: (F, R_max, 3);
     walk: (F, 5) int32 rows of (in_bits, depth, seg_flag, leaf_base,
     n_leaves); dp: (L, 5) int32 per-leaf datapath rows."""
-    rows, lanes = codes.shape
-    assert lanes == LANES and rows % BLOCK_ROWS == 0, codes.shape
-    assert fids.shape == codes.shape, (fids.shape, codes.shape)
     n_funcs, r_max, _ = coeffs.shape
-    n_dp = dp.shape[0]
-    flat = coeffs.reshape(n_funcs * r_max, 3)
     kernel = functools.partial(_library_walk_kernel, n_funcs=n_funcs,
-                               r_max=r_max, n_dp=n_dp)
-    return pl.pallas_call(
-        kernel,
-        grid=(rows // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((n_funcs * r_max, 3), lambda i: (0, 0)),
-            pl.BlockSpec((n_funcs, 5), lambda i: (0, 0)),
-            pl.BlockSpec((n_dp, 5), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        interpret=interpret,
-    )(codes, fids, flat, walk, dp)
+                               r_max=r_max, n_dp=dp.shape[0])
+    return _tiled_call(kernel, (codes, fids), (coeffs, walk, dp), interpret)
+
+
+def _interp_kernel(codes_ref, rom_ref, out_ref, **lut_kw):
+    out_ref[...] = _lut(codes_ref[...], rom_ref, **lut_kw)
 
 
 def interp_eval_2d(codes: jax.Array, coeffs: jax.Array, *, eval_bits: int,
                    k: int, sq_trunc: int, lin_trunc: int, degree: int,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """codes: (rows, 128) int32, rows % 8 == 0; coeffs: (2^R, 3) int32."""
-    rows, lanes = codes.shape
-    assert lanes == LANES and rows % BLOCK_ROWS == 0, codes.shape
-    n_regions = coeffs.shape[0]
-    kernel = functools.partial(
-        _interp_kernel, eval_bits=eval_bits, k=k, sq_trunc=sq_trunc,
-        lin_trunc=lin_trunc, n_regions=n_regions, degree=degree)
-    return pl.pallas_call(
-        kernel,
-        grid=(rows // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((n_regions, 3), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        interpret=interpret,
-    )(codes, coeffs)
+    kernel = functools.partial(_interp_kernel, eval_bits=eval_bits, k=k,
+                               sq_trunc=sq_trunc, lin_trunc=lin_trunc,
+                               degree=degree)
+    return _tiled_call(kernel, (codes,), (coeffs,), interpret)

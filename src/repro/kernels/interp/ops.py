@@ -6,12 +6,14 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.table import TableDesign
 from repro.kernels.interp.kernel import (BLOCK_ROWS, LANES, interp_eval_2d,
                                          library_eval_2d, library_walk_2d)
 from repro.kernels.interp.ref import (interp_eval_ref, interp_eval_wide,
                                       library_eval_ref, library_walk_ref)
+from repro.launch.sharding import local_map, rule_spec
 
 
 def _on_tpu() -> bool:
@@ -78,10 +80,18 @@ def table_eval(codes: jax.Array, design: TableDesign,
                                k=design.k, sq_trunc=design.sq_trunc,
                                lin_trunc=design.lin_trunc, degree=design.degree)
     coeffs = design.device_coeffs(checked=True)
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return _eval_padded(codes, coeffs, eval_bits=design.eval_bits, k=design.k,
                         sq_trunc=design.sq_trunc, lin_trunc=design.lin_trunc,
                         degree=design.degree, interpret=interpret)
+
+
+def _elementwise_spec(x: jax.Array) -> P:
+    """Mesh layout for an elementwise table read: batch on the leading
+    axis, the MLP axis on the last — the layout the activation sites
+    already have, so ``local_map`` moves no data."""
+    if x.ndim < 2:
+        return rule_spec(("batch",) * x.ndim, x.shape)
+    return rule_spec(("batch",) + (None,) * (x.ndim - 2) + ("mlp",), x.shape)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -110,8 +120,11 @@ def library_eval(codes: jax.Array, fids: jax.Array, coeffs: jax.Array,
     fids = jnp.broadcast_to(jnp.asarray(fids, jnp.int32), codes.shape)
     if not use_kernel:
         return library_eval_ref(codes, fids, coeffs, meta)
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    return _library_eval_padded(codes, fids, coeffs, meta, interpret=interpret)
+    spec = _elementwise_spec(codes)
+    return local_map(
+        lambda c, f, co, m: _library_eval_padded(c, f, co, m,
+                                                 interpret=interpret),
+        (codes, fids, coeffs, meta), (spec, spec, P(), P()), spec)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -142,6 +155,8 @@ def library_walk(codes: jax.Array, fids: jax.Array, coeffs: jax.Array,
     fids = jnp.broadcast_to(jnp.asarray(fids, jnp.int32), codes.shape)
     if not use_kernel:
         return library_walk_ref(codes, fids, coeffs, walk, dp)
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    return _library_walk_padded(codes, fids, coeffs, walk, dp,
-                                interpret=interpret)
+    spec = _elementwise_spec(codes)
+    return local_map(
+        lambda c, f, co, w, d: _library_walk_padded(c, f, co, w, d,
+                                                    interpret=interpret),
+        (codes, fids, coeffs, walk, dp), (spec, spec, P(), P(), P()), spec)

@@ -71,7 +71,7 @@ def interp_eval_seg_ref(codes: jax.Array, rows: jax.Array, *,
     triples, then the segment-index table packed 3 int32 per row. ``seg``
     is the static ``FuncMeta.seg_spec()`` tuple ``(in_bits, depth,
     n_leaves, leaf_meta)``. Bit-identical to the in-kernel ``_lut_seg``
-    one-hot path (tests/kernels) and to ``SegmentedDesign.eval_int``.
+    ROM-select path (tests/kernels) and to ``SegmentedDesign.eval_int``.
     """
     in_bits, depth, n_leaves, leaf_meta = seg
     n_cells = 1 << depth

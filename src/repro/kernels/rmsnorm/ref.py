@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.interp.kernel import pow2
+
 
 def fused_rmsnorm_lib_ref(x, gamma, coeffs, meta, eps=1e-6):
     """jnp oracle of the library-bound fused RMSNorm kernel: slice the rsqrt
@@ -45,5 +47,5 @@ def fused_rmsnorm_ref(x, gamma, coeffs, meta, eps=1e-6):
         if ev["degree"] == 2:
             acc = acc + sel[..., 0] * xs * xs
         tab = jax.lax.shift_right_arithmetic(acc, ev["k"]).astype(jnp.float32)
-    rs = tab * (2.0 ** -meta["out_bits"]) * jnp.exp2(-h.astype(jnp.float32))
+    rs = tab * (2.0 ** -meta["out_bits"]) * pow2(-h)
     return (xf * rs * gamma.astype(jnp.float32)).astype(x.dtype)

@@ -4,8 +4,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.table import TableDesign
+from repro.launch.sharding import local_map, rule_spec
 from repro.kernels.softmax.kernel import (BLOCK_ROWS, fused_softmax,
                                           fused_softmax_lib)
 from repro.kernels.softmax.ref import fused_softmax_lib_ref, fused_softmax_ref
@@ -55,6 +57,21 @@ def lib_meta(library, kind: str) -> dict:
     }
 
 
+def _pad_rows_lanes(xf: jax.Array, fill: float = 0.0):
+    """Pad a (rows, d) block to the kernels' (8, 128) grid: rows to
+    BLOCK_ROWS with ``fill`` (a finite value keeps the pad rows' math
+    finite), features to a 128-lane multiple with zeros (masked by the
+    kernels' ``d_valid``). Returns the padded block and ``d_valid`` (None
+    when no lane padding was needed)."""
+    rows, d = xf.shape
+    pad_r, pad_d = (-rows) % BLOCK_ROWS, (-d) % 128
+    if pad_r:
+        xf = jnp.pad(xf, ((0, pad_r), (0, 0)), constant_values=fill)
+    if pad_d:
+        xf = jnp.pad(xf, ((0, 0), (0, pad_d)))
+    return xf, (d if pad_d else None)
+
+
 def approx_softmax_library(x: jax.Array, library, use_kernel: bool | None = None,
                            interpret: bool | None = None) -> jax.Array:
     """Library-bound fused softmax over the last axis.
@@ -63,25 +80,30 @@ def approx_softmax_library(x: jax.Array, library, use_kernel: bool | None = None
     leaf) feeds both in-kernel table reads — exp at its static func id,
     recip at its own — so a softmax is ONE kernel launch instead of a
     gather→eval→elementwise chain per transcendental. ``use_kernel=None``
-    picks the Pallas kernel on TPU (128-lane aligned features) and the
-    bit-identical jnp ROM-gather oracle elsewhere."""
+    picks the Pallas kernel on TPU — any feature width: features off the
+    128-lane grid are padded and masked in-kernel — and the bit-identical
+    jnp ROM-gather oracle elsewhere. On a mesh the kernel runs per device
+    on its rows (``local_map``)."""
     em, rm = lib_meta(library, "exp2neg"), lib_meta(library, "recip")
     shape = x.shape
     d = shape[-1]
     rows = x.size // d
     xf = x.reshape(rows, d)
-    r_max = library.coeffs.shape[1]
-    rom = library.coeffs.reshape(-1, 3)
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu" and d % 128 == 0
+        use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         return fused_softmax_lib_ref(xf, library.coeffs, em, rm).reshape(shape)
-    pad = (-rows) % BLOCK_ROWS
-    if pad:
-        xf = jnp.pad(xf, ((0, pad), (0, 0)))
-    interpret = (jax.default_backend() != "tpu") if interpret is None else interpret
-    out = fused_softmax_lib(xf, rom, em, rm, r_max=r_max, interpret=interpret)
-    return out[:rows].reshape(shape)
+
+    def kernel(x, coeffs):  # rows are independent: any row sharding works
+        xf = x.reshape(-1, d)
+        xp, d_valid = _pad_rows_lanes(xf)
+        out = fused_softmax_lib(xp, coeffs.reshape(-1, 3), em, rm,
+                                r_max=coeffs.shape[1], d_valid=d_valid,
+                                interpret=interpret)
+        return out[:xf.shape[0], :d].reshape(x.shape)
+
+    spec = rule_spec(("batch",) + (None,) * (x.ndim - 1), shape)
+    return local_map(kernel, (x, library.coeffs), (spec, P()), spec)
 
 
 def approx_softmax_fused(x: jax.Array,
@@ -108,6 +130,5 @@ def approx_softmax_fused(x: jax.Array,
     pad = (-rows) % BLOCK_ROWS
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
-    interpret = (jax.default_backend() != "tpu") if interpret is None else interpret
     out = fused_softmax(xf, ec, rc, em, rm, interpret=interpret)
     return out[:rows].reshape(shape)

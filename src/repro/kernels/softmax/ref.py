@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.interp.kernel import pow2
+
 LOG2E = 1.4426950408889634
 
 
@@ -53,7 +55,7 @@ def fused_softmax_ref(x, exp_coeffs, recip_coeffs, exp_meta, recip_meta):
     eb = exp_meta["in_bits"]
     codes = jnp.clip(jnp.round(frac * (1 << eb)).astype(jnp.int32), 0, (1 << eb) - 1)
     tab = lut(codes, exp_coeffs, **exp_meta["eval"]).astype(jnp.float32)
-    e = tab * (2.0 ** -exp_meta["out_bits"]) * jnp.exp2(-n)
+    e = tab * (2.0 ** -exp_meta["out_bits"]) * pow2(-n)
     s = jnp.sum(e, axis=-1, keepdims=True)
     bits = jax.lax.bitcast_convert_type(s, jnp.int32)
     expo = jnp.bitwise_and(jax.lax.shift_right_logical(bits, 23), 255) - 127
@@ -63,5 +65,5 @@ def fused_softmax_ref(x, exp_coeffs, recip_coeffs, exp_meta, recip_meta):
     rcodes = jnp.clip(jax.lax.shift_right_logical(mant + half, 23 - rb),
                       0, (1 << rb) - 1)
     rtab = lut(rcodes, recip_coeffs, **recip_meta["eval"]).astype(jnp.float32)
-    recip = rtab * (2.0 ** -(rb + 1)) * jnp.exp2(-expo.astype(jnp.float32))
+    recip = rtab * (2.0 ** -(rb + 1)) * pow2(-expo)
     return (e * recip).astype(x.dtype)

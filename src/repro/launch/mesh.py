@@ -17,17 +17,25 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis in Auto mode: the sharding rules
+    (``launch.sharding``) place work through GSPMD constraints, which jax
+    accepts only on Auto axes (its default is Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Whatever this host actually has (tests, examples)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_serve_mesh(data: int = 1, tp: int = 1, *, devices=None):

@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_params, x_micro, stage_fn: Callable, mesh: Mesh,
@@ -37,8 +36,8 @@ def pipeline_apply(stage_params, x_micro, stage_fn: Callable, mesh: Mesh,
 
     p_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
-    @partial(shard_map, mesh=mesh, in_specs=(p_spec, P()), out_specs=P(),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(p_spec, P()), out_specs=P(),
+             check_vma=False)
     def run(params, xs):
         params = jax.tree.map(lambda a: a[0], params)  # this stage's slice
         idx = jax.lax.axis_index(axis)

@@ -22,10 +22,17 @@ PATH`` records admissions and emitted tokens through an fsync'd append-only
 journal, and ``--resume`` (with ``--journal``) rebuilds the engine from that
 journal after a crash — completed requests are not re-served and in-flight
 streams continue bitwise where they left off.
+
+The exit status is 0 only for a clean run: any degradation-ladder step,
+recorded fault or failed request exits 1 (after the summary), so a run that
+quietly fell back from the fused datapath cannot pass for a healthy one.
+The persistent compilation cache is placed by
+:func:`repro.launch.compile_cache.setup_compile_cache`.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -33,12 +40,27 @@ import numpy as np
 
 from repro.api import InterpLibrary
 from repro.configs.base import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import transformer as tf
 from repro.serve import Rejected, ServeEngine
 from repro.serve.engine import Request
 
 
-def main():
+def degraded(eng) -> list[str]:
+    """Why a finished engine run is not clean (empty = clean): degradation
+    ladder steps, recorded faults, failed requests."""
+    why = []
+    d = eng.stats["degradations"]
+    if (sum(d.values()) if isinstance(d, dict) else d):
+        why.append(f"degradations={d}")
+    if eng.faults:
+        why.append(f"faults={len(eng.faults)}")
+    if eng.failed:
+        why.append(f"failed={len(eng.failed)}")
+    return why
+
+
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -95,6 +117,7 @@ def main():
     args = ap.parse_args()
     if args.resume and not args.journal:
         ap.error("--resume requires --journal")
+    setup_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.numerics:
@@ -182,7 +205,12 @@ def main():
         print(f"  faults: {eng.faults}")
     for r in done[:3]:
         print(f"  req {r.rid}: {r.out[:8]}...")
+    why = degraded(eng)
+    if why:
+        print(f"  NOT CLEAN: {', '.join(why)}")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

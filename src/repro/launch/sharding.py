@@ -89,6 +89,35 @@ def constrain(x: jax.Array, names: Sequence[Optional[str]]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def rule_spec(names: Sequence[Optional[str]], shape: Sequence[int]) -> P:
+    """``logical_spec`` under the active rule context (``P()`` outside)."""
+    ctx = getattr(_state, "ctx", None)
+    if ctx is None:
+        return P()
+    mesh, rules = ctx
+    return logical_spec(names, shape, mesh, rules)
+
+
+def local_map(fn, args: tuple, in_specs: tuple, out_specs):
+    """Run ``fn(*args)`` once per device of the active mesh on its local
+    shards (``jax.shard_map``), for bodies GSPMD cannot partition — Mosaic
+    kernels, which are opaque custom calls. ``in_specs`` / ``out_specs``
+    are PartitionSpecs (pytree prefixes allowed, e.g. ``P()`` for a whole
+    replicated library); the caller picks specs under which ``fn`` is
+    independent per shard. Without an active multi-device mesh this is
+    just ``fn(*args)``. The body runs with no rule context, so nothing
+    inside it re-enters ``local_map`` or ``constrain``."""
+    ctx = getattr(_state, "ctx", None)
+    if ctx is None or ctx[0].size == 1:
+        return fn(*args)
+    _state.ctx = None
+    try:
+        return jax.shard_map(fn, mesh=ctx[0], in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
+    finally:
+        _state.ctx = ctx
+
+
 def named_sharding(names: Sequence[Optional[str]], shape: Sequence[int],
                    mesh: Mesh, rules: dict | None = None) -> NamedSharding:
     return NamedSharding(mesh, logical_spec(names, shape, mesh, rules or DEFAULT_RULES))

@@ -8,6 +8,7 @@ the same specs into real arrays for the smoke tests / examples.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -33,6 +34,14 @@ def stack_specs(tree: ShapeTree, n: int) -> ShapeTree:
     return jax.tree.map(lambda s: spec((n, *s.shape), s.dtype), tree)
 
 
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _draw(key: jax.Array, shape: tuple, dtype, std: float) -> jax.Array:
+    """One leaf's truncated-normal draw, jitted so the float32 sample fuses
+    into the cast: a full-width bf16 leaf never materializes in float32."""
+    return (jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
+            * std).astype(dtype)
+
+
 def init_tree(key: jax.Array, shapes: ShapeTree, scale_rules: Callable[[str, Any], float] | None = None) -> Params:
     """Materialize a shape tree: truncated-normal fan-in init, zeros for
     biases/norm offsets, ones for norm scales."""
@@ -54,7 +63,7 @@ def init_tree(key: jax.Array, shapes: ShapeTree, scale_rules: Callable[[str, Any
         std = 1.0 / math.sqrt(max(fan_in, 1))
         if scale_rules is not None:
             std *= scale_rules(name, s)
-        return (jax.random.truncated_normal(k, -2.0, 2.0, s.shape, jnp.float32) * std).astype(s.dtype)
+        return _draw(k, s.shape, jnp.dtype(s.dtype), std)
 
     leaves = [one(p, s, k) for (p, s), k in zip(flat, keys)]
     return jax.tree.unflatten(treedef, leaves)

@@ -22,7 +22,9 @@ the golden tests pin bit-for-bit.
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from functools import partial
 
 import jax
@@ -33,6 +35,33 @@ from repro.core.funcspec import ACT_HI, ACT_LO, act_out_span
 from repro.core.table import TableDesign
 
 LOG2E = 1.4426950408889634
+
+# Trace-time sinks for fused-attention refusals: a stats dict registered by
+# count_attention_fallbacks() (the serving engine wraps every trace of its
+# programs in one) gets ATTN_FALLBACK_KEY incremented each time a traced
+# attention takes the chunked glue path instead of the fused kernel.
+ATTN_FALLBACK_KEY = "attn_glue_fallbacks"
+_SINKS = threading.local()
+
+
+@contextlib.contextmanager
+def count_attention_fallbacks(sink: dict):
+    """Count, into ``sink[ATTN_FALLBACK_KEY]``, every fused-attention
+    refusal traced inside the block (the count is per trace: a program
+    that is already compiled is not traced again)."""
+    stack = getattr(_SINKS, "stack", None)
+    if stack is None:
+        stack = _SINKS.stack = []
+    stack.append(sink)
+    try:
+        yield sink
+    finally:
+        stack.pop()
+
+
+def _attention_fallback() -> None:
+    for sink in getattr(_SINKS, "stack", ()):
+        sink[ATTN_FALLBACK_KEY] = sink.get(ATTN_FALLBACK_KEY, 0) + 1
 
 
 def table_eval_int(codes: jax.Array, design: TableDesign) -> jax.Array:
@@ -361,21 +390,21 @@ class FusedInterpNumerics(InterpNumerics):
                         scale):
         """The ``attention_core`` fast path: whole-datapath flash attention
         with the library ROM inlined. Returns None (caller falls back to
-        the chunked glue path) when the layout is unsupported."""
+        the chunked glue path) when the layout is unsupported; each refusal
+        is counted into the active ``count_attention_fallbacks`` sink."""
         from repro.kernels.flashattn.ops import attention_fused_library
 
         b, sq, h, d = q.shape
         kvh = k.shape[2]
-        if h % kvh:
-            return None
-        if k.shape[1] > 4096:
-            # the kernel holds the whole K/V stripe per program (the
-            # flashattn VMEM bound); longer contexts keep the chunked
-            # memory-bounded glue path on every backend
-            return None
-        if sq * k.shape[1] > (1 << 22) and jax.default_backend() != "tpu":
-            # the off-TPU oracle materializes the (N, Sq, Sk) score block;
-            # long-context prefill stays on the chunked glue path there
+        # the kernel holds the whole K/V stripe per program (the flashattn
+        # VMEM bound): longer contexts keep the chunked memory-bounded glue
+        # path on every backend. Off-TPU the oracle materializes the (N,
+        # Sq, Sk) score block, so long-context prefill stays on the glue
+        # path there.
+        if (h % kvh or k.shape[1] > 4096
+                or (sq * k.shape[1] > (1 << 22)
+                    and jax.default_backend() != "tpu")):
+            _attention_fallback()
             return None
         # grouped kv heads pass through unexpanded: the kernel maps each
         # query-head program onto its kv stripe by index

@@ -79,7 +79,8 @@ from repro.api import InterpLibrary, LibraryIntegrityError, default_explorer
 from repro.faults.inject import crashpoint
 from repro.launch import sharding as shlib
 from repro.models import transformer as tf
-from repro.numerics.ops import INTERP_BACKENDS, get_numerics
+from repro.numerics.ops import (ATTN_FALLBACK_KEY, INTERP_BACKENDS,
+                                count_attention_fallbacks, get_numerics)
 from repro.serve import aot as aot_mod
 from repro.serve.journal import ServeJournal, load_requests
 from repro.serve.pipeline import HostPipeline
@@ -417,7 +418,8 @@ class ServeEngine:
                       "aot_reshards": 0, "aot_fallbacks": 0,
                       "packed_admits": 0,
                       "packed_requests": 0, "admit_dispatches": 0,
-                      "async_chunks": 0, "async_tokens": 0}
+                      "async_chunks": 0, "async_tokens": 0,
+                      ATTN_FALLBACK_KEY: 0}
         self.faults: list[dict] = []  # structured fault/degradation log
         self._trips = 0  # watchdog trips since the last degradation
         self.journal = (journal if isinstance(journal, (ServeJournal,
@@ -506,12 +508,16 @@ class ServeEngine:
             assert_rom_replicated(*jax.tree.leaves(self.library))
 
     def _ctx(self):
-        """Logical-axis rule context for every trace/lower on this engine:
-        ``constrain`` reads the thread-local rules at *trace* time, so all
-        dispatch sites wrap themselves in this (a no-op without a mesh)."""
-        if self.mesh is None:
-            return contextlib.nullcontext()
-        return shlib.axis_rules(self.mesh)
+        """Trace context for every trace/lower on this engine: the
+        logical-axis rules (``constrain`` reads the thread-local rules at
+        *trace* time; none without a mesh) and the fused-attention refusal
+        counter (``stats["attn_glue_fallbacks"]``: attention sites traced
+        onto the chunked glue path instead of the fused kernel)."""
+        stack = contextlib.ExitStack()
+        if self.mesh is not None:
+            stack.enter_context(shlib.axis_rules(self.mesh))
+        stack.enter_context(count_attention_fallbacks(self.stats))
+        return stack
 
     def _aot_key(self, kind: str, *extra) -> tuple:
         """Executable-cache key: the frozen (cfg [incl. plan], geometry,

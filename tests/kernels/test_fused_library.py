@@ -219,3 +219,29 @@ def test_fused_numerics_close_to_glue_numerics(lib):
     np.testing.assert_allclose(np.asarray(fused.rmsnorm(x, gamma)),
                                np.asarray(glue.rmsnorm(x, gamma)),
                                rtol=3e-3, atol=3e-3)
+
+
+def test_fused_attention_refusal_is_counted(lib):
+    """A layout the fused kernel refuses returns None (the chunked glue
+    path takes over) and is counted in the active trace-time sink — the
+    engine's ``stats["attn_glue_fallbacks"]`` — never silently."""
+    from repro.numerics.ops import (ATTN_FALLBACK_KEY,
+                                    count_attention_fallbacks)
+
+    num = get_numerics("interp", lib, fused=True)
+    q = jnp.zeros((1, 4, 3, 16), jnp.float32)  # 3 heads over 2 kv heads
+    k = v = jnp.zeros((1, 4, 2, 16), jnp.float32)
+    pos = jnp.zeros((1, 4), jnp.int32)
+    sink: dict = {}
+    with count_attention_fallbacks(sink):
+        out = num.fused_attention(q, k, v, pos, pos, causal=True,
+                                  window=None, scale=None)
+    assert out is None and sink[ATTN_FALLBACK_KEY] == 1
+    # outside any sink nothing is recorded, and a supported layout is not
+    # a refusal
+    assert num.fused_attention(q, k, v, pos, pos, causal=True, window=None,
+                               scale=None) is None
+    with count_attention_fallbacks(sink):
+        out = num.fused_attention(q[:, :, :2], k, v, pos, pos, causal=True,
+                                  window=None, scale=None)
+    assert out is not None and sink[ATTN_FALLBACK_KEY] == 1

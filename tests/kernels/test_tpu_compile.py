@@ -1,0 +1,200 @@
+"""Compile-only rehearsal of the serving-path kernels for a TPU v5e.
+
+Every kernel on the fused serving path is lowered with ``interpret=False``
+and compiled by the installed TPU compiler (Mosaic) for one chip of a
+*described* ``v5e:2x2`` topology — no chip is attached, nothing runs. A
+kernel that Mosaic would reject on the chip (unsupported shape casts,
+non-MXU matmul operand types, blocks that break the (8, 128) tiling rule,
+unlowerable primitives) fails here, at the real model widths:
+
+* yi_6b decode and prefill attention: head_dim 128, GQA group 8, a
+  4096-slot KV cache, 512-token prefill buckets;
+* minicpm3_4b's MLA attention (Dk 96 != Dv 64);
+* rmsnorm at d_model 4096, softmax on aligned and unaligned widths;
+* the fused ROM walk over the full default manifest with uniform (v1)
+  slots, and with a segmented (v2) slot, plus the per-slot ROM read.
+
+The topology and the shardings built from it live in module fixtures, so
+the TPU library is loaded only by the test process that runs this file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.api import DEFAULT_LIBRARY_KINDS, InterpLibrary, default_explorer
+from repro.api.config import spec_for
+from repro.kernels.flashattn.ops import attention_fused_library
+from repro.kernels.interp.kernel import rom_eval_2d
+from repro.kernels.interp.ops import library_walk
+from repro.kernels.rmsnorm.ops import approx_rmsnorm_library
+from repro.kernels.softmax.ops import approx_softmax_library, lib_meta
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """Compiles for a described chip cannot be read back from the
+    persistent cache; keep it off so nothing warns."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def lib():
+    return default_explorer().compile()
+
+
+@pytest.fixture(scope="module")
+def lib_v2():
+    """The default manifest with its tanh slot replaced by a segmented
+    (ROM v2) design."""
+    from repro.segment import explore_segmented, min_uniform_depth
+
+    spec = spec_for("tanh", 8)
+    seg = explore_segmented(spec, max_depth=min_uniform_depth(
+        spec, engine="batched"), engine="batched")
+    ex = default_explorer()
+    designs = [seg if k == "tanh" else ex.get_table(k)
+               for k in DEFAULT_LIBRARY_KINDS]
+    return InterpLibrary.from_designs(designs, list(DEFAULT_LIBRARY_KINDS))
+
+
+def _compile(fn, args, sharding):
+    """Lower ``fn`` on shape-only arguments placed on ``sharding`` and
+    compile it for that (described) device; returns the HLO text."""
+    sds = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+    text = jax.jit(fn).lower(*sds).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# ROM reads
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("version", ["v1", "v2"])
+def test_library_walk_compiles(one_chip, no_cache, lib, lib_v2, version):
+    library = lib if version == "v1" else lib_v2
+    walk, dp = library.walk_rows()
+    codes = _sds((64, 128), jnp.int32)
+
+    def fn(codes, fids, coeffs, walk, dp):
+        return library_walk(codes, fids, coeffs, walk, dp, use_kernel=True,
+                            interpret=False)
+
+    _compile(fn, (codes, codes, library.coeffs, walk, dp), one_chip)
+
+
+@pytest.mark.parametrize("kind", ["exp2neg", "rsqrt", "tanh"])
+def test_rom_read_compiles(one_chip, no_cache, lib_v2, kind):
+    """The per-slot read every fused consumer inlines (tanh is the
+    segmented slot)."""
+    m = lib_v2.meta(kind)
+    lm = lib_meta(lib_v2, kind)
+
+    def fn(codes, rom):
+        return rom_eval_2d(codes, rom.reshape(-1, 3), fid=lm["fid"],
+                           r_max=lib_v2.coeffs.shape[1], **lm["eval"],
+                           interpret=False)
+
+    assert (m.seg_depth > 0) == (kind == "tanh")
+    _compile(fn, (_sds((64, 128), jnp.int32), lib_v2.coeffs), one_chip)
+
+
+# ---------------------------------------------------------------------------
+# fused consumers at model widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows,d", [(8, 4096), (2048, 4096), (8, 2560)])
+def test_rmsnorm_compiles(one_chip, no_cache, lib, rows, d):
+    def fn(x, gamma, library):
+        return approx_rmsnorm_library(x, gamma, library, use_kernel=True,
+                                      interpret=False)
+
+    _compile(fn, (_sds((rows, d), jnp.bfloat16), _sds((d,), jnp.float32),
+                  lib), one_chip)
+
+
+@pytest.mark.parametrize("shape", [(8, 32, 4096), (4, 8, 100)])
+def test_softmax_compiles(one_chip, no_cache, lib, shape):
+    def fn(x, library):
+        return approx_softmax_library(x, library, use_kernel=True,
+                                      interpret=False)
+
+    _compile(fn, (_sds(shape, jnp.float32), lib), one_chip)
+
+
+# (batch, q len, kv len, heads, kv heads, Dk, Dv)
+ATTN = {
+    "yi_6b-decode": (8, 1, 4096, 32, 4, 128, 128),
+    "yi_6b-prefill": (2, 512, 512, 32, 4, 128, 128),
+    "minicpm3_4b-mla-decode": (8, 1, 4096, 40, 40, 96, 64),
+    "minicpm3_4b-mla-prefill": (1, 256, 256, 40, 40, 96, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ATTN))
+def test_flash_compiles(one_chip, no_cache, lib, case):
+    b, sq, sk, h, kvh, dk, dv = ATTN[case]
+
+    def fn(q, k, v, q_pos, kv_pos, library):
+        return attention_fused_library(q, k, v, library, causal=True,
+                                       q_pos=q_pos, kv_pos=kv_pos,
+                                       use_kernel=True, interpret=False)
+
+    _compile(fn, (_sds((b, sq, h, dk), jnp.bfloat16),
+                  _sds((b, sk, kvh, dk), jnp.bfloat16),
+                  _sds((b, sk, kvh, dv), jnp.bfloat16),
+                  _sds((b, sq), jnp.int32), _sds((b, sk), jnp.int32), lib),
+             one_chip)
+
+
+# ---------------------------------------------------------------------------
+# design-space kernels: off the serving path, not Mosaic-compilable yet
+# ---------------------------------------------------------------------------
+
+@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
+    "Mosaic: 'The Pallas TPU lowering currently requires that the last two "
+    "dimensions of your block shape are divisible by 8 and 128 "
+    "respectively, or be equal to the respective dimensions of the overall "
+    "array' for the (1, 3n) row blocks, and behind it 'Unimplemented "
+    "primitive in Pallas TPU lowering: dynamic_slice' for the per-offset "
+    "lane slices; the dspace engines refuse to run on a TPU instead"))
+def test_dspace_envelopes_compile(one_chip, no_cache):
+    from repro.kernels.dspace.kernel import envelopes_parity_batched
+
+    def fn(lo, hi):
+        return envelopes_parity_batched(lo, hi, interpret=False)
+
+    _compile(fn, (_sds((64, 256), jnp.float32), _sds((64, 256), jnp.float32)),
+             one_chip)

@@ -296,3 +296,35 @@ def test_periodic_rom_verify_catches_runtime_corruption():
     eng.run()  # finishes on the exact rung
     (done,) = eng.finished
     assert len(done.out) == 12
+
+
+def test_serve_cli_exit_status_reflects_degradation(tmp_path):
+    """``repro.launch.serve`` exits 1 when the run degraded, faulted or
+    failed a request (``degraded`` names why), 0 for a clean run."""
+    import subprocess
+    import sys
+    from types import SimpleNamespace
+
+    from repro.launch.serve import degraded
+
+    clean = SimpleNamespace(stats={"degradations": 0}, faults=[], failed=[])
+    assert degraded(clean) == []
+    assert degraded(SimpleNamespace(stats={"degradations": {"0": 1}},
+                                    faults=[], failed=[]))
+    assert degraded(SimpleNamespace(stats={"degradations": 0},
+                                    faults=[{"reason": "x"}], failed=[]))
+    assert degraded(SimpleNamespace(stats={"degradations": 0}, faults=[],
+                                    failed=[object()]))
+    import os
+    import pathlib
+
+    src = pathlib.Path(__file__).resolve().parents[2] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "yi_6b",
+         "--smoke", "--requests", "2", "--slots", "2", "--prompt-len", "4",
+         "--max-new", "3", "--cache-len", "16"],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "served 2 requests" in out.stdout

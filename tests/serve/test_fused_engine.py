@@ -156,3 +156,20 @@ def test_fused_engine_windowed_wrap():
         solo.submit(Request(i, p, max_new=4))
         (ref,) = solo.run()
         assert done[i] == ref.out
+
+
+def test_fused_engine_counts_attention_glue_fallbacks():
+    """Decode against a cache longer than the flash kernel's VMEM bound
+    takes the chunked glue path; the engine counts every such traced site
+    in ``stats["attn_glue_fallbacks"]`` (zero on a supported layout)."""
+    base = get_smoke_config("yi_6b").replace(numerics="interp-fused")
+    params = tf.init_params(jax.random.key(0), base)
+    counts = {}
+    for cache_len in (48, 4100):
+        cfg = base.replace(name=f"yi_6b-fallback-probe-{cache_len}")
+        eng = _mk(cfg, params, fused=True, slots=1, cache_len=cache_len)
+        eng.submit(Request(0, _prompts(cfg, (5,))[0], max_new=3))
+        eng.run()
+        counts[cache_len] = eng.stats["attn_glue_fallbacks"]
+    assert counts[48] == 0
+    assert counts[4100] > 0

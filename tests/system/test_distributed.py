@@ -34,10 +34,11 @@ def test_sharded_train_step_matches_single_device():
     from repro.configs.base import get_smoke_config
     from repro.data import make_batch
     from repro.launch import sharding as shlib
+    from repro.launch.mesh import make_host_mesh
     from repro.train.step import StepConfig, make_train_step, train_state_init
 
     cfg = get_smoke_config("yi_6b").replace(n_layers=2)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(model=2)
     batch = {k: jnp.asarray(v) for k, v in make_batch(cfg, 32, 4).items()}
     sc = StepConfig(peak_lr=1e-3, warmup=0)
     step = make_train_step(cfg, sc)
@@ -68,12 +69,13 @@ def test_sharded_decode_matches_single_device():
     _run("""
     from repro.configs.base import get_smoke_config
     from repro.launch import sharding as shlib
+    from repro.launch.mesh import make_host_mesh
     from repro.models import transformer as tf
     from repro.numerics.ops import get_numerics
 
     cfg = get_smoke_config("qwen1_5_110b").replace(n_layers=2)
     numerics = get_numerics("exact")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model=4)
     params = tf.init_params(jax.random.key(0), cfg)
     toks = jax.random.randint(jax.random.key(1), (4, 16), 0, cfg.vocab_size)
 

@@ -179,7 +179,7 @@ def test_positive_domain_paths_bitwise_everywhere(kind, xs):
 
 @settings(max_examples=25, deadline=None)
 @given(st.sampled_from(["recip", "rsqrt"]),
-       st.floats(-1e30, 0.0, width=32))
+       st.floats(np.float32(-1e30), 0.0, width=32))
 def test_nonpositive_never_silently_wraps(kind, bad):
     """Any non-positive float either raises (strict guard) or, unguarded +
     non-strict-guarded, never produces a value that looks like a valid

@@ -146,3 +146,60 @@ def test_mesh_factory_validation():
         raise AssertionError("partitioned ROM accepted")
     print("factory OK")
     """)
+
+
+def test_fused_kernels_run_per_device_on_a_mesh():
+    """On a mesh the Pallas library kernels (opaque to the SPMD
+    partitioner on a TPU) run per device on their shards through
+    ``local_map``: rmsnorm / softmax rows, flash attention batch rows and
+    kv-head groups (GQA), elementwise ROM walks. Interpret-mode kernels
+    under ``jit`` on sharded inputs equal the single-device kernels
+    bitwise."""
+    _run("""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.api import default_explorer
+    from repro.kernels.flashattn.ops import attention_fused_library
+    from repro.kernels.interp.ops import library_walk
+    from repro.kernels.rmsnorm.ops import approx_rmsnorm_library
+    from repro.kernels.softmax.ops import approx_softmax_library
+    from repro.launch import sharding as shlib
+
+    lib = default_explorer().compile()
+    walk, dp = lib.walk_rows()
+    kw = dict(use_kernel=True, interpret=True)
+    r = np.random.default_rng(0)
+    b, s, h, kvh, d = 4, 16, 8, 2, 64
+    q = jnp.asarray(r.normal(0, 1, (b, s, h, d)), jnp.float32)
+    k = jnp.asarray(r.normal(0, 1, (b, s, kvh, d)), jnp.float32)
+    v = jnp.asarray(r.normal(0, 1, (b, s, kvh, d)), jnp.float32)
+    x = jnp.asarray(r.normal(0, 2, (b, s, 200)), jnp.float32)
+    gamma = jnp.asarray(r.normal(1, 0.1, 200), jnp.float32)
+    codes = jnp.asarray(r.integers(0, 4096, (b, s, 256)), jnp.int32)
+    fids = jnp.asarray(r.integers(0, len(lib.kinds), (b, s, 256)), jnp.int32)
+
+    def f(q, k, v, x, codes, fids):
+        return (attention_fused_library(q, k, v, lib, causal=True, **kw),
+                approx_rmsnorm_library(x, gamma, lib, **kw),
+                approx_softmax_library(x, lib, **kw),
+                library_walk(codes, fids, lib.coeffs, walk, dp, **kw))
+
+    want = jax.jit(f)(q, k, v, x, codes, fids)
+    for data, tp in ((2, 2), (4, 2), (1, 2)):
+        mesh = make_serve_mesh(data, tp)
+        put = lambda a, names: jax.device_put(
+            a, shlib.named_sharding(names, a.shape, mesh))
+        args = (put(q, ("batch", None, "heads", None)),
+                put(k, ("batch", None, "kv_heads", None)),
+                put(v, ("batch", None, "kv_heads", None)),
+                put(x, ("batch", None, None)),
+                put(codes, ("batch", None, "mlp")),
+                put(fids, ("batch", None, "mlp")))
+        with shlib.axis_rules(mesh):
+            text = jax.jit(f).lower(*args).as_text()
+            got = jax.jit(f)(*args)
+        assert "shard_map" in text or "manual" in text, (data, tp)
+        for w, g in zip(want, got):
+            np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+    print("OK")
+    """)
