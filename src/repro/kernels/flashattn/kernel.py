@@ -215,6 +215,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, d), v.dtype),
         interpret=interpret_mode(interpret),
+        name="flash",
     )(q, k, v, flat_rom(exp_coeffs), flat_rom(recip_coeffs))
 
 
@@ -260,5 +261,6 @@ def flash_attention_lib(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, sq, dv), v.dtype),
         interpret=interpret_mode(interpret),
+        name="flash_lib",
     )(q, k, v, q_pos.astype(jnp.int32).reshape(n, sq, 1),
       kv_pos.astype(jnp.int32).reshape(n // g, nk, block_k), flat_rom(rom))

@@ -191,9 +191,10 @@ def _rom_kernel(codes_ref, rom_ref, out_ref, **lut_kw):
 
 
 def _tiled_call(kernel, tiles: tuple, roms: tuple,
-                interpret: bool | None) -> jax.Array:
+                interpret: bool | None, name: str) -> jax.Array:
     """Run ``kernel`` over (rows, 128) int32 tile operands in (8, 128)
-    blocks with every ROM-side operand whole in SMEM."""
+    blocks with every ROM-side operand whole in SMEM; ``name`` names the
+    kernel in the compiled program (and the trace)."""
     rows, lanes = tiles[0].shape
     assert lanes == LANES and rows % BLOCK_ROWS == 0, tiles[0].shape
     assert all(t.shape == tiles[0].shape for t in tiles), \
@@ -206,6 +207,7 @@ def _tiled_call(kernel, tiles: tuple, roms: tuple,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         interpret=interpret_mode(interpret),
+        name=name,
     )(*tiles, *(flat_rom(r) for r in roms))
 
 
@@ -219,7 +221,7 @@ def rom_eval_2d(codes: jax.Array, rom: jax.Array, *, fid: int, r_max: int,
     kernel = functools.partial(_rom_kernel, fid=fid, r_max=r_max,
                                eval_bits=eval_bits, k=k, sq_trunc=sq_trunc,
                                lin_trunc=lin_trunc, degree=degree, seg=seg)
-    return _tiled_call(kernel, (codes,), (rom,), interpret)
+    return _tiled_call(kernel, (codes,), (rom,), interpret, "rom_eval")
 
 
 def _library_kernel(codes_ref, fids_ref, rom_ref, meta_ref, out_ref, *,
@@ -250,7 +252,8 @@ def library_eval_2d(codes: jax.Array, fids: jax.Array, coeffs: jax.Array,
     meta: (F, 5) int32 rows of (eval_bits, k, sq_trunc, lin_trunc, degree)."""
     n_funcs, r_max, _ = coeffs.shape
     kernel = functools.partial(_library_kernel, n_funcs=n_funcs, r_max=r_max)
-    return _tiled_call(kernel, (codes, fids), (coeffs, meta), interpret)
+    return _tiled_call(kernel, (codes, fids), (coeffs, meta), interpret,
+                       "_library_eval")
 
 
 def _library_walk_kernel(codes_ref, fids_ref, rom_ref, walk_ref, dp_ref,
@@ -306,7 +309,8 @@ def library_walk_2d(codes: jax.Array, fids: jax.Array, coeffs: jax.Array,
     n_funcs, r_max, _ = coeffs.shape
     kernel = functools.partial(_library_walk_kernel, n_funcs=n_funcs,
                                r_max=r_max, n_dp=dp.shape[0])
-    return _tiled_call(kernel, (codes, fids), (coeffs, walk, dp), interpret)
+    return _tiled_call(kernel, (codes, fids), (coeffs, walk, dp), interpret,
+                       "_library_walk")
 
 
 def _interp_kernel(codes_ref, rom_ref, out_ref, **lut_kw):
@@ -320,4 +324,5 @@ def interp_eval_2d(codes: jax.Array, coeffs: jax.Array, *, eval_bits: int,
     kernel = functools.partial(_interp_kernel, eval_bits=eval_bits, k=k,
                                sq_trunc=sq_trunc, lin_trunc=lin_trunc,
                                degree=degree)
-    return _tiled_call(kernel, (codes,), (coeffs,), interpret)
+    return _tiled_call(kernel, (codes,), (coeffs,), interpret,
+                       "interp_eval")

@@ -65,7 +65,7 @@ def _rmsnorm_lib_kernel(x_ref, gamma_ref, rom_ref, out_ref, *, r_max: int,
 
 
 def _row_call(kernel, x: jax.Array, gamma: jax.Array, rom: jax.Array,
-              interpret: bool | None):
+              interpret: bool | None, name: str):
     rows, d = x.shape
     assert rows % BLOCK_ROWS == 0 and d % 128 == 0, x.shape
     block = pl.BlockSpec((BLOCK_ROWS, d), lambda i: (i, 0))
@@ -76,6 +76,7 @@ def _row_call(kernel, x: jax.Array, gamma: jax.Array, rom: jax.Array,
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret_mode(interpret),
+        name=name,
     )(x, gamma.reshape(1, d), flat_rom(rom))
 
 
@@ -88,10 +89,10 @@ def fused_rmsnorm_lib(x: jax.Array, gamma: jax.Array, rom: jax.Array,
     r_max, 3) int32."""
     kernel = functools.partial(_rmsnorm_lib_kernel, r_max=r_max, meta=meta,
                                eps=eps, d_valid=d_valid)
-    return _row_call(kernel, x, gamma, rom, interpret)
+    return _row_call(kernel, x, gamma, rom, interpret, "rmsnorm_lib")
 
 
 def fused_rmsnorm(x: jax.Array, gamma: jax.Array, coeffs: jax.Array, meta: dict,
                   eps: float = 1e-6, interpret: bool | None = None) -> jax.Array:
     kernel = functools.partial(_rmsnorm_kernel, meta=meta, eps=eps)
-    return _row_call(kernel, x, gamma, coeffs, interpret)
+    return _row_call(kernel, x, gamma, coeffs, interpret, "rmsnorm")

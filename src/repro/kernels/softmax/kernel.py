@@ -94,8 +94,10 @@ def _softmax_lib_kernel(x_ref, rom_ref, out_ref, *, r_max: int,
         exp_meta, recip_meta, out_ref.dtype, d_valid)
 
 
-def _row_call(kernel, x: jax.Array, roms: tuple, interpret: bool | None):
-    """(BLOCK_ROWS, D) row blocks of ``x`` with every ROM whole in SMEM."""
+def _row_call(kernel, x: jax.Array, roms: tuple, interpret: bool | None,
+              name: str):
+    """(BLOCK_ROWS, D) row blocks of ``x`` with every ROM whole in SMEM;
+    ``name`` names the kernel in the compiled program (and the trace)."""
     rows, d = x.shape
     assert rows % BLOCK_ROWS == 0 and d % 128 == 0, x.shape
     block = pl.BlockSpec((BLOCK_ROWS, d), lambda i: (i, 0))
@@ -106,6 +108,7 @@ def _row_call(kernel, x: jax.Array, roms: tuple, interpret: bool | None):
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret_mode(interpret),
+        name=name,
     )(x, *(flat_rom(r) for r in roms))
 
 
@@ -119,7 +122,7 @@ def fused_softmax_lib(x: jax.Array, rom: jax.Array, exp_meta: dict,
     kernel = functools.partial(_softmax_lib_kernel, r_max=r_max,
                                exp_meta=exp_meta, recip_meta=recip_meta,
                                d_valid=d_valid)
-    return _row_call(kernel, x, (rom,), interpret)
+    return _row_call(kernel, x, (rom,), interpret, "softmax_lib")
 
 
 def fused_softmax(x: jax.Array, exp_coeffs: jax.Array, recip_coeffs: jax.Array,
@@ -128,4 +131,5 @@ def fused_softmax(x: jax.Array, exp_coeffs: jax.Array, recip_coeffs: jax.Array,
     """x: (rows, D) with rows % BLOCK_ROWS == 0, D % 128 == 0."""
     kernel = functools.partial(_softmax_kernel, exp_meta=exp_meta,
                                recip_meta=recip_meta)
-    return _row_call(kernel, x, (exp_coeffs, recip_coeffs), interpret)
+    return _row_call(kernel, x, (exp_coeffs, recip_coeffs), interpret,
+                     "softmax")

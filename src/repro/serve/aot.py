@@ -88,14 +88,20 @@ def lookup(key: tuple):
     return _EXEC_CACHE.get(key)
 
 
-def compile_cached(key: tuple, jit_fn, args: tuple, kwargs: dict):
+def compile_cached(key: tuple, jit_fn, args: tuple, kwargs: dict, spans):
     """AOT-compile ``jit_fn`` for the concrete ``args``/``kwargs`` (their
     shapes, dtypes *and shardings* are what gets pinned) unless an
     executable is already cached under ``key``. Lowering only traces — the
-    donated buffers among ``args`` are not consumed."""
+    donated buffers among ``args`` are not consumed. ``spans`` (a
+    :class:`repro.serve.spans.SpanRecorder`) times the two halves as
+    ``lower`` and ``compile`` (the latter a load where JAX's persistent
+    cache holds the program)."""
     exe = _EXEC_CACHE.get(key)
     if exe is None:
-        exe = jit_fn.lower(*args, **kwargs).compile()
+        with spans.span("lower"):
+            lowered = jit_fn.lower(*args, **kwargs)
+        with spans.span("compile"):
+            exe = lowered.compile()
         _EXEC_CACHE[key] = exe
     return exe
 

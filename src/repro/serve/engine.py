@@ -82,6 +82,7 @@ from repro.models import transformer as tf
 from repro.numerics.ops import (ATTN_FALLBACK_KEY, INTERP_BACKENDS,
                                 count_attention_fallbacks, get_numerics)
 from repro.serve import aot as aot_mod
+from repro.serve import spans as span_lib
 from repro.serve.journal import ServeJournal, load_requests
 from repro.serve.pipeline import HostPipeline
 
@@ -258,6 +259,12 @@ class Request:
     done: bool = False
     deadline: float | None = None  # absolute engine-clock seconds
     error: str | None = None  # structured failure ("deadline_exceeded", ...)
+    # lifecycle stamps, engine-clock seconds (None until reached):
+    submitted_at: float | None = None  # accepted by submit()
+    admitted_at: float | None = None  # just before its admitting dispatch
+    first_token_at: float | None = None  # first token on the host
+    first_token_returned_at: float | None = None  # end of that step()
+    finished_at: float | None = None  # end of the step() it ended in
 
 
 class ServeEngine:
@@ -297,7 +304,11 @@ class ServeEngine:
                          a slot.
     ``clock``            monotonic clock (injectable:
                          ``repro.faults.FaultClock`` drives deadline and
-                         stall tests without sleeping).
+                         stall tests without sleeping). The engine's
+                         ``engine.*`` spans (:mod:`repro.serve.spans`,
+                         :meth:`spans`) and the ``Request`` stamps are
+                         taken on it; the stall watchdog reads the
+                         ``engine.tick`` span.
     ``watchdog_limit``   watchdog trips (non-finite tick output, stalled
                          tick) tolerated before degrading one ladder rung.
     ``max_tick_s``       stall watchdog: a tick exceeding this wall budget
@@ -363,113 +374,135 @@ class ServeEngine:
         self.watchdog_limit = max(1, int(watchdog_limit))
         self.max_tick_s = max_tick_s
         self.verify_rom_every = max(0, int(verify_rom_every))
-        if cfg.sliding_window is not None and cache_len < cfg.sliding_window:
-            # the wrapped decode slot (pos % cache) would overwrite KV rows
-            # that are still inside the attention window — silent context
-            # loss on every wrap; serving must retain the full window
-            raise ValueError(
-                f"cache_len {cache_len} < sliding_window "
-                f"{cfg.sliding_window}: a windowed engine must retain the "
-                f"full attention window")
-        if not _interp(cfg):
-            if library is not None:
+        # always-on span recorder on the engine clock (repro.serve.spans);
+        # the newest engine's recorder is the process default
+        self._spans = span_lib.SpanRecorder(clock)
+        span_lib.set_default(self._spans)
+        with self._spans.span("engine.init"):
+            if (cfg.sliding_window is not None
+                    and cache_len < cfg.sliding_window):
+                # the wrapped decode slot (pos % cache) would overwrite KV
+                # rows that are still inside the attention window — silent
+                # context loss on every wrap; serving must retain the full
+                # window
                 raise ValueError(
-                    f"library passed to ServeEngine but cfg.numerics="
-                    f"{cfg.numerics!r} never consults it; drop the library "
-                    f"or serve with numerics='interp'")
-        elif library is None:
-            # The library manifest replaces the hand-maintained warm-up kind
-            # set: Explorer.compile() packs every table the interp numerics
-            # can touch (activations hardcoded by MoE/SSM layers and the
-            # vision-stub projector included), so a kind can't be forgotten
-            # here again. To serve from a custom session (cache dir, worker
-            # pool), install it with repro.api.set_default_explorer() before
-            # constructing the engine — or pass a compiled/loaded library.
-            # A plan engine compiles one library per distinct plan slot and
-            # threads the dict as a pytree (each value replicates/donates
-            # like the single-library case).
-            if cfg.plan is not None:
-                from repro.plan.numerics import compile_plan_libraries
+                    f"cache_len {cache_len} < sliding_window "
+                    f"{cfg.sliding_window}: a windowed engine must retain the "
+                    f"full attention window")
+            if not _interp(cfg):
+                if library is not None:
+                    raise ValueError(
+                        f"library passed to ServeEngine but cfg.numerics="
+                        f"{cfg.numerics!r} never consults it; drop the "
+                        f"library or serve with numerics='interp'")
+            elif library is None:
+                # The library manifest replaces the hand-maintained warm-up
+                # kind set: Explorer.compile() packs every table the interp
+                # numerics can touch (activations hardcoded by MoE/SSM layers
+                # and the vision-stub projector included), so a kind can't be
+                # forgotten here again. To serve from a custom session (cache
+                # dir, worker pool), install it with
+                # repro.api.set_default_explorer() before constructing the
+                # engine — or pass a compiled/loaded library. A plan engine
+                # compiles one library per distinct plan slot and threads the
+                # dict as a pytree (each value replicates/donates like the
+                # single-library case).
+                if cfg.plan is not None:
+                    from repro.plan.numerics import compile_plan_libraries
 
-                library = compile_plan_libraries(cfg.plan)
+                    library = compile_plan_libraries(cfg.plan)
+                else:
+                    library = default_explorer().compile()
+            self.library = library
+            self.numerics = get_numerics(
+                cfg, library, fused=self.fused and _interp(cfg))
+            self.caches = tf.init_cache(cfg, slots, cache_len)
+            self.pos = np.zeros(slots, np.int32)  # next position per slot
+            self.cur = np.full(slots, -1, np.int32)  # current token per slot
+            self.req: list[Request | None] = [None] * slots
+            self.queue: collections.deque[Request] = collections.deque()
+            self.finished: list[Request] = []
+            self.failed: list[Request] = []
+            # plan engines attribute degradations per layer label ("0", "7",
+            # "rest", or "engine" for whole-ladder rungs); plan-less engines
+            # keep the historical scalar counter
+            # decode_live_slot_steps: live slots x decode steps, per tick
+            self.stats = {"dispatches": 0, "transfers": 0, "ticks": 0,
+                          "decode_steps": 0, "decode_live_slot_steps": 0,
+                          "rejected": 0, "expired": 0,
+                          "watchdog_trips": 0,
+                          "degradations": {} if cfg.plan is not None else 0,
+                          "rom_verifies": 0, "rom_faults": 0,
+                          "slot_failures": 0,
+                          "resumed": 0, "resume_skipped_done": 0,
+                          "resume_replay_steps": 0,
+                          "aot_compiles": 0, "aot_hits": 0, "aot_misses": 0,
+                          "aot_reshards": 0, "aot_fallbacks": 0,
+                          "packed_admits": 0,
+                          "packed_requests": 0, "admit_dispatches": 0,
+                          "async_chunks": 0, "async_tokens": 0,
+                          ATTN_FALLBACK_KEY: 0}
+            self.faults: list[dict] = []  # structured fault/degradation log
+            self._trips = 0  # watchdog trips since the last degradation
+            # requests the running step() admitted, and those it ended:
+            # stamped with the step's end when it returns
+            self._firsts_now: list[Request] = []
+            self._ended_now: list[Request] = []
+            self.journal = (journal if isinstance(journal, (ServeJournal,
+                                                            type(None)))
+                            else ServeJournal(journal))
+            # device-resident slot state (fused path): current token, next
+            # position, liveness — donated through the tick alongside the
+            # caches
+            self._tok_dev = jnp.zeros((slots, 1), jnp.int32)
+            self._pos_dev = jnp.zeros((slots,), jnp.int32)
+            self._live_dev = jnp.zeros((slots,), jnp.bool_)
+            # sharded / AOT-warmed / async serving tier (DESIGN.md §17)
+            self.mesh = mesh
+            self._mesh_key = aot_mod.mesh_key(mesh)
+            # per-slot emitted-token counts owned by the MAIN thread:
+            # retirement and chunk sizing cannot read len(Request.out) once
+            # the async pipeline extends it from the worker
+            self._emitted = np.zeros(slots, np.int64)
+            # bucketed (padded) prefill packing is only sound for pure
+            # attention-cache decoders: SSM state is cumulative, windowed
+            # caches wrap, encoder/frontend extras carry no per-row length
+            self._packable = (
+                cfg.sliding_window is None and cfg.encoder is None
+                and cfg.frontend is None
+                and not any(k.mixer == "ssm" for seg in tf.layer_plan(cfg)
+                            for k in seg.pattern))
+            if aot_buckets is None:
+                self.aot_buckets = None
+            elif aot_buckets is True:
+                self.aot_buckets = aot_mod.BucketTable.for_cache(cache_len)
+            elif isinstance(aot_buckets, aot_mod.BucketTable):
+                self.aot_buckets = aot_mod.BucketTable.for_cache(
+                    cache_len, aot_buckets.buckets)
             else:
-                library = default_explorer().compile()
-        self.library = library
-        self.numerics = get_numerics(
-            cfg, library, fused=self.fused and _interp(cfg))
-        self.caches = tf.init_cache(cfg, slots, cache_len)
-        self.pos = np.zeros(slots, np.int32)  # next position per slot
-        self.cur = np.full(slots, -1, np.int32)  # current token per slot
-        self.req: list[Request | None] = [None] * slots
-        self.queue: collections.deque[Request] = collections.deque()
-        self.finished: list[Request] = []
-        self.failed: list[Request] = []
-        # plan engines attribute degradations per layer label ("0", "7",
-        # "rest", or "engine" for whole-ladder rungs); plan-less engines
-        # keep the historical scalar counter
-        self.stats = {"dispatches": 0, "transfers": 0, "ticks": 0,
-                      "decode_steps": 0, "rejected": 0, "expired": 0,
-                      "watchdog_trips": 0,
-                      "degradations": {} if cfg.plan is not None else 0,
-                      "rom_verifies": 0, "rom_faults": 0, "slot_failures": 0,
-                      "resumed": 0, "resume_skipped_done": 0,
-                      "resume_replay_steps": 0,
-                      "aot_compiles": 0, "aot_hits": 0, "aot_misses": 0,
-                      "aot_reshards": 0, "aot_fallbacks": 0,
-                      "packed_admits": 0,
-                      "packed_requests": 0, "admit_dispatches": 0,
-                      "async_chunks": 0, "async_tokens": 0,
-                      ATTN_FALLBACK_KEY: 0}
-        self.faults: list[dict] = []  # structured fault/degradation log
-        self._trips = 0  # watchdog trips since the last degradation
-        self.journal = (journal if isinstance(journal, (ServeJournal,
-                                                        type(None)))
-                        else ServeJournal(journal))
-        # device-resident slot state (fused path): current token, next
-        # position, liveness — donated through the tick alongside the caches
-        self._tok_dev = jnp.zeros((slots, 1), jnp.int32)
-        self._pos_dev = jnp.zeros((slots,), jnp.int32)
-        self._live_dev = jnp.zeros((slots,), jnp.bool_)
-        # ISSUE 10: sharded / AOT-warmed / async serving tier (DESIGN.md §17)
-        self.mesh = mesh
-        self._mesh_key = aot_mod.mesh_key(mesh)
-        # per-slot emitted-token counts owned by the MAIN thread: retirement
-        # and chunk sizing cannot read len(Request.out) once the async
-        # pipeline extends it from the worker
-        self._emitted = np.zeros(slots, np.int64)
-        # bucketed (padded) prefill packing is only sound for pure
-        # attention-cache decoders: SSM state is cumulative, windowed caches
-        # wrap, encoder/frontend extras carry no per-row length
-        self._packable = (
-            cfg.sliding_window is None and cfg.encoder is None
-            and cfg.frontend is None
-            and not any(k.mixer == "ssm" for seg in tf.layer_plan(cfg)
-                        for k in seg.pattern))
-        if aot_buckets is None:
-            self.aot_buckets = None
-        elif aot_buckets is True:
-            self.aot_buckets = aot_mod.BucketTable.for_cache(cache_len)
-        elif isinstance(aot_buckets, aot_mod.BucketTable):
-            self.aot_buckets = aot_mod.BucketTable.for_cache(
-                cache_len, aot_buckets.buckets)
-        else:
-            self.aot_buckets = aot_mod.BucketTable.for_cache(
-                cache_len, aot_buckets)
-        self._pack_sizes = aot_mod.pack_sizes(max_pack, slots)
-        if async_host and not self.fused:
-            raise ValueError(
-                "async_host=True requires the fused engine: the serial "
-                "per-op path is the synchronous oracle/baseline")
-        self.pipeline = (HostPipeline(journal=self.journal,
-                                      depth=pipeline_depth)
-                         if async_host else None)
-        if mesh is not None:
-            self._shard_state()
-        self._build_programs()
-        self._warm_aot()
-        # serve-time ROM integrity: the load-time checksum catches a corrupt
-        # artifact; this catches the resident copy going bad afterwards
-        self.verify_library()
+                self.aot_buckets = aot_mod.BucketTable.for_cache(
+                    cache_len, aot_buckets)
+            self._pack_sizes = aot_mod.pack_sizes(max_pack, slots)
+            if async_host and not self.fused:
+                raise ValueError(
+                    "async_host=True requires the fused engine: the serial "
+                    "per-op path is the synchronous oracle/baseline")
+            self.pipeline = (HostPipeline(journal=self.journal,
+                                          depth=pipeline_depth, clock=clock)
+                             if async_host else None)
+            if mesh is not None:
+                self._shard_state()
+            self._build_programs()
+            self._warm_aot()
+            # serve-time ROM integrity: the load-time checksum catches a
+            # corrupt artifact; this catches the resident copy going bad
+            # afterwards
+            self.verify_library()
+
+    def spans(self) -> list:
+        """The engine's finished spans still in its ring, oldest first
+        (:class:`repro.serve.spans.Span`; engine-clock seconds)."""
+        return self._spans.spans()
 
     def _shard_state(self) -> None:
         """Place params/caches/slot-state/library on the serve mesh: KV pool
@@ -538,16 +571,18 @@ class ServeEngine:
             raise ValueError("aot_buckets requires the fused engine")
         rep = (shlib.replicated(self.mesh) if self.mesh is not None
                else None)
-        with self._ctx():
+        sp = self._spans.span
+        with sp("engine.aot"), self._ctx():
             for steps in aot_mod.tick_chunk_sizes(self.horizon):
                 key = self._aot_key("tick", steps)
                 if aot_mod.lookup(key) is None:
                     self.stats["aot_compiles"] += 1
-                aot_mod.compile_cached(
-                    key, self._tick_jit(steps),
-                    (self.params, self._tok_dev, self._pos_dev,
-                     self._live_dev, self.caches),
-                    {"library": self.library})
+                with sp("engine.aot.program", key=f"tick/{steps}"):
+                    aot_mod.compile_cached(
+                        key, self._tick_jit(steps),
+                        (self.params, self._tok_dev, self._pos_dev,
+                         self._live_dev, self.caches),
+                        {"library": self.library}, self._spans)
             if not self._packable:
                 return
             for bucket in self.aot_buckets.buckets:
@@ -562,11 +597,14 @@ class ServeEngine:
                     key = self._aot_key("admit_packed", bucket, pk)
                     if aot_mod.lookup(key) is None:
                         self.stats["aot_compiles"] += 1
-                    aot_mod.compile_cached(
-                        key, self._packed_jit(pk),
-                        (self.params, prompts, lens, slots0, self.caches,
-                         self._tok_dev, self._pos_dev, self._live_dev),
-                        {"library": self.library})
+                    with sp("engine.aot.program",
+                            key=f"admit_packed/{bucket}/{pk}"):
+                        aot_mod.compile_cached(
+                            key, self._packed_jit(pk),
+                            (self.params, prompts, lens, slots0,
+                             self.caches, self._tok_dev, self._pos_dev,
+                             self._live_dev),
+                            {"library": self.library}, self._spans)
 
     # -- program construction (re-run on every degradation rung) ----------
     def _build_programs(self) -> None:
@@ -705,6 +743,10 @@ class ServeEngine:
         corrupt ROM)."""
         if self.library is None:
             return True
+        with self._spans.span("engine.verify_rom"):
+            return self._verify_library()
+
+    def _verify_library(self) -> bool:
         self.stats["rom_verifies"] += 1
         if isinstance(self.library, dict):
             bad: list[tuple[str, str]] = []
@@ -831,6 +873,7 @@ class ServeEngine:
             return
         r.error = error
         self.failed.append(r)
+        self._ended_now.append(r)
         self.stats["slot_failures"] += 1
         self.req[s] = None
         self.cur[s] = -1
@@ -899,6 +942,7 @@ class ServeEngine:
                            f"request {req.rid}: already past its deadline")
         self._journal("submit", req.rid, req.prompt, req.max_new,
                       req.deadline, crash="serve.submit.journaled")
+        req.submitted_at = self.clock()
         self.queue.append(req)
 
     def _expired(self, r: Request) -> bool:
@@ -915,6 +959,7 @@ class ServeEngine:
         """Expired while queued: fail without burning a prefill."""
         r.error = "deadline_exceeded"
         self.failed.append(r)
+        self._ended_now.append(r)
         self.stats["expired"] += 1
         self._journal("fail", r.rid, r.error)
 
@@ -937,6 +982,22 @@ class ServeEngine:
         path; also the bucketed path's fallback for prompts longer than
         every bucket)."""
         self.stats["admit_dispatches"] += 1
+        with self._spans.span("engine.prefill", bucket=len(r.prompt), pack=1,
+                              rids=(r.rid,)):
+            tok = self._prefill_one(r, s)
+        self._firsts_now.append(r)
+        if tok is None:
+            return
+        r.out.append(tok)
+        self.cur[s] = tok
+        if self.journal is not None:
+            self.journal.emit(r.rid, [tok])
+            crashpoint("serve.admit.emitted")
+
+    def _prefill_one(self, r: Request, s: int) -> int | None:
+        """The dispatch of :meth:`_admit_one`; returns the first token, or
+        None where the async pipeline downloads it."""
+        r.admitted_at = self.clock()
         if self.fused:
             # one dispatch: prefill + in-place pool splice + greedy
             # first token + slot-state update (donated buffers)
@@ -953,8 +1014,9 @@ class ServeEngine:
                 # first-token download + journal emit happen on the worker,
                 # in order with every other journal write
                 self.pipeline.emit_admit(((0, r),), first)
-                return
-            tok = int(first)
+                return None
+            with self._spans.span("engine.sync") as sync:
+                tok = int(first)
         else:
             with self._ctx():
                 logits, cache1, _ = self._prefill1(
@@ -964,15 +1026,13 @@ class ServeEngine:
                 # knows the stacked-layer layout); the pool buffer is
                 # donated — the insertion is in place, not a pool copy
                 self.caches = self._splice(self.caches, cache1, s)
-                tok = int(jnp.argmax(logits[0, -1]))
+                with self._spans.span("engine.sync") as sync:
+                    tok = int(jnp.argmax(logits[0, -1]))
             self.req[s] = r
             self.pos[s] = len(r.prompt)
             self._emitted[s] = 1
-        r.out.append(tok)
-        self.cur[s] = tok
-        if self.journal is not None:
-            self.journal.emit(r.rid, [tok])
-            crashpoint("serve.admit.emitted")
+        r.first_token_at = sync.span.t1
+        return tok
 
     def _admit_bucketed(self):
         """Bucketed admission (DESIGN.md §17): drain the queue front into
@@ -1013,6 +1073,24 @@ class ServeEngine:
 
     def _admit_packed(self, sub: list, bucket: int) -> None:
         """One padded prefill dispatch admitting ``len(sub)`` requests."""
+        with self._spans.span("engine.prefill", bucket=bucket, pack=len(sub),
+                              rids=tuple(r.rid for r, _s in sub)):
+            vals = self._prefill_packed(sub, bucket)
+        self._firsts_now.extend(r for r, _s in sub)
+        if vals is None:
+            return
+        for i, (r, s) in enumerate(sub):
+            tok = int(vals[i])
+            r.out.append(tok)
+            self.cur[s] = tok
+            if self.journal is not None:
+                self.journal.emit(r.rid, [tok])
+        if self.journal is not None:
+            crashpoint("serve.admit.emitted")
+
+    def _prefill_packed(self, sub: list, bucket: int):
+        """The dispatch of :meth:`_admit_packed`; returns the first tokens
+        (P,), or None where the async pipeline downloads them."""
         pk = len(sub)
         prompts = np.zeros((pk, bucket), np.int32)
         lens = np.zeros(pk, np.int32)
@@ -1030,6 +1108,9 @@ class ServeEngine:
             # arrays must arrive committed-replicated like the lowering saw
             rep = shlib.replicated(self.mesh)
             args = tuple(jax.device_put(a, rep) for a in args)
+        t = self.clock()
+        for r, _s in sub:
+            r.admitted_at = t
         with self._ctx():
             (firsts, self.caches, self._tok_dev, self._pos_dev,
              self._live_dev) = fn(
@@ -1045,16 +1126,12 @@ class ServeEngine:
         if self.pipeline is not None:
             self.pipeline.emit_admit(
                 tuple((i, r) for i, (r, _s) in enumerate(sub)), firsts)
-            return
-        vals = np.asarray(jax.device_get(firsts)).reshape(-1)
-        for i, (r, s) in enumerate(sub):
-            tok = int(vals[i])
-            r.out.append(tok)
-            self.cur[s] = tok
-            if self.journal is not None:
-                self.journal.emit(r.rid, [tok])
-        if self.journal is not None:
-            crashpoint("serve.admit.emitted")
+            return None
+        with self._spans.span("engine.sync") as sync:
+            vals = np.asarray(jax.device_get(firsts)).reshape(-1)
+        for r, _s in sub:
+            r.first_token_at = sync.span.t1
+        return vals
 
     def _admit_replay(self, r: Request, s: int):
         """Re-admit a journal-recovered in-flight request at its recorded
@@ -1067,6 +1144,7 @@ class ServeEngine:
         and nothing is re-journaled."""
         prefill = self._prefill_fnum if self.fused else self._prefill1
         decode = self._decode_fnum if self.fused else self._decode
+        r.admitted_at = self.clock()
         with self._ctx():
             _logits, cache1, _ = prefill(self.params, r.prompt[None, :],
                                          library=self.library)
@@ -1097,6 +1175,7 @@ class ServeEngine:
             if self._emitted[s] >= r.max_new:
                 r.done = True
                 self.finished.append(r)
+                self._ended_now.append(r)
                 self.req[s] = None
                 self.cur[s] = -1
                 self.pos[s] = 0
@@ -1127,13 +1206,31 @@ class ServeEngine:
         admission token already fills its budget (``max_new <= 1``) still
         decodes once before retiring. The default ``step()`` performs
         exactly one decode step either way.
+
+        The step runs inside the ``engine.step`` span (``engine.admit``,
+        ``engine.tick``, ``engine.retire`` inside it); the requests whose
+        first token, or end, it produced are stamped with its end
+        (``first_token_returned_at``, ``finished_at``).
         """
+        with self._spans.span("engine.step") as step:
+            busy = self._step(max_steps)
+        t = step.span.t1
+        for r in self._firsts_now:
+            r.first_token_returned_at = t
+        for r in self._ended_now:
+            r.finished_at = t
+        self._firsts_now.clear()
+        self._ended_now.clear()
+        return busy
+
+    def _step(self, max_steps: int):
         if self.pipeline is not None:
             self.pipeline.check()
         if (self.verify_rom_every
                 and self.stats["ticks"] % self.verify_rom_every == 0):
             self.verify_library()
-        self._admit()
+        with self._spans.span("engine.admit"):
+            self._admit()
         if all(r is None for r in self.req):
             if self.pipeline is not None:
                 # idle: everything queued behind us is the backlog — drain
@@ -1149,24 +1246,33 @@ class ServeEngine:
         # then reuse log2(horizon)+1 compiled tick programs (1, 2, 4, ...)
         # instead of jitting one decode-scan per distinct tail length
         steps = 1 << (steps.bit_length() - 1)
-        t0 = self.clock()
-        with self._ctx():
-            (toks, self._tok_dev, self._pos_dev, ok_dev,
-             self.caches) = self._tick_fn(steps)(
-                self.params, self._tok_dev, self._pos_dev, self._live_dev,
-                self.caches, library=self.library)
-        self.stats["dispatches"] += 1  # the tick program
+        live = sum(r is not None for r in self.req)
+        with self._spans.span("engine.tick", steps=steps, live=live) as tick:
+            with self._ctx():
+                (toks, self._tok_dev, self._pos_dev, ok_dev,
+                 self.caches) = self._tick_fn(steps)(
+                    self.params, self._tok_dev, self._pos_dev,
+                    self._live_dev, self.caches, library=self.library)
+            self.stats["dispatches"] += 1  # the tick program
+            with self._spans.span("engine.sync"):
+                if self.pipeline is not None:
+                    # async host path: only the (B,) watchdog sentinel
+                    # comes down synchronously (poison detection timing
+                    # unchanged); the token block download + detokenize +
+                    # journal emits ride the worker
+                    ok = np.asarray(jax.device_get(ok_dev))
+                else:
+                    # ONE device->host round-trip: the (steps, B) token
+                    # block and the (B,) watchdog sentinel come down
+                    # together
+                    out, ok = jax.device_get((toks, ok_dev))
+        self.stats["transfers"] += 1
+        self.stats["ticks"] += 1
+        self.stats["decode_steps"] += steps
+        self.stats["decode_live_slot_steps"] += live * steps
+        poisoned = [s for s, r in enumerate(self.req)
+                    if r is not None and not bool(ok[s])]
         if self.pipeline is not None:
-            # async host path: only the (B,) watchdog sentinel comes down
-            # synchronously (poison detection timing unchanged); the token
-            # block download + detokenize + journal emits ride the worker
-            ok = np.asarray(jax.device_get(ok_dev))
-            self.stats["transfers"] += 1
-            self.stats["ticks"] += 1
-            self.stats["decode_steps"] += steps
-            tick_s = self.clock() - t0
-            poisoned = [s for s, r in enumerate(self.req)
-                        if r is not None and not bool(ok[s])]
             alive = tuple((s, r) for s, r in enumerate(self.req)
                           if r is not None and s not in poisoned)
             if alive:
@@ -1175,15 +1281,6 @@ class ServeEngine:
                 self._emitted[s] += steps
                 self.pos[s] += steps
         else:
-            # ONE device->host round-trip: the (steps, B) token block and
-            # the (B,) watchdog sentinel come down together
-            out, ok = jax.device_get((toks, ok_dev))
-            self.stats["transfers"] += 1
-            self.stats["ticks"] += 1
-            self.stats["decode_steps"] += steps
-            tick_s = self.clock() - t0
-            poisoned = [s for s, r in enumerate(self.req)
-                        if r is not None and not bool(ok[s])]
             for s, r in enumerate(self.req):
                 if r is not None and s not in poisoned:
                     fresh = [int(t) for t in out[:, s]]
@@ -1195,6 +1292,12 @@ class ServeEngine:
                         self.journal.emit(r.rid, fresh)
             if self.journal is not None:
                 crashpoint("serve.tick.emitted")
+        self._after_tick(poisoned, tick.span.dur)
+        return True
+
+    def _after_tick(self, poisoned: list, tick_s: float) -> None:
+        """Watchdog and retirement after a tick of ``tick_s`` engine-clock
+        seconds (the ``engine.tick`` span)."""
         for s in poisoned:
             # a poisoned slot is retired with a structured error — its
             # chunk of garbage tokens is never streamed or journaled
@@ -1205,8 +1308,8 @@ class ServeEngine:
         if self.max_tick_s is not None and tick_s > self.max_tick_s:
             self._watchdog_trip("stalled_tick",
                                 detail=f"{tick_s:.3f}s > {self.max_tick_s}s")
-        self._retire()
-        return True
+        with self._spans.span("engine.retire"):
+            self._retire()
 
     def _step_serial(self):
         """The ISSUE-3/4 per-op tick: token upload, one decode dispatch, a
@@ -1216,18 +1319,21 @@ class ServeEngine:
         toks = jnp.asarray(np.maximum(self.cur, 0)[:, None], jnp.int32)
         pos = jnp.asarray(self.pos, jnp.int32)
         self.stats["transfers"] += 2  # token + position upload
-        t0 = self.clock()
-        with self._ctx():
-            logits, self.caches = self._decode(
-                self.params, toks, pos, self.caches, library=self.library)
-        self.stats["dispatches"] += 1  # decode program
-        nxt_dev, ok_dev = self._argmax_ok(logits)
-        self.stats["dispatches"] += 1  # argmax+sentinel program
-        nxt, ok = jax.device_get((nxt_dev, ok_dev))
+        live = sum(r is not None for r in self.req)
+        with self._spans.span("engine.tick", steps=1, live=live) as tick:
+            with self._ctx():
+                logits, self.caches = self._decode(
+                    self.params, toks, pos, self.caches,
+                    library=self.library)
+            self.stats["dispatches"] += 1  # decode program
+            nxt_dev, ok_dev = self._argmax_ok(logits)
+            self.stats["dispatches"] += 1  # argmax+sentinel program
+            with self._spans.span("engine.sync"):
+                nxt, ok = jax.device_get((nxt_dev, ok_dev))
         self.stats["transfers"] += 1  # next-token (+ sentinel) download
         self.stats["ticks"] += 1
         self.stats["decode_steps"] += 1
-        tick_s = self.clock() - t0
+        self.stats["decode_live_slot_steps"] += live
         poisoned = [s for s, r in enumerate(self.req)
                     if r is not None and not bool(ok[s])]
         for s, r in enumerate(self.req):
@@ -1240,15 +1346,7 @@ class ServeEngine:
                     self.journal.emit(r.rid, [int(nxt[s])])
         if self.journal is not None:
             crashpoint("serve.tick.emitted")
-        for s in poisoned:
-            self._fail_slot(s, "non_finite_output")
-        if poisoned:
-            self._watchdog_trip("non_finite_output",
-                                detail=f"slots {poisoned}")
-        if self.max_tick_s is not None and tick_s > self.max_tick_s:
-            self._watchdog_trip("stalled_tick",
-                                detail=f"{tick_s:.3f}s > {self.max_tick_s}s")
-        self._retire()
+        self._after_tick(poisoned, tick.span.dur)
         return True
 
     def run(self, max_ticks: int = 10_000) -> list[Request]:

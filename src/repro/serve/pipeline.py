@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
+from typing import Callable
 
 import jax
 import numpy as np
@@ -39,8 +41,10 @@ import numpy as np
 class HostPipeline:
     """One background worker consuming (chunk | admit | journal) items."""
 
-    def __init__(self, journal=None, depth: int = 4):
+    def __init__(self, journal=None, depth: int = 4,
+                 clock: Callable[[], float] = time.monotonic):
         self.journal = journal
+        self.clock = clock  # stamps Request.first_token_at on the worker
         self._q: queue.Queue = queue.Queue(maxsize=max(1, int(depth)))
         self._lock = threading.Lock()
         self._stats = {"transfers": 0, "chunks": 0, "tokens": 0}
@@ -131,9 +135,11 @@ class HostPipeline:
                 elif kind == "admit":
                     _, items, firsts = item
                     vals = np.asarray(jax.device_get(firsts)).reshape(-1)
+                    t = self.clock()
                     self._bump(transfers=1, tokens=len(items))
                     for row, req in items:
                         tok = int(vals[row])
+                        req.first_token_at = t
                         req.out.append(tok)
                         if self.journal is not None:
                             self.journal.emit(req.rid, [tok])

@@ -179,6 +179,45 @@ def test_flash_compiles(one_chip, no_cache, lib, case):
              one_chip)
 
 
+def test_served_kernels_carry_their_names(one_chip, no_cache, lib):
+    """Each kernel of the served path is named in the compiled program (the
+    instruction name the device trace's ``XLA Ops`` events carry), not left
+    as an anonymous ``closed_call``; the activation kernel keeps its
+    ``_library_eval`` prefix."""
+    import re
+
+    walk, dp = lib.walk_rows()
+
+    def fn(q, k, v, q_pos, kv_pos, x, gamma, codes, library, walk, dp):
+        o = attention_fused_library(q, k, v, library, q_pos=q_pos,
+                                    kv_pos=kv_pos, use_kernel=True,
+                                    interpret=False)
+        y = approx_rmsnorm_library(x, gamma, library, use_kernel=True,
+                                   interpret=False)
+        z = approx_softmax_library(x.astype(jnp.float32), library,
+                                   use_kernel=True, interpret=False)
+        e = library.eval_fused(codes, codes, use_kernel=True,
+                               interpret=False)
+        w = library_walk(codes, codes, library.coeffs, walk, dp,
+                         use_kernel=True, interpret=False)
+        return o, y, z, e, w
+
+    text = _compile(fn, (_sds((8, 1, 32, 128), jnp.bfloat16),
+                         _sds((8, 512, 4, 128), jnp.bfloat16),
+                         _sds((8, 512, 4, 128), jnp.bfloat16),
+                         _sds((8, 1), jnp.int32), _sds((8, 512), jnp.int32),
+                         _sds((8, 4096), jnp.bfloat16),
+                         _sds((4096,), jnp.float32),
+                         _sds((64, 128), jnp.int32), lib, walk, dp),
+                    one_chip)
+    instr = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = ")
+    names = {re.sub(r"\.\d+$", "", instr.match(line).group(1))
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert names == {"flash_lib", "rmsnorm_lib", "softmax_lib",
+                     "_library_eval", "_library_walk"}, names
+
+
 # ---------------------------------------------------------------------------
 # design-space kernels: off the serving path, not Mosaic-compilable yet
 # ---------------------------------------------------------------------------
