@@ -7,11 +7,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.table import TableDesign
-from repro.kernels.flashattn.kernel import flash_attention, flash_attention_lib
+from repro.kernels.flashattn.kernel import (BLOCK_Q, flash_attention,
+                                            flash_attention_lib)
 from repro.kernels.flashattn.ref import (flash_attention_lib_ref,
                                          flash_attention_ref)
 from repro.kernels.softmax.ops import _meta, lib_meta
 from repro.launch.sharding import local_map, rule_spec
+from repro.numerics.ops import ATTN_FOLD_KEY, note_attention_site
 from repro.api import get_table
 
 
@@ -21,6 +23,39 @@ def _block(n: int) -> int:
         if n % b == 0:
             return b
     return 8
+
+
+def _folds(sq: int, h: int, kvh: int) -> bool:
+    """A GQA group's query rows fit one tile: one program per kv stripe
+    takes all of them (decode, short chunks) instead of one per query
+    head, each re-walking the same stripe."""
+    g = h // kvh
+    return g > 1 and sq * g <= BLOCK_Q
+
+
+def _to_rows(q, q_pos, kvh: int, fold: bool):
+    """(B, Sq, H, D) queries -> (N, R, D) program rows and their (N, R)
+    positions. Folded: N = B * kvh and R = Sq * g, row s * g + i being
+    query head kv * g + i at query s (at Sq = 1 a pure reshape); else
+    N = B * H and R = Sq."""
+    b, sq, h, d = q.shape
+    qp = q_pos.astype(jnp.int32)
+    if not fold:
+        return (q.transpose(0, 2, 1, 3).reshape(b * h, sq, d),
+                jnp.repeat(qp, h, axis=0))
+    g = h // kvh
+    qn = q.reshape(b, sq, kvh, g, d).transpose(0, 2, 1, 3, 4)
+    return (qn.reshape(b * kvh, sq * g, d),
+            jnp.repeat(jnp.repeat(qp, g, axis=1), kvh, axis=0))
+
+
+def _from_rows(o, b: int, sq: int, h: int, kvh: int, fold: bool):
+    """Inverse of ``_to_rows`` on the (N, R, Dv) output."""
+    dv = o.shape[-1]
+    if not fold:
+        return o.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+    o = o.reshape(b, kvh, sq, h // kvh, dv).transpose(0, 2, 1, 3, 4)
+    return o.reshape(b, sq, h, dv)
 
 
 def attention_fused_library(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -37,18 +72,23 @@ def attention_fused_library(q: jax.Array, k: jax.Array, v: jax.Array,
     static func ids in-kernel). ``q_pos`` / ``kv_pos``: (B, S*) absolute
     positions (-1 = dead KV slot), the decode-against-cache contract of
     ``models.attention.attention_core``; ``None`` means the training layout
-    (``arange``). GQA passes k/v with their own (fewer) heads — the kernel
-    maps each query-head program onto its kv stripe by index (never
-    materializing the expansion); Dk may differ from Dv (MLA).
-    ``use_kernel=None`` picks the Pallas kernel on TPU and the unchunked
-    jnp oracle elsewhere; the kernel path pads Sq/Sk to tile multiples
-    with masked (-1) positions, and on a mesh runs per device on its batch
-    rows and kv-head groups (``local_map``).
+    (``arange``). GQA passes k/v with their own (fewer) heads, never
+    materialized per query head; Dk may differ from Dv (MLA). When a
+    group's Sq * g query rows fit one tile (decode), they are folded into
+    the rows of one program per kv stripe (counted under
+    ``ATTN_FOLD_KEY``); otherwise each query-head program maps onto its kv
+    stripe by index. ``use_kernel=None`` picks the Pallas kernel on TPU and
+    the unchunked jnp oracle elsewhere; the kernel path pads rows and Sk to
+    tile multiples with masked (-1) positions, and on a mesh runs per
+    device on its batch rows and kv-head groups (``local_map``).
     """
     b, sq, h, d = q.shape
     sk, kvh, dv = k.shape[1], k.shape[2], v.shape[-1]
     assert h % kvh == 0, (h, kvh)
     em, rm = lib_meta(library, "exp2neg"), lib_meta(library, "recip")
+    fold = _folds(sq, h, kvh)
+    if fold:
+        note_attention_site(ATTN_FOLD_KEY)
     if q_pos is None:
         q_pos = jnp.broadcast_to(jnp.arange(sq, dtype=jnp.int32), (b, sq))
     if kv_pos is None:
@@ -56,29 +96,28 @@ def attention_fused_library(q: jax.Array, k: jax.Array, v: jax.Array,
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
-        # the unchunked oracle takes one kv stripe per query row
-        g = h // kvh
-        qn = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-        kn = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1
-                        ).reshape(b * h, sk, -1)
-        vn = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1
-                        ).reshape(b * h, sk, dv)
-        qp = jnp.repeat(q_pos.astype(jnp.int32), h, axis=0)  # (B*H, Sq)
-        kp = jnp.repeat(kv_pos.astype(jnp.int32), h, axis=0)
+        # the unchunked oracle takes one kv stripe per program
+        n = kvh if fold else h
+        qn, qp = _to_rows(q, q_pos, kvh, fold)
+        kn = jnp.repeat(k.transpose(0, 2, 1, 3), n // kvh, axis=1
+                        ).reshape(b * n, sk, -1)
+        vn = jnp.repeat(v.transpose(0, 2, 1, 3), n // kvh, axis=1
+                        ).reshape(b * n, sk, dv)
+        kp = jnp.repeat(kv_pos.astype(jnp.int32), n, axis=0)
         o = flash_attention_lib_ref(qn, kn, vn, qp, kp, library.coeffs, em,
                                     rm, causal=causal, window=window,
                                     scale=scale)
-        return o.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+        return _from_rows(o, b, sq, h, kvh, fold)
 
     def kernel(q, k, v, q_pos, kv_pos, coeffs):
         b, _, h, _ = q.shape
         kvh = k.shape[2]
-        qn = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        qn, qp = _to_rows(q, q_pos, kvh, fold)
         kn = k.transpose(0, 2, 1, 3).reshape(b * kvh, sk, k.shape[-1])
         vn = v.transpose(0, 2, 1, 3).reshape(b * kvh, sk, dv)
-        qp = jnp.repeat(q_pos.astype(jnp.int32), h, axis=0)  # (B*H, Sq)
         kp = jnp.repeat(kv_pos.astype(jnp.int32), kvh, axis=0)
-        pad_q, pad_k = (-sq) % 8, (-sk) % 8
+        rows = qn.shape[1]
+        pad_q, pad_k = (-rows) % 8, (-sk) % 8
         if pad_q:
             qn = jnp.pad(qn, ((0, 0), (0, pad_q), (0, 0)))
             qp = jnp.pad(qp, ((0, 0), (0, pad_q)), constant_values=-1)
@@ -89,9 +128,10 @@ def attention_fused_library(q: jax.Array, k: jax.Array, v: jax.Array,
         o = flash_attention_lib(
             qn, kn, vn, qp, kp, coeffs.reshape(-1, 3), em, rm,
             r_max=coeffs.shape[1], causal=causal, window=window,
-            scale=scale, kv_group=h // kvh, block_q=_block(sq + pad_q),
+            scale=scale, kv_group=1 if fold else h // kvh,
+            block_q=rows + pad_q if fold else _block(rows + pad_q),
             block_k=_block(sk + pad_k), interpret=interpret)
-        return o[:, :sq].reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+        return _from_rows(o[:, :rows], b, sq, h, kvh, fold)
 
     # on a mesh: batch rows and whole kv-head groups are independent, so
     # query heads shard exactly where their kv heads do

@@ -36,19 +36,23 @@ from repro.core.table import TableDesign
 
 LOG2E = 1.4426950408889634
 
-# Trace-time sinks for fused-attention refusals: a stats dict registered by
-# count_attention_fallbacks() (the serving engine wraps every trace of its
+# Trace-time sinks for fused-attention sites: a stats dict registered by
+# count_attention_sites() (the serving engine wraps every trace of its
 # programs in one) gets ATTN_FALLBACK_KEY incremented each time a traced
-# attention takes the chunked glue path instead of the fused kernel.
+# attention takes the chunked glue path instead of the fused kernel, and
+# ATTN_FOLD_KEY each time a traced fused attention folds a GQA group's
+# query heads into the rows of one tile per kv stripe.
 ATTN_FALLBACK_KEY = "attn_glue_fallbacks"
+ATTN_FOLD_KEY = "attn_folded_sites"
 _SINKS = threading.local()
 
 
 @contextlib.contextmanager
-def count_attention_fallbacks(sink: dict):
-    """Count, into ``sink[ATTN_FALLBACK_KEY]``, every fused-attention
-    refusal traced inside the block (the count is per trace: a program
-    that is already compiled is not traced again)."""
+def count_attention_sites(sink: dict):
+    """Count, into ``sink``, every fused-attention refusal
+    (``ATTN_FALLBACK_KEY``) and folded flash call (``ATTN_FOLD_KEY``)
+    traced inside the block (the count is per trace: a program that is
+    already compiled is not traced again)."""
     stack = getattr(_SINKS, "stack", None)
     if stack is None:
         stack = _SINKS.stack = []
@@ -59,9 +63,10 @@ def count_attention_fallbacks(sink: dict):
         stack.pop()
 
 
-def _attention_fallback() -> None:
+def note_attention_site(key: str) -> None:
+    """Count one traced attention site under ``key`` in every active sink."""
     for sink in getattr(_SINKS, "stack", ()):
-        sink[ATTN_FALLBACK_KEY] = sink.get(ATTN_FALLBACK_KEY, 0) + 1
+        sink[key] = sink.get(key, 0) + 1
 
 
 def table_eval_int(codes: jax.Array, design: TableDesign) -> jax.Array:
@@ -391,7 +396,7 @@ class FusedInterpNumerics(InterpNumerics):
         """The ``attention_core`` fast path: whole-datapath flash attention
         with the library ROM inlined. Returns None (caller falls back to
         the chunked glue path) when the layout is unsupported; each refusal
-        is counted into the active ``count_attention_fallbacks`` sink."""
+        is counted into the active ``count_attention_sites`` sink."""
         from repro.kernels.flashattn.ops import attention_fused_library
 
         b, sq, h, d = q.shape
@@ -404,10 +409,10 @@ class FusedInterpNumerics(InterpNumerics):
         if (h % kvh or k.shape[1] > 4096
                 or (sq * k.shape[1] > (1 << 22)
                     and jax.default_backend() != "tpu")):
-            _attention_fallback()
+            note_attention_site(ATTN_FALLBACK_KEY)
             return None
-        # grouped kv heads pass through unexpanded: the kernel maps each
-        # query-head program onto its kv stripe by index
+        # grouped kv heads pass through unexpanded: one program per kv
+        # stripe (group folded into its rows) or per query head
         return attention_fused_library(q, k, v, self.library, causal=causal,
                                        window=window, scale=scale,
                                        q_pos=q_pos, kv_pos=kv_pos)

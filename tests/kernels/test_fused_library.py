@@ -115,9 +115,10 @@ def test_library_flash_bitwise_equals_per_table_kernel(lib):
 
 def test_library_flash_grouped_kv_matches_expanded(lib):
     """GQA: unexpanded (kvh < h) K/V through the kernel's index-mapped kv
-    stripes == caller-expanded heads, bitwise (same programs per row)."""
+    stripes == caller-expanded heads, bitwise (same programs per row).
+    Sq * g = 256 rows exceed one tile, so the group is not folded."""
     rng = np.random.default_rng(9)
-    b, s, h, kvh, d = 2, 64, 4, 2, 64
+    b, s, h, kvh, d = 2, 128, 4, 2, 64
     q = jnp.asarray(rng.normal(0, 1, (b, s, h, d)).astype(np.float32))
     k = jnp.asarray(rng.normal(0, 1, (b, s, kvh, d)).astype(np.float32))
     v = jnp.asarray(rng.normal(0, 1, (b, s, kvh, d)).astype(np.float32))
@@ -130,6 +131,58 @@ def test_library_flash_grouped_kv_matches_expanded(lib):
             q, kx, vx, lib, causal=True, use_kernel=use_kernel,
             interpret=True))
         np.testing.assert_array_equal(grouped, expanded)
+
+
+# (batch, q len, heads, kv heads, filled kv slots per row (0: dead slot),
+# window); Sk 256 with d 128, two kv chunks per stripe
+FOLD = {
+    "decode-g8-dead-slot": (3, 1, 8, 1, (0, 100, 256), None),
+    "decode-g6-pad-rows": (2, 1, 12, 2, (37, 200), None),
+    "decode-g8-window": (2, 1, 8, 1, (180, 250), 64),
+    "chunk4-g8-causal": (2, 4, 8, 1, (60, 200), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLD))
+def test_library_flash_folded_group_bitwise_equals_unfolded(lib, case):
+    """Decode-sized calls fold a GQA group's query heads into the rows of
+    one tile per kv stripe; the same K/V with the heads expanded (group 1)
+    keep one program per query head. The two give the same output, bit
+    for bit: each row keeps its own positions, masks and online softmax,
+    and a folded tile holds the same query positions as an unfolded one,
+    so every chunk's liveness is the same too."""
+    from repro.numerics.ops import ATTN_FOLD_KEY, count_attention_sites
+
+    b, sq, h, kvh, fills, window = FOLD[case]
+    d, sk = 128, 256
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(0, 1, (b, sq, h, d)).astype(np.float32))
+    k = jnp.asarray(rng.normal(0, 1, (b, sk, kvh, d)).astype(np.float32))
+    v = jnp.asarray(rng.normal(0, 1, (b, sk, kvh, d)).astype(np.float32))
+    kv_pos = np.full((b, sk), -1, np.int32)
+    q_pos = np.zeros((b, sq), np.int32)
+    for i, n in enumerate(fills):
+        kv_pos[i, :n] = np.arange(n)
+        q_pos[i] = np.arange(max(n - sq, 0), max(n - sq, 0) + sq)
+    kw = dict(causal=True, window=window, q_pos=jnp.asarray(q_pos),
+              kv_pos=jnp.asarray(kv_pos), interpret=True)
+    g = h // kvh
+    sink: dict = {}
+    with count_attention_sites(sink):
+        folded = np.asarray(attention_fused_library(
+            q, k, v, lib, use_kernel=True, **kw))
+    assert sink[ATTN_FOLD_KEY] == 1
+    with count_attention_sites(sink):
+        unfolded = np.asarray(attention_fused_library(
+            q, jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2), lib,
+            use_kernel=True, **kw))
+    assert sink[ATTN_FOLD_KEY] == 1
+    np.testing.assert_array_equal(folded, unfolded)
+    live = np.asarray(fills) > 0  # a dead slot's row is never read
+    ref = np.asarray(attention_fused_library(q, k, v, lib, use_kernel=False,
+                                             **kw))
+    np.testing.assert_allclose(folded[live], ref[live], rtol=5e-2,
+                               atol=5e-3)
 
 
 def test_library_flash_decode_masking_matches_ref_and_glue(lib):
@@ -226,14 +279,14 @@ def test_fused_attention_refusal_is_counted(lib):
     path takes over) and is counted in the active trace-time sink — the
     engine's ``stats["attn_glue_fallbacks"]`` — never silently."""
     from repro.numerics.ops import (ATTN_FALLBACK_KEY,
-                                    count_attention_fallbacks)
+                                    count_attention_sites)
 
     num = get_numerics("interp", lib, fused=True)
     q = jnp.zeros((1, 4, 3, 16), jnp.float32)  # 3 heads over 2 kv heads
     k = v = jnp.zeros((1, 4, 2, 16), jnp.float32)
     pos = jnp.zeros((1, 4), jnp.int32)
     sink: dict = {}
-    with count_attention_fallbacks(sink):
+    with count_attention_sites(sink):
         out = num.fused_attention(q, k, v, pos, pos, causal=True,
                                   window=None, scale=None)
     assert out is None and sink[ATTN_FALLBACK_KEY] == 1
@@ -241,7 +294,7 @@ def test_fused_attention_refusal_is_counted(lib):
     # a refusal
     assert num.fused_attention(q, k, v, pos, pos, causal=True, window=None,
                                scale=None) is None
-    with count_attention_fallbacks(sink):
+    with count_attention_sites(sink):
         out = num.fused_attention(q[:, :, :2], k, v, pos, pos, causal=True,
                                   window=None, scale=None)
     assert out is not None and sink[ATTN_FALLBACK_KEY] == 1

@@ -8,7 +8,9 @@ non-MXU matmul operand types, blocks that break the (8, 128) tiling rule,
 unlowerable primitives) fails here, at the real model widths:
 
 * yi_6b decode and prefill attention: head_dim 128, GQA group 8, a
-  4096-slot KV cache, 512-token prefill buckets;
+  4096-slot KV cache, 512-token prefill buckets; decode folds the group
+  into the rows of one tile per kv stripe, also at group 6 (48 heads over
+  8 kv heads, Mixtral-8x22B / Minitron-8B), where the rows pad to 8;
 * minicpm3_4b's MLA attention (Dk 96 != Dv 64);
 * rmsnorm at d_model 4096, softmax on aligned and unaligned widths;
 * the fused ROM walk over the full default manifest with uniform (v1)
@@ -158,6 +160,7 @@ def test_softmax_compiles(one_chip, no_cache, lib, shape):
 ATTN = {
     "yi_6b-decode": (8, 1, 4096, 32, 4, 128, 128),
     "yi_6b-prefill": (2, 512, 512, 32, 4, 128, 128),
+    "mixtral_8x22b-decode": (8, 1, 4096, 48, 8, 128, 128),
     "minicpm3_4b-mla-decode": (8, 1, 4096, 40, 40, 96, 64),
     "minicpm3_4b-mla-prefill": (1, 256, 256, 40, 40, 96, 64),
 }

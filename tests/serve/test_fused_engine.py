@@ -173,3 +173,45 @@ def test_fused_engine_counts_attention_glue_fallbacks():
         counts[cache_len] = eng.stats["attn_glue_fallbacks"]
     assert counts[48] == 0
     assert counts[4100] > 0
+
+
+def test_fused_engine_counts_folded_attention_sites(monkeypatch):
+    """Decode folds a GQA group's query heads into the rows of one tile per
+    kv stripe. Every attention site of a traced tick program counts once in
+    ``stats["attn_folded_sites"]``; the admission programs' 128-token
+    bucket (128 x g rows, more than a tile) keeps one program per query
+    head and counts none; nothing falls back to the glue path. The layer
+    stack is one ``lax.scan``, traced once per program: a tick program
+    holds one attention site per scanned segment."""
+    from repro.numerics.ops import (ATTN_FALLBACK_KEY, ATTN_FOLD_KEY,
+                                    FusedInterpNumerics,
+                                    count_attention_sites)
+    from repro.serve.aot import tick_chunk_sizes
+
+    cfg = get_smoke_config("yi_6b").replace(numerics="interp-fused",
+                                            name="yi_6b-fold-probe")
+    assert cfg.n_heads // cfg.n_kv_heads > 1
+    params = tf.init_params(jax.random.key(0), cfg)
+    sites = []  # (Sq, folded, fallbacks) of each traced attention site
+    real = FusedInterpNumerics.fused_attention
+
+    def spy(self, q, *args, **kw):
+        with count_attention_sites({}) as site:
+            out = real(self, q, *args, **kw)
+        sites.append((q.shape[1], site.get(ATTN_FOLD_KEY, 0),
+                      site.get(ATTN_FALLBACK_KEY, 0)))
+        return out
+
+    monkeypatch.setattr(FusedInterpNumerics, "fused_attention", spy)
+    horizon = 2
+    eng = ServeEngine(cfg, params, slots=2, cache_len=128, horizon=horizon,
+                      aot_buckets=(128,), max_pack=2)
+    ticks = [s for s in sites if s[0] == 1]
+    admits = [s for s in sites if s[0] == 128]
+    assert len(ticks) + len(admits) == len(sites)
+    assert len(ticks) == (len(tick_chunk_sizes(horizon))
+                          * len(tf.layer_plan(cfg)))
+    assert admits and all(s[1:] == (0, 0) for s in admits)
+    assert all(s[1:] == (1, 0) for s in ticks)
+    assert eng.stats["attn_folded_sites"] == len(ticks)
+    assert eng.stats["attn_glue_fallbacks"] == 0
