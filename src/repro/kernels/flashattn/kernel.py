@@ -12,6 +12,10 @@ and the full K/V stripe (Sk, D) for that head are VMEM-resident (bf16
 Sk=4k, D=128 -> 2 MB; longer Sk moves kv onto the grid axis — documented
 bound). The kv loop runs in BLOCK_K chunks with `pl.when`-guarded compute:
 causally-dead chunks are skipped (perf iteration B1 inside the kernel).
+
+The absorbed-latent variant (``mla_flash_lib``, MLA decode) runs the same
+recurrence with keys in two parts — the latent stripe and the shared
+rotary stripe — and the latent stripe as its values.
 """
 from __future__ import annotations
 
@@ -59,28 +63,26 @@ def _table_recip(s, lut, meta):
     return rtab * (2.0 ** -(rb + 1)) * pow2(-expo)
 
 
-def _flash_loop(q, k_ref, v_ref, out_ref, lut_exp, lut_recip, exp_meta: dict,
-                recip_meta: dict, block_k: int, mask_chunk, chunk_live):
-    """The online-softmax flash recurrence shared by the per-table and
-    library-bound kernels: kv-chunked score/renormalize/PV loop with
-    `pl.when`-style liveness skipping, then the reciprocal epilogue.
+def _flash_loop(q, chunk, nk: int, block_k: int, out_ref, lut_exp,
+                lut_recip, exp_meta: dict, recip_meta: dict, mask_chunk,
+                chunk_live):
+    """The online-softmax flash recurrence shared by the per-table,
+    library-bound and absorbed-latent kernels: kv-chunked
+    score/renormalize/PV loop with `pl.when`-style liveness skipping, then
+    the reciprocal epilogue.
 
-    ``mask_chunk(j, s)`` masks one (BQ, BK) score chunk (or returns it
-    untouched); ``chunk_live(j)`` returns a traced liveness bool for the
-    ``lax.cond`` skip, or None to always run the chunk. One copy of the
-    m/l/acc update — the two kernel variants differ only in masking and
-    table-read closures and cannot drift."""
-    sk = k_ref.shape[1]
-    nk = sk // block_k
+    ``chunk(start)`` returns one kv chunk's (BQ, BK) float32 scores and its
+    (BK, Dv) values; ``mask_chunk(j, s)`` masks the scores of chunk ``j``
+    (or returns them untouched); ``chunk_live(j)`` returns a traced
+    liveness bool for the ``lax.cond`` skip, or None to always run the
+    chunk. One copy of the m/l/acc update — the kernel variants differ
+    only in their score, masking and table-read closures and cannot
+    drift."""
     bq = q.shape[0]
 
     def body(j, carry):
         m_i, l_i, acc = carry
-        start = pl.multiple_of(j * block_k, block_k)
-        kb = k_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)  # (BK, D)
-        vb = v_ref[0, pl.ds(start, block_k), :]
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (BQ, BK)
+        s, vb = chunk(pl.multiple_of(j * block_k, block_k))
         s = mask_chunk(j, s)
         m_new = jnp.maximum(jnp.maximum(m_i, jnp.max(s, -1, keepdims=True)),
                             M_FLOOR)
@@ -100,10 +102,26 @@ def _flash_loop(q, k_ref, v_ref, out_ref, lut_exp, lut_recip, exp_meta: dict,
 
     init = (jnp.full((bq, 1), M_FLOOR, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
-            jnp.zeros((bq, v_ref.shape[-1]), jnp.float32))
+            jnp.zeros((bq, out_ref.shape[-1]), jnp.float32))
     m_i, l_i, acc = jax.lax.fori_loop(0, nk, guarded, init)
     recip = _table_recip(jnp.maximum(l_i, 1e-30), lut_recip, recip_meta)
     out_ref[0] = (acc * recip).astype(out_ref.dtype)
+
+
+def _scores(q, k):
+    """(BQ, D) x (BK, D) -> (BQ, BK) float32 scores."""
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _kv_chunk(q, k_ref, v_ref, block_k: int):
+    """``_flash_loop``'s chunk closure over separate K and V stripes."""
+    def chunk(start):
+        kb = k_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)  # (BK, D)
+        vb = v_ref[0, pl.ds(start, block_k), :]
+        return _scores(q, kb), vb
+
+    return chunk
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, ecoef_ref, rcoef_ref, out_ref, *,
@@ -127,30 +145,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, ecoef_ref, rcoef_ref, out_ref, *,
         # B1 inside the kernel: skip chunks strictly above the diagonal
         return (j * block_k) <= (qi * bq + bq - 1)
 
-    _flash_loop(q, k_ref, v_ref, out_ref,
+    _flash_loop(q, _kv_chunk(q, k_ref, v_ref, block_k),
+                k_ref.shape[1] // block_k, block_k, out_ref,
                 lambda c: _lut(c, ecoef_ref, **exp_meta["eval"]),
                 lambda c: _lut(c, rcoef_ref, **recip_meta["eval"]),
-                exp_meta, recip_meta, block_k, mask_chunk, chunk_live)
+                exp_meta, recip_meta, mask_chunk, chunk_live)
 
 
-def _flash_lib_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, rom_ref,
-                      out_ref, *, causal: bool, window: int | None,
-                      scale: float, r_max: int, exp_meta: dict,
-                      recip_meta: dict, block_k: int):
-    """Library-bound flash attention with explicit position operands.
-
-    Both transcendentals read the whole-library ROM (`_lut_rom` at their
-    static func ids) — the approximation datapath is inlined into the
-    attention kernel, not a lookup service between ops. ``qpos_ref`` /
-    ``kpos_ref`` carry *absolute* positions per row: decode against a
-    partially-filled KV cache masks dead slots (pos < 0), applies causality
-    by position (not buffer index), and honors a sliding window — the same
-    contract as ``models.attention._mask``. Query positions arrive as a
-    (BQ, 1) column and key positions as one (1, BK) row per kv chunk, the
-    layouts the (8, 128) tiling rule accepts.
-    """
-    q = q_ref[0].astype(jnp.float32) * scale  # (BQ, D)
-    qp = qpos_ref[0]  # (BQ, 1) int32, -1 = padded query row
+def _positional(qp, kpos_ref, causal: bool, window: int | None):
+    """The library kernels' masking and chunk-liveness closures, from
+    *absolute* positions: ``qp`` the (BQ, 1) query column (-1 = padded
+    row), ``kpos_ref`` one (1, BK) row of key positions per kv chunk (-1 =
+    dead cache slot). Decode against a partially-filled cache masks dead
+    slots, applies causality by position (not buffer index) and honors a
+    sliding window — the contract of ``models.attention._mask``."""
     imax = jnp.iinfo(jnp.int32).max
 
     def kpos(j):
@@ -179,12 +187,96 @@ def _flash_lib_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, rom_ref,
             need = jnp.logical_and(need, jnp.max(kpb) > qmin - window)
         return need
 
-    _flash_loop(q, k_ref, v_ref, out_ref,
+    return mask_chunk, chunk_live
+
+
+def _lib_loop(q, chunk, qpos_ref, kpos_ref, rom_ref, out_ref, *, causal,
+              window, r_max, exp_meta, recip_meta, block_k):
+    """``_flash_loop`` with the library ROM's exp and recip reads and the
+    position-operand masks."""
+    mask_chunk, chunk_live = _positional(qpos_ref[0], kpos_ref, causal,
+                                         window)
+    _flash_loop(q, chunk, kpos_ref.shape[1], block_k, out_ref,
                 lambda c: _lut_rom(c, rom_ref, fid=exp_meta["fid"],
                                    r_max=r_max, **exp_meta["eval"]),
                 lambda c: _lut_rom(c, rom_ref, fid=recip_meta["fid"],
                                    r_max=r_max, **recip_meta["eval"]),
-                exp_meta, recip_meta, block_k, mask_chunk, chunk_live)
+                exp_meta, recip_meta, mask_chunk, chunk_live)
+
+
+def _flash_lib_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, rom_ref,
+                      out_ref, *, scale: float, block_k: int, **kw):
+    """Library-bound flash attention with explicit position operands.
+
+    Both transcendentals read the whole-library ROM (`_lut_rom` at their
+    static func ids) — the approximation datapath is inlined into the
+    attention kernel, not a lookup service between ops. Query positions
+    arrive as a (BQ, 1) column and key positions as one (1, BK) row per kv
+    chunk, the layouts the (8, 128) tiling rule accepts (``_positional``).
+    """
+    q = q_ref[0].astype(jnp.float32) * scale  # (BQ, D)
+    _lib_loop(q, _kv_chunk(q, k_ref, v_ref, block_k), qpos_ref, kpos_ref,
+              rom_ref, out_ref, block_k=block_k, **kw)
+
+
+def _mla_flash_lib_kernel(q_ref, qr_ref, c_ref, kr_ref, qpos_ref, kpos_ref,
+                          rom_ref, out_ref, *, scale: float, block_k: int,
+                          **kw):
+    """Absorbed-latent (MLA) flash attention: the keys come in two parts,
+    the latent stripe ``c_ref`` (Sk, Dc) and the shared rotary stripe
+    ``kr_ref`` (Sk, Dr), and the values are the latent stripe itself, so a
+    program reads each once. Scores are q_lat . c + q_rope . kr; the rest
+    is ``_flash_lib_kernel``'s recurrence."""
+    q = q_ref[0].astype(jnp.float32) * scale  # (BQ, Dc)
+    qr = qr_ref[0].astype(jnp.float32) * scale  # (BQ, Dr)
+
+    def chunk(start):
+        cb = c_ref[0, pl.ds(start, block_k), :]  # (BK, Dc), also the values
+        krb = kr_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)
+        return (_scores(q, cb.astype(jnp.float32)) + _scores(qr, krb)), cb
+
+    _lib_loop(q, chunk, qpos_ref, kpos_ref, rom_ref, out_ref,
+              block_k=block_k, **kw)
+
+
+def _lib_call(body, name: str, qs: tuple, kvs: tuple, q_pos, kv_pos, rom,
+              exp_meta: dict, recip_meta: dict, *, dv: int, dtype, r_max: int,
+              causal: bool, window: int | None, scale: float, kv_group: int,
+              block_q: int, block_k: int, interpret: bool | None):
+    """One ``pallas_call`` of a library-bound flash body: ``qs`` are (N, Sq,
+    *) query operands (one tile of ``block_q`` rows a program), ``kvs``
+    (N // kv_group, Sk, *) kv stripes, read whole by query program i at
+    stripe ``i // kv_group``, then the positions and the ROM."""
+    n, sq, _ = qs[0].shape
+    sk = kvs[0].shape[1]
+    g = kv_group
+    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
+    assert n % g == 0 and all(a.shape[0] == n // g for a in kvs), \
+        (n, g, [a.shape for a in kvs])
+    assert q_pos.shape == (n, sq) and kv_pos.shape == (n // g, sk), \
+        (q_pos.shape, kv_pos.shape)
+    nk = sk // block_k
+    kernel = functools.partial(body, causal=causal, window=window,
+                               scale=scale, r_max=r_max, exp_meta=exp_meta,
+                               recip_meta=recip_meta, block_k=block_k)
+    return pl.pallas_call(
+        kernel,
+        grid=(n, sq // block_q),
+        in_specs=[
+            *(pl.BlockSpec((1, block_q, a.shape[-1]), lambda i, j: (i, j, 0))
+              for a in qs),
+            *(pl.BlockSpec((1, sk, a.shape[-1]), lambda i, j: (i // g, 0, 0))
+              for a in kvs),
+            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, nk, block_k), lambda i, j: (i // g, 0, 0)),
+            rom_spec(),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, sq, dv), dtype),
+        interpret=interpret_mode(interpret),
+        name=name,
+    )(*qs, *kvs, q_pos.astype(jnp.int32).reshape(n, sq, 1),
+      kv_pos.astype(jnp.int32).reshape(n // g, nk, block_k), flat_rom(rom))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -234,33 +326,34 @@ def flash_attention_lib(q: jax.Array, k: jax.Array, v: jax.Array,
     via the BlockSpec index map, so grouped K/V are never materialized per
     query head. Sq % block_q == 0, Sk % block_k == 0.
     """
-    n, sq, d = q.shape
-    sk, dv = k.shape[1], v.shape[-1]
-    g = kv_group
-    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
-    assert n % g == 0 and k.shape[0] == n // g, (n, g, k.shape)
-    assert q_pos.shape == (n, sq) and kv_pos.shape == (n // g, sk), \
-        (q_pos.shape, kv_pos.shape)
-    scale = (d ** -0.5) if scale is None else scale
-    nk = sk // block_k
-    kernel = functools.partial(_flash_lib_kernel, causal=causal,
-                               window=window, scale=scale, r_max=r_max,
-                               exp_meta=exp_meta, recip_meta=recip_meta,
-                               block_k=block_k)
-    return pl.pallas_call(
-        kernel,
-        grid=(n, sq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, sk, k.shape[-1]), lambda i, j: (i // g, 0, 0)),
-            pl.BlockSpec((1, sk, dv), lambda i, j: (i // g, 0, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, nk, block_k), lambda i, j: (i // g, 0, 0)),
-            rom_spec(),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, sq, dv), v.dtype),
-        interpret=interpret_mode(interpret),
-        name="flash_lib",
-    )(q, k, v, q_pos.astype(jnp.int32).reshape(n, sq, 1),
-      kv_pos.astype(jnp.int32).reshape(n // g, nk, block_k), flat_rom(rom))
+    d = q.shape[-1]
+    return _lib_call(_flash_lib_kernel, "flash_lib", (q,), (k, v), q_pos,
+                     kv_pos, rom, exp_meta, recip_meta, dv=v.shape[-1],
+                     dtype=v.dtype, r_max=r_max, causal=causal,
+                     window=window,
+                     scale=(d ** -0.5) if scale is None else scale,
+                     kv_group=kv_group, block_q=block_q, block_k=block_k,
+                     interpret=interpret)
+
+
+def flash_attention_mla_lib(q: jax.Array, q_rope: jax.Array, c: jax.Array,
+                            k_rope: jax.Array, q_pos: jax.Array,
+                            kv_pos: jax.Array, rom: jax.Array,
+                            exp_meta: dict, recip_meta: dict, *, r_max: int,
+                            scale: float, causal: bool = True,
+                            window: int | None = None, kv_group: int = 1,
+                            block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
+                            interpret: bool | None = None) -> jax.Array:
+    """Absorbed-latent attention (``mla_flash_lib``): q: (N, Sq, Dc) queries
+    taken into the latent space; q_rope: (N, Sq, Dr) their rotary part; c:
+    (N // kv_group, Sk, Dc) the latent cache, keys and values at once;
+    k_rope: (N // kv_group, Sk, Dr) the shared rotary key. Returns (N, Sq,
+    Dc) latent outputs. ``scale`` is explicit: the published head width,
+    not Dc + Dr, sets it. Positions, ROM, grid and tiles as in
+    ``flash_attention_lib``."""
+    return _lib_call(_mla_flash_lib_kernel, "mla_flash_lib", (q, q_rope),
+                     (c, k_rope), q_pos, kv_pos, rom, exp_meta, recip_meta,
+                     dv=c.shape[-1], dtype=c.dtype, r_max=r_max,
+                     causal=causal, window=window, scale=scale,
+                     kv_group=kv_group, block_q=block_q, block_k=block_k,
+                     interpret=interpret)

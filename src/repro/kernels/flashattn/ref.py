@@ -17,14 +17,17 @@ def flash_attention_lib_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                             coeffs: jax.Array, exp_meta: dict,
                             recip_meta: dict, *, causal: bool = True,
                             window: int | None = None,
-                            scale: float | None = None) -> jax.Array:
+                            scale: float | None = None,
+                            q_rope: jax.Array | None = None,
+                            k_rope: jax.Array | None = None) -> jax.Array:
     """Unchunked oracle of the library-bound flash kernel.
 
     Same in-kernel glue (`_table_exp_neg` / `_table_recip`) over the padded
     (F, R_max, 3) ROM — the integer table reads are bit-identical to the
     kernel's `_lut_rom`; only the chunked renormalization order differs.
     q: (N, Sq, D); k: (N, Sk, Dk); v: (N, Sk, Dv); positions as in the
-    kernel (-1 = dead/padded row)."""
+    kernel (-1 = dead/padded row). ``q_rope`` (N, Sq, Dr) / ``k_rope`` (N,
+    Sk, Dr): the absorbed-latent kernel's second score term."""
     from repro.kernels.flashattn.kernel import _table_exp_neg, _table_recip
     from repro.kernels.interp.ref import interp_eval_ref
     from repro.kernels.softmax.ref import _rom_rows
@@ -41,7 +44,11 @@ def flash_attention_lib_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     n, sq, d = q.shape
     scale = (d ** -0.5) if scale is None else scale
     s = jnp.einsum("nqd,nkd->nqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+                   k.astype(jnp.float32))
+    if k_rope is not None:
+        s = s + jnp.einsum("nqd,nkd->nqk", q_rope.astype(jnp.float32),
+                           k_rope.astype(jnp.float32))
+    s = s * scale
     ok = (kv_pos >= 0)[:, None, :]
     if causal:
         ok = jnp.logical_and(ok, q_pos[:, :, None] >= kv_pos[:, None, :])

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch.sharding import constrain
+from repro.numerics.ops import ATTN_ABSORB_KEY, note_attention_site
 from repro.models.layers import Params, ShapeTree, apply_rope, pdtype, rope_angles, spec
 
 NEG = -1e30
@@ -47,28 +48,45 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
                    q_pos: jax.Array, kv_pos: jax.Array, numerics,
                    causal: bool = True, window: Optional[int] = None,
                    q_chunk: int = 1024, kv_chunk: int = 1024,
-                   softmax_scale: float | None = None) -> jax.Array:
+                   softmax_scale: float | None = None,
+                   q_rope: jax.Array | None = None,
+                   k_rope: jax.Array | None = None) -> jax.Array:
     """q: (B,Sq,H,D); k,v: (B,Sk,KV,Dk/Dv); *_pos: (B, S*) int32.
 
     Grouped heads are expressed as (KV, G) so the head contraction matches
     the GQA weight sharding; chunked over both Sq and Sk with flash-style
     renormalization (all exponentials/reciprocals via the numerics backend).
+    ``q_rope`` (B,Sq,H,Dr) / ``k_rope`` (B,Sk,KV,Dr) add a second score
+    term q_rope . k_rope, so a key held in two parts (absorbed MLA: latent
+    and shared rotary key) is never concatenated; that form counts one
+    ``ATTN_ABSORB_KEY`` site.
     """
     b, sq, h, d = q.shape
     _, sk, kvh, dk = k.shape
     dv = v.shape[-1]
     g = h // kvh
+    if k_rope is not None:
+        note_attention_site(ATTN_ABSORB_KEY)
     fused = getattr(numerics, "fused_attention", None)
     if fused is not None:
         # fused numerics inline the whole datapath (scores, table-backed
         # exp/recip, PV product) into one kernel; None = unsupported layout,
         # fall through to the chunked glue path
         out = fused(q, k, v, q_pos, kv_pos, causal=causal, window=window,
-                    scale=softmax_scale)
+                    scale=softmax_scale, q_rope=q_rope, k_rope=k_rope)
         if out is not None:
             return out
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
     q = q.reshape(b, sq, kvh, g, d)
+    qr = None if q_rope is None else q_rope.reshape(b, sq, kvh, g, -1)
+
+    def scores(qb, kb, qrb, krb):
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qb, kb,
+                       preferred_element_type=jnp.float32)
+        if krb is not None:
+            s = s + jnp.einsum("bqkgd,bskd->bkgqs", qrb, krb,
+                               preferred_element_type=jnp.float32)
+        return s * scale
 
     def _divisor_chunk(n: int, target: int) -> int:
         c = min(target, n)
@@ -81,7 +99,7 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
     nq, nk = sq // q_chunk, sk // kv_chunk
 
     if nq == 1 and nk == 1:
-        s = jnp.einsum("bqkgd,bskd->bkgqs", q, k, preferred_element_type=jnp.float32) * scale
+        s = scores(q, k, qr, k_rope)
         m = _mask(q_pos, kv_pos, causal, window)[:, None, None]
         s = jnp.where(m, s, NEG)
         mx = jax.lax.stop_gradient(
@@ -96,13 +114,14 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
     kc = k.reshape(b, nk, kv_chunk, kvh, dk).transpose(1, 0, 2, 3, 4)
     vc = v.reshape(b, nk, kv_chunk, kvh, dv).transpose(1, 0, 2, 3, 4)
     pc = kv_pos.reshape(b, nk, kv_chunk).transpose(1, 0, 2)
+    krc = (None if k_rope is None else k_rope.reshape(
+        b, nk, kv_chunk, kvh, -1).transpose(1, 0, 2, 3, 4))
 
-    def q_block(qb, qpb):
-        # qb: (B, Tq, KV, G, D); qpb: (B, Tq)
-        def compute_chunk(carry, kb, vb, kpb, masked: bool):
+    def q_block(qb, qpb, qrb):
+        # qb: (B, Tq, KV, G, D); qpb: (B, Tq); qrb: (B, Tq, KV, G, Dr) | None
+        def compute_chunk(carry, kb, vb, kpb, krb, masked: bool):
             m_i, l_i, acc = carry
-            s = jnp.einsum("bqkgd,bskd->bkgqs", qb, kb,
-                           preferred_element_type=jnp.float32) * scale
+            s = scores(qb, kb, qrb, krb)
             if masked:  # only boundary chunks pay the mask-select (B2)
                 msk = _mask(qpb, kpb, causal, window)[:, None, None]
                 s = jnp.where(msk, s, NEG)
@@ -123,9 +142,10 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
         use_skip = nk >= 8
 
         def kv_step(carry, xs):
-            kb, vb, kpb = xs
+            kb, vb, kpb, krb = xs
             if not use_skip:
-                return compute_chunk(carry, kb, vb, kpb, masked=True), None
+                return (compute_chunk(carry, kb, vb, kpb, krb, masked=True),
+                        None)
             # chunk-level liveness (perf iteration B1): a kv chunk is dead if
             # it is entirely in the causal future of every query, entirely
             # outside the sliding window, or entirely empty cache slots.
@@ -147,8 +167,10 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
             def live(c):
                 return jax.lax.cond(
                     full,
-                    lambda cc: compute_chunk(cc, kb, vb, kpb, masked=False),
-                    lambda cc: compute_chunk(cc, kb, vb, kpb, masked=True),
+                    lambda cc: compute_chunk(cc, kb, vb, kpb, krb,
+                                             masked=False),
+                    lambda cc: compute_chunk(cc, kb, vb, kpb, krb,
+                                             masked=True),
                     c)
 
             carry = jax.lax.cond(need, live, lambda c: c, carry)
@@ -158,13 +180,15 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
         init = (jnp.full((b, kvh, g, tq), M_FLOOR, jnp.float32),
                 jnp.zeros((b, kvh, g, tq), jnp.float32),
                 jnp.zeros((b, kvh, g, tq, dv), jnp.float32))
-        (m_i, l_i, acc), _ = jax.lax.scan(kv_step, init, (kc, vc, pc))
+        (m_i, l_i, acc), _ = jax.lax.scan(kv_step, init, (kc, vc, pc, krc))
         o = acc * numerics.recip_pos(jnp.maximum(l_i, 1e-30))[..., None]
         return o.transpose(0, 3, 1, 2, 4).reshape(b, tq, h, dv).astype(v.dtype)
 
     qs = q.reshape(b, nq, q_chunk, kvh, g, d).transpose(1, 0, 2, 3, 4, 5)
     qps = q_pos.reshape(b, nq, q_chunk).transpose(1, 0, 2)
-    out = jax.lax.map(lambda xs: q_block(*xs), (qs, qps))
+    qrs = (None if qr is None else qr.reshape(
+        b, nq, q_chunk, kvh, g, -1).transpose(1, 0, 2, 3, 4, 5))
+    out = jax.lax.map(lambda xs: q_block(*xs), (qs, qps, qrs))
     return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dv)
 
 
@@ -349,7 +373,8 @@ def _mla_kv_latent(p, x, positions, cfg, numerics):
 
 
 def _mla_expand(p, ckv, kr, cfg):
-    """Latents -> per-head K (nope+rope) and V."""
+    """Latents -> per-head K (nope+rope) and V. Only prefill (and training)
+    expands; decode attends over the latent cache (``mla_decode``)."""
     m = cfg.mla
     b, s, _ = ckv.shape
     kvb = (ckv @ p["wkv_b"]).reshape(b, s, cfg.n_heads, m.qk_nope_head_dim + m.v_head_dim)
@@ -359,10 +384,10 @@ def _mla_expand(p, ckv, kr, cfg):
     return k, v
 
 
-def mla_train(p: Params, x, positions, cfg, numerics, causal: bool = True):
+def _mla_attend(p, x, positions, ckv, kr, cfg, numerics, causal: bool):
+    """Expanded-form attention over the sequence's own latents."""
     b, s, _ = x.shape
     q = _mla_q(p, x, positions, cfg, numerics)
-    ckv, kr = _mla_kv_latent(p, x, positions, cfg, numerics)
     k, v = _mla_expand(p, ckv, kr, cfg)
     q = constrain(q, ("batch", "seq2", "heads", None))
     k = constrain(k, ("batch", "seq2", "heads", None))
@@ -370,11 +395,16 @@ def mla_train(p: Params, x, positions, cfg, numerics, causal: bool = True):
     return constrain(o.reshape(b, s, -1) @ p["wo"], ("batch", "seq", None))  # C3
 
 
+def mla_train(p: Params, x, positions, cfg, numerics, causal: bool = True):
+    ckv, kr = _mla_kv_latent(p, x, positions, cfg, numerics)
+    return _mla_attend(p, x, positions, ckv, kr, cfg, numerics, causal)
+
+
 def mla_prefill(p, x, positions, cfg, numerics, cache_len: int):
     m = cfg.mla
     b, s, _ = x.shape
-    y = mla_train(p, x, positions, cfg, numerics)
     ckv, kr = _mla_kv_latent(p, x, positions, cfg, numerics)
+    y = _mla_attend(p, x, positions, ckv, kr, cfg, numerics, causal=True)
     ck_buf = jnp.zeros((b, cache_len, m.kv_lora_rank), ckv.dtype)
     kr_buf = jnp.zeros((b, cache_len, m.qk_rope_head_dim), kr.dtype)
     pos_buf = jnp.full((b, cache_len), -1, jnp.int32)
@@ -385,7 +415,15 @@ def mla_prefill(p, x, positions, cfg, numerics, cache_len: int):
 
 
 def mla_decode(p, x, pos, cache: KVCache, cfg, numerics):
-    """pos: scalar int32 or (B,) per-slot positions (continuous batching)."""
+    """pos: scalar int32 or (B,) per-slot positions (continuous batching).
+
+    Absorbed form: ``wkv_b``'s key half takes the query's no-position part
+    into the latent space and its value half brings the latent output back
+    up, so every head attends to the cached latent and shared rotary key as
+    they lie — one kv head, all heads its group, the two score terms
+    summed, the latent as values — and the cache is never expanded,
+    concatenated or transposed."""
+    m = cfg.mla
     b = x.shape[0]
     pos, positions = _decode_positions(pos, b)
     q = _mla_q(p, x, positions, cfg, numerics)
@@ -402,9 +440,19 @@ def mla_decode(p, x, pos, cache: KVCache, cfg, numerics):
         pc = jax.vmap(lambda buf, new, s:
                       jax.lax.dynamic_update_slice(buf, new, (s,)))(
             cache.pos, positions, pos)
-    k, v = _mla_expand(p, ck, krb, cfg)  # chunked expansion would go here
-    o = attention_core(q, k, v, positions, pc, numerics, causal=True,
-                       kv_chunk=min(4096, k.shape[1]))
+    wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, cfg.n_heads,
+                               m.qk_nope_head_dim + m.v_head_dim)
+    w_k, w_v = wkv_b[..., : m.qk_nope_head_dim], wkv_b[..., m.qk_nope_head_dim:]
+    q_lat = jnp.einsum("bshn,chn->bshc", q[..., : m.qk_nope_head_dim], w_k,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    lat = ck[:, :, None, :]  # (B, S, 1, kv_lora): keys and values
+    o = attention_core(q_lat, lat, lat, positions, pc, numerics, causal=True,
+                       kv_chunk=min(4096, ck.shape[1]),
+                       softmax_scale=1.0 / math.sqrt(q.shape[-1]),
+                       q_rope=q[..., m.qk_nope_head_dim:],
+                       k_rope=krb[:, :, None, :])
+    o = jnp.einsum("bshc,chv->bshv", o, w_v,
+                   preferred_element_type=jnp.float32).astype(x.dtype)
     y = o.reshape(b, 1, -1) @ p["wo"]
     return y, KVCache(ck, krb, pc)
 

@@ -40,18 +40,21 @@ LOG2E = 1.4426950408889634
 # count_attention_sites() (the serving engine wraps every trace of its
 # programs in one) gets ATTN_FALLBACK_KEY incremented each time a traced
 # attention takes the chunked glue path instead of the fused kernel, and
-# ATTN_FOLD_KEY each time a traced fused attention folds a GQA group's
-# query heads into the rows of one tile per kv stripe.
+# ATTN_FOLD_KEY each time a traced fused attention folds a group's query
+# heads into the rows of one tile per kv stripe, and ATTN_ABSORB_KEY each
+# time an MLA decode attention is traced in the absorbed-latent form.
 ATTN_FALLBACK_KEY = "attn_glue_fallbacks"
 ATTN_FOLD_KEY = "attn_folded_sites"
+ATTN_ABSORB_KEY = "attn_absorbed_sites"
 _SINKS = threading.local()
 
 
 @contextlib.contextmanager
 def count_attention_sites(sink: dict):
     """Count, into ``sink``, every fused-attention refusal
-    (``ATTN_FALLBACK_KEY``) and folded flash call (``ATTN_FOLD_KEY``)
-    traced inside the block (the count is per trace: a program that is
+    (``ATTN_FALLBACK_KEY``), folded flash call (``ATTN_FOLD_KEY``) and
+    absorbed MLA decode attention (``ATTN_ABSORB_KEY``) traced inside the
+    block (the count is per trace: a program that is
     already compiled is not traced again)."""
     stack = getattr(_SINKS, "stack", None)
     if stack is None:
@@ -392,11 +395,13 @@ class FusedInterpNumerics(InterpNumerics):
                                       eps=eps).astype(x.dtype)
 
     def fused_attention(self, q, k, v, q_pos, kv_pos, *, causal, window,
-                        scale):
+                        scale, q_rope=None, k_rope=None):
         """The ``attention_core`` fast path: whole-datapath flash attention
-        with the library ROM inlined. Returns None (caller falls back to
-        the chunked glue path) when the layout is unsupported; each refusal
-        is counted into the active ``count_attention_sites`` sink."""
+        with the library ROM inlined; the absorbed MLA form (``q_rope``,
+        ``k_rope``, values = keys) routes to its latent kernel. Returns
+        None (caller falls back to the chunked glue path) when the layout
+        is unsupported; each refusal is counted into the active
+        ``count_attention_sites`` sink."""
         from repro.kernels.flashattn.ops import attention_fused_library
 
         b, sq, h, d = q.shape
@@ -415,7 +420,8 @@ class FusedInterpNumerics(InterpNumerics):
         # stripe (group folded into its rows) or per query head
         return attention_fused_library(q, k, v, self.library, causal=causal,
                                        window=window, scale=scale,
-                                       q_pos=q_pos, kv_pos=kv_pos)
+                                       q_pos=q_pos, kv_pos=kv_pos,
+                                       q_rope=q_rope, k_rope=k_rope)
 
 
 BACKENDS = {"exact": ExactNumerics, "interp": InterpNumerics,

@@ -83,12 +83,12 @@ class SiteNumerics:
         return self._softmax.softmax(x, axis=axis)
 
     def fused_attention(self, q, k, v, q_pos, kv_pos, *, causal, window,
-                        scale):
+                        scale, q_rope=None, k_rope=None):
         fa = getattr(self._softmax, "fused_attention", None)
         if fa is None:
             return None  # caller falls back to the chunked glue path
         return fa(q, k, v, q_pos, kv_pos, causal=causal, window=window,
-                  scale=scale)
+                  scale=scale, q_rope=q_rope, k_rope=k_rope)
 
     # rmsnorm site
     def rmsnorm(self, x, gamma, eps: float = 1e-6):

@@ -79,9 +79,9 @@ from repro.api import InterpLibrary, LibraryIntegrityError, default_explorer
 from repro.faults.inject import crashpoint
 from repro.launch import sharding as shlib
 from repro.models import transformer as tf
-from repro.numerics.ops import (ATTN_FALLBACK_KEY, ATTN_FOLD_KEY,
-                                INTERP_BACKENDS, count_attention_sites,
-                                get_numerics)
+from repro.numerics.ops import (ATTN_ABSORB_KEY, ATTN_FALLBACK_KEY,
+                                ATTN_FOLD_KEY, INTERP_BACKENDS,
+                                count_attention_sites, get_numerics)
 from repro.serve import aot as aot_mod
 from repro.serve import spans as span_lib
 from repro.serve.journal import ServeJournal, load_requests
@@ -442,7 +442,8 @@ class ServeEngine:
                           "packed_admits": 0,
                           "packed_requests": 0, "admit_dispatches": 0,
                           "async_chunks": 0, "async_tokens": 0,
-                          ATTN_FALLBACK_KEY: 0, ATTN_FOLD_KEY: 0}
+                          ATTN_FALLBACK_KEY: 0, ATTN_FOLD_KEY: 0,
+                          ATTN_ABSORB_KEY: 0}
             self.faults: list[dict] = []  # structured fault/degradation log
             self._trips = 0  # watchdog trips since the last degradation
             # requests the running step() admitted, and those it ended:
@@ -547,8 +548,10 @@ class ServeEngine:
         *trace* time; none without a mesh) and the fused-attention site
         counters (``stats["attn_glue_fallbacks"]``: attention sites traced
         onto the chunked glue path instead of the fused kernel;
-        ``stats["attn_folded_sites"]``: flash calls traced with a GQA
-        group's query heads folded into one tile's rows)."""
+        ``stats["attn_folded_sites"]``: flash calls traced with a group's
+        query heads folded into one tile's rows;
+        ``stats["attn_absorbed_sites"]``: MLA decode attentions traced in
+        the absorbed-latent form)."""
         stack = contextlib.ExitStack()
         if self.mesh is not None:
             stack.enter_context(shlib.axis_rules(self.mesh))

@@ -298,3 +298,67 @@ def test_fused_attention_refusal_is_counted(lib):
         out = num.fused_attention(q[:, :, :2], k, v, pos, pos, causal=True,
                                   window=None, scale=None)
     assert out is not None and sink[ATTN_FALLBACK_KEY] == 1
+
+
+# (batch, query heads, filled kv slots per row (0: dead slot)); Sk 256,
+# latent 256 and rotary key 32 wide (MiniCPM3's), two kv chunks a stripe
+ABSORBED = {
+    "h12-pad-rows-dead-slot": (3, 12, (0, 100, 256)),
+    "h40-per-slot-positions": (2, 40, (37, 200)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ABSORBED))
+def test_library_flash_absorbed_latent_matches_oracle(lib, case):
+    """Absorbed MLA decode: every query head of a slot is a row of one
+    program over the slot's latent stripe, which is the keys' first part
+    and the values; the shared rotary stripe is the keys' second part;
+    the scale is 1/sqrt(96), the published head width, not the operands'
+    Dc + Dr. The kernel in interpret mode against its unchunked jnp
+    oracle, and against float32 softmax attention over the concatenated
+    keys."""
+    from repro.numerics.ops import (ATTN_FOLD_KEY, count_attention_sites,
+                                    softmax_ulp_bound)
+
+    b, h, fills = ABSORBED[case]
+    dc, dr, sk, scale = 256, 32, 256, 96 ** -0.5
+    rng = np.random.default_rng(12)
+    q = jnp.asarray(rng.normal(0, 1, (b, 1, h, dc)).astype(np.float32))
+    qr = jnp.asarray(rng.normal(0, 1, (b, 1, h, dr)).astype(np.float32))
+    c = jnp.asarray(rng.normal(0, 1, (b, sk, 1, dc)).astype(np.float32))
+    kr = jnp.asarray(rng.normal(0, 1, (b, sk, 1, dr)).astype(np.float32))
+    kv_pos = np.full((b, sk), -1, np.int32)
+    q_pos = np.zeros((b, 1), np.int32)
+    for i, n in enumerate(fills):
+        kv_pos[i, :n] = np.arange(n)
+        q_pos[i] = max(n - 1, 0)
+    kw = dict(causal=True, q_pos=jnp.asarray(q_pos),
+              kv_pos=jnp.asarray(kv_pos), q_rope=qr, k_rope=kr, scale=scale,
+              interpret=True)
+    sink: dict = {}
+    with count_attention_sites(sink):
+        got = np.asarray(attention_fused_library(q, c, c, lib,
+                                                 use_kernel=True, **kw))
+    assert sink[ATTN_FOLD_KEY] == 1 and got.shape == (b, 1, h, dc)
+    oracle = np.asarray(attention_fused_library(q, c, c, lib,
+                                                use_kernel=False, **kw))
+    live = np.asarray(fills) > 0  # a dead slot's rows are never read
+    vmax = np.abs(np.asarray(c)).max()
+    # same table reads; the kernel renormalizes chunk by chunk against a
+    # running max, so exp codes can differ by their lsb (measured 2.6e-5 of
+    # max |v|); values rounded to bfloat16 (2^-9) would fail
+    np.testing.assert_allclose(got[live], oracle[live], rtol=0,
+                               atol=1e-4 * vmax)
+    s = (np.einsum("bqhd,bkd->bhqk", np.asarray(q), np.asarray(c)[:, :, 0])
+         + np.einsum("bqhd,bkd->bhqk", np.asarray(qr),
+                     np.asarray(kr)[:, :, 0])) * scale
+    s = np.where((kv_pos >= 0)[:, None, None, :], s, -1e30)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("bhqk,bkd->bqhd", p, np.asarray(c)[:, :, 0])
+    # the tables' certified softmax error bounds each weight's relative
+    # error, so an output is off by at most that share of max |v| (measured
+    # 0.07 of the bound); a scale of 1/sqrt(Dc + Dr) misses it by far
+    bound = softmax_ulp_bound(lib.meta("exp2neg"), lib.meta("recip"))
+    err = np.abs(got[live] - want[live]).max()
+    assert err <= bound * vmax, (err, bound)

@@ -11,7 +11,10 @@ unlowerable primitives) fails here, at the real model widths:
   4096-slot KV cache, 512-token prefill buckets; decode folds the group
   into the rows of one tile per kv stripe, also at group 6 (48 heads over
   8 kv heads, Mixtral-8x22B / Minitron-8B), where the rows pad to 8;
-* minicpm3_4b's MLA attention (Dk 96 != Dv 64);
+* minicpm3_4b's MLA attention: prefill expanded (Dk 96 != Dv 64), decode
+  absorbed (``mla_flash_lib``: 16 slots, the 40 query heads the rows of
+  one program over a 4096-position latent stripe, keys 256 + 32, values
+  the 256-wide latent);
 * rmsnorm at d_model 4096, softmax on aligned and unaligned widths;
 * the fused ROM walk over the full default manifest with uniform (v1)
   slots, and with a segmented (v2) slot, plus the per-slot ROM read.
@@ -156,30 +159,46 @@ def test_softmax_compiles(one_chip, no_cache, lib, shape):
     _compile(fn, (_sds(shape, jnp.float32), lib), one_chip)
 
 
-# (batch, q len, kv len, heads, kv heads, Dk, Dv)
+# (batch, q len, kv len, heads, kv heads, Dk, Dv, Dr): Dr > 0 is the
+# absorbed-latent form, keys Dk + Dr in two parts and values the keys' Dk
 ATTN = {
-    "yi_6b-decode": (8, 1, 4096, 32, 4, 128, 128),
-    "yi_6b-prefill": (2, 512, 512, 32, 4, 128, 128),
-    "mixtral_8x22b-decode": (8, 1, 4096, 48, 8, 128, 128),
-    "minicpm3_4b-mla-decode": (8, 1, 4096, 40, 40, 96, 64),
-    "minicpm3_4b-mla-prefill": (1, 256, 256, 40, 40, 96, 64),
+    "yi_6b-decode": (8, 1, 4096, 32, 4, 128, 128, 0),
+    "yi_6b-prefill": (2, 512, 512, 32, 4, 128, 128, 0),
+    "mixtral_8x22b-decode": (8, 1, 4096, 48, 8, 128, 128, 0),
+    "minicpm3_4b-mla-decode": (16, 1, 4096, 40, 1, 256, 256, 32),
+    "minicpm3_4b-mla-prefill": (1, 256, 256, 40, 40, 96, 64, 0),
 }
 
 
 @pytest.mark.parametrize("case", sorted(ATTN))
 def test_flash_compiles(one_chip, no_cache, lib, case):
-    b, sq, sk, h, kvh, dk, dv = ATTN[case]
+    """Each call compiles with its whole K/V stripes VMEM-resident (the
+    compiler refuses a program whose blocks overflow VMEM). The absorbed
+    decode runs one program per slot: its query operands are (slots, 40
+    rows, D) tiles."""
+    b, sq, sk, h, kvh, dk, dv, dr = ATTN[case]
 
-    def fn(q, k, v, q_pos, kv_pos, library):
+    def fn(q, k, v, q_pos, kv_pos, library, *rope):
+        kw = {}
+        if rope:
+            v = k
+            kw = dict(q_rope=rope[0], k_rope=rope[1], scale=96 ** -0.5)
         return attention_fused_library(q, k, v, library, causal=True,
                                        q_pos=q_pos, kv_pos=kv_pos,
-                                       use_kernel=True, interpret=False)
+                                       use_kernel=True, interpret=False, **kw)
 
-    _compile(fn, (_sds((b, sq, h, dk), jnp.bfloat16),
-                  _sds((b, sk, kvh, dk), jnp.bfloat16),
-                  _sds((b, sk, kvh, dv), jnp.bfloat16),
-                  _sds((b, sq), jnp.int32), _sds((b, sk), jnp.int32), lib),
-             one_chip)
+    rope = ((_sds((b, sq, h, dr), jnp.bfloat16),
+             _sds((b, sk, kvh, dr), jnp.bfloat16)) if dr else ())
+    text = _compile(fn, (_sds((b, sq, h, dk), jnp.bfloat16),
+                         _sds((b, sk, kvh, dk), jnp.bfloat16),
+                         _sds((b, sk, kvh, dv), jnp.bfloat16),
+                         _sds((b, sq), jnp.int32), _sds((b, sk), jnp.int32),
+                         lib, *rope), one_chip)
+    if dr:
+        call = next(line for line in text.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in line)
+        assert "mla_flash_lib" in call.split("=", 1)[0]
+        assert f"bf16[{b},{h},{dk}]" in call and f"bf16[{b},{h},{dr}]" in call
 
 
 def test_served_kernels_carry_their_names(one_chip, no_cache, lib):
@@ -195,6 +214,13 @@ def test_served_kernels_carry_their_names(one_chip, no_cache, lib):
         o = attention_fused_library(q, k, v, library, q_pos=q_pos,
                                     kv_pos=kv_pos, use_kernel=True,
                                     interpret=False)
+        # absorbed MLA decode: 32 query heads over one latent stripe
+        lat = k[:, :, :1]
+        m = attention_fused_library(q, lat, lat, library,
+                                    q_pos=q_pos, kv_pos=kv_pos,
+                                    q_rope=q[..., :32], k_rope=k[:, :, :1, :32],
+                                    scale=96 ** -0.5, use_kernel=True,
+                                    interpret=False)
         y = approx_rmsnorm_library(x, gamma, library, use_kernel=True,
                                    interpret=False)
         z = approx_softmax_library(x.astype(jnp.float32), library,
@@ -203,7 +229,7 @@ def test_served_kernels_carry_their_names(one_chip, no_cache, lib):
                                interpret=False)
         w = library_walk(codes, codes, library.coeffs, walk, dp,
                          use_kernel=True, interpret=False)
-        return o, y, z, e, w
+        return o, m, y, z, e, w
 
     text = _compile(fn, (_sds((8, 1, 32, 128), jnp.bfloat16),
                          _sds((8, 512, 4, 128), jnp.bfloat16),
@@ -217,8 +243,8 @@ def test_served_kernels_carry_their_names(one_chip, no_cache, lib):
     names = {re.sub(r"\.\d+$", "", instr.match(line).group(1))
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line}
-    assert names == {"flash_lib", "rmsnorm_lib", "softmax_lib",
-                     "_library_eval", "_library_walk"}, names
+    assert names == {"flash_lib", "mla_flash_lib", "rmsnorm_lib",
+                     "softmax_lib", "_library_eval", "_library_walk"}, names
 
 
 # ---------------------------------------------------------------------------

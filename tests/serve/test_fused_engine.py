@@ -215,3 +215,49 @@ def test_fused_engine_counts_folded_attention_sites(monkeypatch):
     assert all(s[1:] == (1, 0) for s in ticks)
     assert eng.stats["attn_folded_sites"] == len(ticks)
     assert eng.stats["attn_glue_fallbacks"] == 0
+
+
+@pytest.mark.parametrize("numerics", ["exact", "interp-fused"])
+def test_engine_counts_absorbed_mla_decode_sites(monkeypatch, numerics):
+    """MLA decode attends over the latent cache in the absorbed form: every
+    attention site of a traced tick program counts once in
+    ``stats["attn_absorbed_sites"]`` (and, interp-fused, folds its heads
+    into one tile); the admission programs attend in the expanded form and
+    count none; nothing falls back to the glue path."""
+    from repro.models import attention as attn
+    from repro.numerics.ops import (ATTN_ABSORB_KEY, ATTN_FALLBACK_KEY,
+                                    ATTN_FOLD_KEY, count_attention_sites)
+    from repro.serve.aot import tick_chunk_sizes
+
+    cfg = get_smoke_config("minicpm3_4b").replace(
+        numerics=numerics, name=f"minicpm3_4b-absorb-probe-{numerics}")
+    params = tf.init_params(jax.random.key(0), cfg)
+    sites = []  # (Sq, absorbed, folded, fallbacks) of each traced site
+    real = attn.attention_core
+
+    def spy(q, *args, **kw):
+        with count_attention_sites({}) as site:
+            out = real(q, *args, **kw)
+        sites.append((q.shape[1], site.get(ATTN_ABSORB_KEY, 0),
+                      site.get(ATTN_FOLD_KEY, 0),
+                      site.get(ATTN_FALLBACK_KEY, 0)))
+        return out
+
+    monkeypatch.setattr(attn, "attention_core", spy)
+    horizon = 2
+    lib = None
+    if numerics != "exact":
+        from repro.api import default_explorer
+        lib = default_explorer().compile()
+    eng = ServeEngine(cfg, params, slots=2, cache_len=128, horizon=horizon,
+                      aot_buckets=(128,), max_pack=2, library=lib)
+    ticks = [s for s in sites if s[0] == 1]
+    admits = [s for s in sites if s[0] == 128]
+    assert len(ticks) + len(admits) == len(sites)
+    assert len(ticks) == (len(tick_chunk_sizes(horizon))
+                          * len(tf.layer_plan(cfg)))
+    assert admits and all(s[1:] == (0, 0, 0) for s in admits)
+    folded = int(numerics != "exact")
+    assert all(s[1:] == (1, folded, 0) for s in ticks)
+    assert eng.stats["attn_absorbed_sites"] == len(ticks) > 0
+    assert eng.stats["attn_glue_fallbacks"] == 0
