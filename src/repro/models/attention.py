@@ -289,38 +289,77 @@ def _decode_positions(pos: jax.Array, b: int) -> tuple[jax.Array, jax.Array]:
     return pos, positions
 
 
+def _put_token(buf: jax.Array, new: jax.Array, slot: jax.Array,
+               layer: jax.Array | None) -> jax.Array:
+    """Write each batch row's new token into ``buf`` at ``slot`` along the
+    position axis (the last axis of ``pos``, the second to last of K/V).
+
+    ``new`` is one layer's (B, ...) rows, length 1 on that axis; ``slot``
+    is () (uniform batch) or (B,) per-slot. Without ``layer``, ``buf`` is
+    that layer's buffer; with it, ``buf`` is a scanned segment's stack (L,
+    B, ...) and the token lands at [layer, b, ..., slot, ...] in place —
+    the rest of the stack is neither read nor written."""
+    new = new.astype(buf.dtype)
+    t = buf.ndim - (1 if new.ndim == 2 else 2)  # the position axis
+    if slot.ndim == 0:
+        start = [0] * buf.ndim
+        start[t] = slot
+        if layer is not None:
+            start[0], new = layer, new[None]
+        return jax.lax.dynamic_update_slice(buf, new, start)
+    if layer is None:
+        # one dynamic_update per batch row (vmap lowers these to a batched
+        # scatter)
+        def one(b_buf, b_new, s):
+            start = [0] * b_buf.ndim
+            start[t - 1] = s
+            return jax.lax.dynamic_update_slice(b_buf, b_new, start)
+
+        return jax.vmap(one)(buf, new, slot)
+    # one in-place row write per batch row into the stack
+    for i in range(new.shape[0]):
+        start = [layer, i] + [0] * (buf.ndim - 2)
+        start[t] = slot[i]
+        buf = jax.lax.dynamic_update_slice(buf, new[i][None, None], start)
+    return buf
+
+
+def _cache_write(cache: KVCache, new: KVCache, slot: jax.Array,
+                 layer: jax.Array | None) -> tuple[KVCache, KVCache]:
+    """Write the decode token's rows (``new``: K/V or latent/rotary rows,
+    and the (B, 1) positions) into ``cache`` at ``slot``. Returns (the
+    cache as written, the layer's view of it). With ``layer``, ``cache``
+    is a scanned segment's layer stack carried through the layer loop:
+    the rows land in place and the view is that layer's slice."""
+    cache = KVCache(*(_put_token(buf, n, slot, layer)
+                      for buf, n in zip(cache, new)))
+    if layer is None:
+        return cache, cache
+    return cache, KVCache(*(jax.lax.dynamic_index_in_dim(
+        buf, layer, 0, keepdims=False) for buf in cache))
+
+
 def gqa_decode(p: Params, x: jax.Array, pos: jax.Array, cache: KVCache, cfg,
-               numerics) -> tuple[jax.Array, KVCache]:
+               numerics, layer: jax.Array | None = None
+               ) -> tuple[jax.Array, KVCache]:
     """x: (B, 1, d); pos: scalar int32 (uniform across batch) or (B,)
-    per-slot positions (mixed-length continuous batching)."""
+    per-slot positions (mixed-length continuous batching). ``layer``:
+    ``cache`` is the segment's layer stack and this is its layer ``layer``
+    (see ``_cache_write``)."""
     b = x.shape[0]
     pos, positions = _decode_positions(pos, b)
     q, k, v = _gqa_qkv(p, x, positions, cfg)
-    s_max = cache.k.shape[2]
+    s_max = cache.k.shape[-2]
     slot = (pos % s_max).astype(jnp.int32) if cfg.sliding_window else pos
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    if pos.ndim == 0:
-        kc = jax.lax.dynamic_update_slice(cache.k, kt, (0, 0, slot, 0))
-        vc = jax.lax.dynamic_update_slice(cache.v, vt, (0, 0, slot, 0))
-        pc = jax.lax.dynamic_update_slice(cache.pos, positions, (0, slot))
-    else:
-        # per-slot write positions: one dynamic_update per batch row (vmap
-        # lowers these to a batched scatter)
-        upd = jax.vmap(lambda buf, new, s:
-                       jax.lax.dynamic_update_slice(buf, new, (0, s, 0)))
-        kc = upd(cache.k, kt, slot)
-        vc = upd(cache.v, vt, slot)
-        pc = jax.vmap(lambda buf, new, s:
-                      jax.lax.dynamic_update_slice(buf, new, (s,)))(
-            cache.pos, positions, slot)
-    kv_pos = pc
+    cache, (kc, vc, kv_pos) = _cache_write(
+        cache, KVCache(k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                       positions), slot, layer)
     o = attention_core(q, kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3),
                        positions, kv_pos, numerics, causal=True,
                        window=cfg.sliding_window,
                        kv_chunk=min(4096, s_max))
     y = o.reshape(b, 1, -1) @ p["wo"]
-    return y, KVCache(kc, vc, pc)
+    return y, cache
 
 
 def gqa_cache_specs(cfg, b: int, s: int, dtype) -> KVCache:
@@ -414,8 +453,9 @@ def mla_prefill(p, x, positions, cfg, numerics, cache_len: int):
     return y, KVCache(ck_buf, kr_buf, pos_buf)
 
 
-def mla_decode(p, x, pos, cache: KVCache, cfg, numerics):
-    """pos: scalar int32 or (B,) per-slot positions (continuous batching).
+def mla_decode(p, x, pos, cache: KVCache, cfg, numerics, layer=None):
+    """pos: scalar int32 or (B,) per-slot positions (continuous batching);
+    ``layer``: as ``gqa_decode``'s.
 
     Absorbed form: ``wkv_b``'s key half takes the query's no-position part
     into the latent space and its value half brings the latent output back
@@ -428,18 +468,8 @@ def mla_decode(p, x, pos, cache: KVCache, cfg, numerics):
     pos, positions = _decode_positions(pos, b)
     q = _mla_q(p, x, positions, cfg, numerics)
     ckv, kr = _mla_kv_latent(p, x, positions, cfg, numerics)
-    if pos.ndim == 0:
-        ck = jax.lax.dynamic_update_slice(cache.k, ckv, (0, pos, 0))
-        krb = jax.lax.dynamic_update_slice(cache.v, kr, (0, pos, 0))
-        pc = jax.lax.dynamic_update_slice(cache.pos, positions, (0, pos))
-    else:
-        upd = jax.vmap(lambda buf, new, s:
-                       jax.lax.dynamic_update_slice(buf, new, (s, 0)))
-        ck = upd(cache.k, ckv, pos)
-        krb = upd(cache.v, kr, pos)
-        pc = jax.vmap(lambda buf, new, s:
-                      jax.lax.dynamic_update_slice(buf, new, (s,)))(
-            cache.pos, positions, pos)
+    cache, (ck, krb, pc) = _cache_write(cache, KVCache(ckv, kr, positions),
+                                        pos, layer)
     wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, cfg.n_heads,
                                m.qk_nope_head_dim + m.v_head_dim)
     w_k, w_v = wkv_b[..., : m.qk_nope_head_dim], wkv_b[..., m.qk_nope_head_dim:]
@@ -454,7 +484,7 @@ def mla_decode(p, x, pos, cache: KVCache, cfg, numerics):
     o = jnp.einsum("bshc,chv->bshv", o, w_v,
                    preferred_element_type=jnp.float32).astype(x.dtype)
     y = o.reshape(b, 1, -1) @ p["wo"]
-    return y, KVCache(ck, krb, pc)
+    return y, cache
 
 
 def mla_cache_specs(cfg, b: int, s: int, dtype) -> KVCache:

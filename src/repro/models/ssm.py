@@ -175,8 +175,18 @@ def ssm_prefill(p: Params, x: jax.Array, cfg, numerics):
     return y, SSMState(conv=tail, ssm=h_last)
 
 
-def ssm_decode(p: Params, x: jax.Array, state: SSMState, cfg, numerics):
-    """x: (B, 1, d)."""
+def ssm_decode(p: Params, x: jax.Array, state: SSMState, cfg, numerics,
+               layer: jax.Array | None = None):
+    """x: (B, 1, d). ``layer``: ``state`` is the segment's layer stack;
+    this layer reads its slice and writes its new state back at ``layer``
+    in place (the whole state changes every token; it is small)."""
+    if layer is not None:
+        y, new = ssm_decode(p, x, jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False),
+            state), cfg, numerics)
+        return y, jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+            state, new)
     s = cfg.ssm
     d_inner, n_heads, conv_dim = _dims(cfg)
     bsz = x.shape[0]

@@ -23,6 +23,7 @@ from repro.models.layers import (Params, ShapeTree, apply_mlp, apply_norm,
                                  embed_shapes, embed_tokens, init_tree,
                                  lm_logits, mlp_shapes, norm_shapes, pdtype,
                                  spec, stack_specs)
+from repro.numerics.ops import CACHE_INPLACE_KEY, note_attention_site
 
 LOSS_CHUNK = 512
 
@@ -104,10 +105,13 @@ def segment_shapes(seg: Segment, cfg, cross: bool = False) -> ShapeTree:
 
 def apply_block(p: Params, kind: LayerKind, h, positions, cfg, numerics,
                 mode: str = "train", cache=None, cache_len: int = 0,
-                cross_kv=None, pos=None):
+                cross_kv=None, pos=None, layer=None):
     """Returns (h, new_cache, aux_loss). ``cross_kv`` is the *encoder hidden
     state* (B, S_src, d); per-layer K/V are derived from it inside the block
-    so scanned decoder stacks keep one parameter structure."""
+    so scanned decoder stacks keep one parameter structure. ``layer``
+    (decode): ``cache`` is the scanned segment's layer stack, this block
+    its layer ``layer``; the block writes its token into the stack in place
+    and returns the whole stack."""
     # Megatron sequence parallelism (perf iteration C1): the residual stream
     # is sharded along seq over the model axis between mixer/FFN bodies —
     # norms/adds run 1/16th-size, the scan carry shrinks 16x, and GSPMD
@@ -129,21 +133,24 @@ def apply_block(p: Params, kind: LayerKind, h, positions, cfg, numerics,
         elif mode == "prefill":
             y, new_cache = ssm_mod.ssm_prefill(p["mixer"], x, cfg, numerics)
         else:
-            y, new_cache = ssm_mod.ssm_decode(p["mixer"], x, cache, cfg, numerics)
+            y, new_cache = ssm_mod.ssm_decode(p["mixer"], x, cache, cfg,
+                                              numerics, layer)
     elif kind.mixer == "mla":
         if mode == "train":
             y = attn.mla_train(p["mixer"], x, positions, cfg, numerics)
         elif mode == "prefill":
             y, new_cache = attn.mla_prefill(p["mixer"], x, positions, cfg, numerics, cache_len)
         else:
-            y, new_cache = attn.mla_decode(p["mixer"], x, pos, cache, cfg, numerics)
+            y, new_cache = attn.mla_decode(p["mixer"], x, pos, cache, cfg,
+                                           numerics, layer)
     else:
         if mode == "train":
             y = attn.gqa_train(p["mixer"], x, positions, cfg, numerics)
         elif mode == "prefill":
             y, new_cache = attn.gqa_prefill(p["mixer"], x, positions, cfg, numerics, cache_len)
         else:
-            y, new_cache = attn.gqa_decode(p["mixer"], x, pos, cache, cfg, numerics)
+            y, new_cache = attn.gqa_decode(p["mixer"], x, pos, cache, cfg,
+                                           numerics, layer)
     h = h + y
     if cross_kv is not None:
         xc = apply_norm(p["norm_x"], h, cfg, numerics)
@@ -186,6 +193,13 @@ def apply_segment(p_seg: Params, seg: Segment, h, positions, cfg, numerics,
     plan collapses to a single run over the unsliced stack, i.e. exactly
     the homogeneous program.
 
+    Decode carries the stacked caches through the layer loop (carry, not
+    xs/ys): each layer writes its token's rows into the stack in place at
+    its layer index and reads its slice for attention, so no layer writes
+    its whole slice back and no step copies the stack; every group of a
+    split plan scans the one stack at its offset. Counted once per traced
+    segment under ``CACHE_INPLACE_KEY``.
+
     Returns (h, stacked caches or None, aux sum).
     """
     npat = len(seg.pattern)
@@ -206,6 +220,19 @@ def apply_segment(p_seg: Params, seg: Segment, h, positions, cfg, numerics,
                 new_caches[str(i)] = nc
                 aux_sum = aux_sum + aux
             return h_in, (new_caches, aux_sum)
+        return body
+
+    def make_decode_body(layer_nums):
+        def body(carry, xs):
+            h_in, stack = carry
+            p_layer, layer = xs
+            stack = dict(stack)
+            for i, kind in enumerate(seg.pattern):
+                h_in, stack[str(i)], _ = apply_block(
+                    p_layer[str(i)], kind, h_in, positions, cfg,
+                    layer_nums[i], mode=mode, cache=stack[str(i)],
+                    cross_kv=cross_kv, pos=pos, layer=layer)
+            return (h_in, stack), None
         return body
 
     plan_aware = hasattr(numerics, "for_layer")
@@ -229,6 +256,17 @@ def apply_segment(p_seg: Params, seg: Segment, h, positions, cfg, numerics,
             groups[-1][1] += 1
         else:
             groups.append([r, 1, nt])
+
+    if mode == "decode":
+        note_attention_site(CACHE_INPLACE_KEY)
+        for start, length, nt in groups:
+            p_sl = p_seg if len(groups) == 1 else jax.tree.map(
+                lambda x: jax.lax.slice_in_dim(x, start, start + length,
+                                               axis=0), p_seg)
+            (h, caches), _ = jax.lax.scan(
+                make_decode_body(nt), (h, caches),
+                (p_sl, jnp.arange(start, start + length, dtype=jnp.int32)))
+        return h, caches, jnp.zeros((), jnp.float32)
 
     if len(groups) == 1:
         body_fn = (_maybe_remat(make_body(groups[0][2]), cfg)

@@ -41,20 +41,24 @@ LOG2E = 1.4426950408889634
 # programs in one) gets ATTN_FALLBACK_KEY incremented each time a traced
 # attention takes the chunked glue path instead of the fused kernel, and
 # ATTN_FOLD_KEY each time a traced fused attention folds a group's query
-# heads into the rows of one tile per kv stripe, and ATTN_ABSORB_KEY each
-# time an MLA decode attention is traced in the absorbed-latent form.
+# heads into the rows of one tile per kv stripe, ATTN_ABSORB_KEY each
+# time an MLA decode attention is traced in the absorbed-latent form, and
+# CACHE_INPLACE_KEY each time a scanned decode segment is traced with its
+# stacked caches carried through the layer loop (written in place).
 ATTN_FALLBACK_KEY = "attn_glue_fallbacks"
 ATTN_FOLD_KEY = "attn_folded_sites"
 ATTN_ABSORB_KEY = "attn_absorbed_sites"
+CACHE_INPLACE_KEY = "cache_inplace_sites"
 _SINKS = threading.local()
 
 
 @contextlib.contextmanager
 def count_attention_sites(sink: dict):
     """Count, into ``sink``, every fused-attention refusal
-    (``ATTN_FALLBACK_KEY``), folded flash call (``ATTN_FOLD_KEY``) and
-    absorbed MLA decode attention (``ATTN_ABSORB_KEY``) traced inside the
-    block (the count is per trace: a program that is
+    (``ATTN_FALLBACK_KEY``), folded flash call (``ATTN_FOLD_KEY``),
+    absorbed MLA decode attention (``ATTN_ABSORB_KEY``) and scanned decode
+    segment carrying its cache stack (``CACHE_INPLACE_KEY``) traced inside
+    the block (the count is per trace: a program that is
     already compiled is not traced again)."""
     stack = getattr(_SINKS, "stack", None)
     if stack is None:
@@ -67,7 +71,7 @@ def count_attention_sites(sink: dict):
 
 
 def note_attention_site(key: str) -> None:
-    """Count one traced attention site under ``key`` in every active sink."""
+    """Count one traced site under ``key`` in every active sink."""
     for sink in getattr(_SINKS, "stack", ()):
         sink[key] = sink.get(key, 0) + 1
 

@@ -80,8 +80,9 @@ from repro.faults.inject import crashpoint
 from repro.launch import sharding as shlib
 from repro.models import transformer as tf
 from repro.numerics.ops import (ATTN_ABSORB_KEY, ATTN_FALLBACK_KEY,
-                                ATTN_FOLD_KEY, INTERP_BACKENDS,
-                                count_attention_sites, get_numerics)
+                                ATTN_FOLD_KEY, CACHE_INPLACE_KEY,
+                                INTERP_BACKENDS, count_attention_sites,
+                                get_numerics)
 from repro.serve import aot as aot_mod
 from repro.serve import spans as span_lib
 from repro.serve.journal import ServeJournal, load_requests
@@ -443,7 +444,7 @@ class ServeEngine:
                           "packed_requests": 0, "admit_dispatches": 0,
                           "async_chunks": 0, "async_tokens": 0,
                           ATTN_FALLBACK_KEY: 0, ATTN_FOLD_KEY: 0,
-                          ATTN_ABSORB_KEY: 0}
+                          ATTN_ABSORB_KEY: 0, CACHE_INPLACE_KEY: 0}
             self.faults: list[dict] = []  # structured fault/degradation log
             self._trips = 0  # watchdog trips since the last degradation
             # requests the running step() admitted, and those it ended:
@@ -551,7 +552,9 @@ class ServeEngine:
         ``stats["attn_folded_sites"]``: flash calls traced with a group's
         query heads folded into one tile's rows;
         ``stats["attn_absorbed_sites"]``: MLA decode attentions traced in
-        the absorbed-latent form)."""
+        the absorbed-latent form; ``stats["cache_inplace_sites"]``: scanned
+        decode segments traced with their cache stack carried through the
+        layer loop)."""
         stack = contextlib.ExitStack()
         if self.mesh is not None:
             stack.enter_context(shlib.axis_rules(self.mesh))

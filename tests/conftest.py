@@ -47,3 +47,14 @@ except ImportError:
     st_mod.__getattr__ = lambda name: _st  # PEP 562 module-level fallback
     sys.modules["hypothesis"] = stub
     sys.modules["hypothesis.strategies"] = st_mod
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _fresh_default_span_recorder():
+    """Each test file starts from a fresh default span recorder, as a
+    process of its own would: the spans of the newest engine another file
+    built on the same worker never reach this file's readers."""
+    spans = sys.modules.get("repro.serve.spans")
+    if spans is not None:
+        spans.set_default(spans.SpanRecorder())
+    yield

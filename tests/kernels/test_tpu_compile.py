@@ -17,7 +17,10 @@ unlowerable primitives) fails here, at the real model widths:
   the 256-wide latent);
 * rmsnorm at d_model 4096, softmax on aligned and unaligned widths;
 * the fused ROM walk over the full default manifest with uniform (v1)
-  slots, and with a segmented (v2) slot, plus the per-slot ROM read.
+  slots, and with a segmented (v2) slot, plus the per-slot ROM read;
+* the served decode tick of yi_6b and minicpm3_4b (2 layers, 16 slots x
+  4096): the cache stack stays one buffer through the layer and step
+  loops, written one token row at a time.
 
 The topology and the shardings built from it live in module fixtures, so
 the TPU library is loaded only by the test process that runs this file.
@@ -25,6 +28,7 @@ the TPU library is loaded only by the test process that runs this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -245,6 +249,90 @@ def test_served_kernels_carry_their_names(one_chip, no_cache, lib):
              if 'custom_call_target="tpu_custom_call"' in line}
     assert names == {"flash_lib", "mla_flash_lib", "rmsnorm_lib",
                      "softmax_lib", "_library_eval", "_library_walk"}, names
+
+
+# ---------------------------------------------------------------------------
+# the engine tick: the cache stack stays one buffer through both loops
+# ---------------------------------------------------------------------------
+
+# the temporaries (bytes) of these ticks (2 layers, 16 slots x 4096,
+# horizon 2, interp-fused, jax 0.9.0 / libtpu 0.0.34) when the layer loop
+# took the caches as xs and returned them as ys: each decode step wrote
+# every layer's whole slice back and copied the stack
+XS_YS_TEMP_BYTES = {"yi_6b": 489_127_936, "minicpm3_4b": 126_959_616}
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(%([\w.\-]+)(?:, %([\w.\-]+))?")
+
+
+def _stack_ops(text: str, stacks: set) -> list:
+    """(op, dims, update dims) of every ``copy`` and
+    ``dynamic-update-slice`` outside the entry computation (in the step loop, the layer loop and
+    what they call) whose result has a stacked cache's dims."""
+    dims, ops, entry = {}, [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, shape, op, _, update = m.groups()
+        dims[name] = tuple(int(d) for d in shape.split(",") if d)
+        if (not entry and op in ("copy", "dynamic-update-slice")
+                and dims[name] in stacks):
+            ops.append((op, dims[name], update))
+    return [(op, d, dims.get(update)) for op, d, update in ops]
+
+
+@pytest.mark.parametrize("arch", sorted(XS_YS_TEMP_BYTES))
+def test_tick_carries_cache_stack_in_place(one_chip, no_cache, lib,
+                                           monkeypatch, arch):
+    """The served tick at published widths (2 layers, 16 slots x 4096,
+    horizon 2), kernel path forced: its one scanned segment is traced with
+    the stack carried, no step copies the stack and no layer writes its
+    whole slice back. Every write into a stack inside the loops
+    is one token row (layer, slot and position extents 1); the
+    temporaries fall against the xs/ys loop's by at least 80% of one
+    stacked cache."""
+    import math
+
+    from repro.configs.base import get_config
+    from repro.models import transformer as tf
+    from repro.numerics.ops import CACHE_INPLACE_KEY, count_attention_sites
+    from repro.serve.engine import make_engine_tick
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config(arch).replace(n_layers=2, numerics="interp-fused")
+    slots, cache_len = 16, 4096
+    caches = tf.cache_shapes(cfg, slots, cache_len)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    tick = jax.jit(make_engine_tick(cfg, 2), donate_argnums=(1, 2, 4))
+    with count_attention_sites({}) as sites:
+        lowered = tick.lower(place(tf.model_shapes(cfg)),
+                             place(_sds((slots, 1), jnp.int32)),
+                             place(_sds((slots,), jnp.int32)),
+                             place(_sds((slots,), jnp.bool_)), place(caches),
+                             library=place(lib))
+    assert sites[CACHE_INPLACE_KEY] == len(tf.layer_plan(cfg)) == 1
+    exe = lowered.compile()
+    text = exe.as_text()
+    assert "mla_flash_lib" in text if cfg.mla else "flash_lib" in text
+    leaves = jax.tree.leaves(caches)
+    stacks = {a.shape for a in leaves}
+    writes = _stack_ops(text, stacks)
+    assert writes and not [w for w in writes if w[0] == "copy"], writes
+    for _, stack, upd in writes:
+        # (layers, slots, [kv heads,] positions, [width]): pos has rank 3
+        at = len(stack) - (1 if len(stack) == 3 else 2)
+        assert upd and (upd[0], upd[1], upd[at]) == (1, 1, 1), (stack, upd)
+    stack_bytes = sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves)
+    temp = exe.memory_analysis().temp_size_in_bytes
+    assert temp <= XS_YS_TEMP_BYTES[arch] - 0.8 * stack_bytes, (temp,
+                                                                 stack_bytes)
 
 
 # ---------------------------------------------------------------------------
