@@ -376,9 +376,9 @@ def phase_mesh(cfg, params, lib, sizes: Sizes, seed: int,
                 f"shard {tuple(wq.sharding.shard_shape(wq.shape))}")
         log(f"[{name}] memory after placement: {memory_line()}")
         with eng._ctx():
-            lg, _, _ = eng._prefill_fnum(eng.params,
-                                         jnp.asarray(prompts[0])[None, :],
-                                         library=eng.library)
+            lg, _, _ = eng._prefill(eng.params,
+                                    jnp.asarray(prompts[0])[None, :],
+                                    library=eng.library)
         logits[name] = np.asarray(lg, np.float32)
         toks[name], wall = serve(eng, prompts, sizes, cfg.vocab_size)
         log(f"[{name}] served {len(toks[name])} requests, wall {wall:.3f}s "

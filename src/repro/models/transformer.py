@@ -542,7 +542,7 @@ def mask_cache_tail(caches: Params, true_lens: jax.Array) -> Params:
     assigns them ``NEG`` scores, and decode overwrites row ``p`` in place
     when the sequence actually reaches position ``p``. Only positional
     (attention) caches carry a ``pos`` leaf; SSM state is not positional and
-    cannot be padded-prefilled at all (callers gate on the layer plan).
+    cannot be padded-prefilled at all (callers gate on :func:`packable`).
     """
     lens = jnp.asarray(true_lens, jnp.int32)
 
@@ -560,6 +560,31 @@ def mask_cache_tail(caches: Params, true_lens: jax.Array) -> Params:
         treedef, [one(path, leaf) for path, leaf in flat])
 
 
+def _padding_refusal(cfg) -> str | None:
+    """Why ``cfg`` cannot be prefilled right-padded, or None if it can."""
+    if cfg.encoder is not None or cfg.frontend is not None:
+        return "encoder/frontend configs must use exact-length prefill"
+    if cfg.sliding_window is not None:
+        return "sliding-window caches wrap; use exact-length prefill"
+    kinds = [k for seg in layer_plan(cfg) for k in seg.pattern]
+    if any(k.mixer == "ssm" for k in kinds):
+        return ("SSM state is cumulative, a pad suffix corrupts it; use "
+                "exact-length prefill")
+    if any(k.ffn == "moe" for k in kinds):
+        return ("expert capacity grows with the padded length and keeps "
+                "tokens an exact-length prefill drops; use exact-length "
+                "prefill")
+    return None
+
+
+def packable(cfg) -> bool:
+    """Can ``cfg`` be prefilled right-padded (:func:`prefill_padded`), and
+    so packed several prompts to one bucketed dispatch? True for attention-
+    cache decoders with no sliding window, no encoder, no front end, no SSM
+    mixer and no capacity-bounded MoE layer."""
+    return _padding_refusal(cfg) is None
+
+
 def prefill_padded(p: Params, tokens: jax.Array, true_lens: jax.Array, cfg,
                    numerics, cache_len: int):
     """Bucketed prefill: ``tokens`` is (B, S_bucket) with each row right-
@@ -572,20 +597,15 @@ def prefill_padded(p: Params, tokens: jax.Array, true_lens: jax.Array, cfg,
     the same values as an exact-length prefill of that prompt (the pad
     suffix can only influence *later* positions), so the gathered logits
     match the exact path and :func:`mask_cache_tail` is the only repair the
-    caches need. Restricted to attention-cache decoder-only configs: SSM
-    state is cumulative (a pad token pollutes it for good), sliding-window
-    caches wrap ``pos % cache_len``, and encoder/frontend extras carry no
-    per-row length — callers fall back to exact-length prefill there.
+    caches need. Restricted to :func:`packable` configs: SSM state is
+    cumulative (a pad token pollutes it for good), sliding-window caches
+    wrap ``pos % cache_len``, encoder/frontend extras carry no per-row
+    length, and MoE expert capacity scales with the padded length — callers
+    fall back to exact-length prefill there.
     """
-    if cfg.encoder is not None or cfg.frontend is not None:
-        raise ValueError("prefill_padded: encoder/frontend configs must "
-                         "use exact-length prefill")
-    if cfg.sliding_window is not None:
-        raise ValueError("prefill_padded: sliding-window caches wrap; use "
-                         "exact-length prefill")
-    if any(k.mixer == "ssm" for seg in layer_plan(cfg) for k in seg.pattern):
-        raise ValueError("prefill_padded: SSM state is cumulative, a pad "
-                         "suffix corrupts it; use exact-length prefill")
+    why = _padding_refusal(cfg)
+    if why is not None:
+        raise ValueError(f"prefill_padded: {why}")
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     h = _embed_inputs(p, tokens, positions, cfg, numerics)

@@ -18,14 +18,22 @@ steps as an explicit pytree argument, alongside params and caches. That is
 what makes the deployed tables shardable (replicated leaf), donatable and
 checkpointable instead of ambient global state.
 
-Since ISSUE 5 the default engine path is *fused* (DESIGN.md §12): one
-jitted multi-slot tick per chunk of decode steps — greedy argmax and the
-per-slot position bump happen inside the program, the KV cache (and slot
-state) buffers are **donated** so XLA updates them in place instead of
-copying every tick, and interp numerics lower through the library-bound
-fused kernels (ROM gather + Horner inside softmax/rmsnorm/attention). The
-serial per-op path (`fused=False`) is kept as the dispatch-per-op oracle
-and benchmark baseline.
+The default engine is *fused* (DESIGN.md §12): one jitted multi-slot tick
+per chunk of decode steps — greedy argmax and the per-slot position bump
+happen inside the program, the KV cache (and slot state) buffers are
+**donated** so XLA updates them in place instead of copying every tick, and
+interp numerics lower through the library-bound fused kernels (ROM gather +
+Horner inside softmax/rmsnorm/attention). ``fused=False`` decodes one
+dispatch per token with a host argmax, on unfused numerics: the serial rung
+of the degradation ladder.
+
+Admission has one path whatever the engine's mode: the queue front drains
+into free slots in ascending order, each request gets an admission length
+(its prefill bucket where the engine pads — fused, a bucket table set, a
+:func:`~repro.models.transformer.packable` config — else its own prompt
+length), and each group of same-length requests is one dispatch of
+:func:`make_engine_admit`: prefill, pool splice, greedy first token and
+slot state in one program.
 
 Since ISSUE 7 the engine carries the serving-robustness layer
 (DESIGN.md §14):
@@ -125,51 +133,34 @@ def make_prefill(cfg, cache_len: int, fused: bool = False) -> Callable:
     return pf
 
 
-def make_engine_admit(cfg, cache_len: int) -> Callable:
-    """Fused admission: prefill + pool splice + greedy first token + slot-
-    state update in ONE dispatch.
+def make_engine_admit(cfg, cache_len: int, pack: int,
+                      fused: bool) -> Callable:
+    """Admission: prefill ``pack`` prompts, splice each into its slot, take
+    each greedy first token and set its slot state — ONE dispatch.
 
-    admit(params, prompt (1,S), pool, slot (), tok (B,1), pos (B,),
-    live (B,), library=None) -> (first_token (), pool, tok, pos, live).
-    ``pool`` and the slot-state vectors are donated by the engine — an
-    admission splices the new request's cache rows in place and flips its
-    slot live without a host round-trip per update (the eager ``.at[].set``
-    path recompiled per concrete index/token value).
-    """
-
-    def admit(params, prompt, pool, slot, tok, pos, live, library=None):
-        numerics = get_numerics(cfg, library,
-                                fused=_interp(cfg))
-        logits, cache1, _ = tf.prefill(params, prompt, cfg, numerics,
-                                       cache_len)
-        pool = tf.splice_cache(cfg, pool, cache1, slot)
-        first = jnp.argmax(logits[0, -1]).astype(jnp.int32)
-        tok = tok.at[slot, 0].set(first)
-        pos = pos.at[slot].set(prompt.shape[1])
-        live = live.at[slot].set(True)
-        return first, pool, tok, pos, live
-
-    return admit
-
-
-def make_engine_admit_packed(cfg, cache_len: int, pack: int) -> Callable:
-    """Bucketed admission: prefill ``pack`` right-padded prompts, splice
-    each into its slot, take each greedy first token — ONE dispatch.
-
-    admit(params, prompts (P, S_bucket), true_lens (P,), slots (P,), pool,
+    admit(params, prompts (P, S), true_lens (P,), slots (P,), pool,
     tok (B,1), pos (B,), live (B,), library=None) -> (firsts (P,), pool,
-    tok, pos, live). ``prompts`` rows are right-padded to the bucket length
-    (pad id 0 — any in-vocab id works, the pad tail is causally invisible
-    and its cache rows are masked dead by ``prefill_padded``); the splice
-    loop unrolls over the static pack size with traced slot indices, so one
-    compiled program serves every slot assignment."""
+    tok, pos, live). A :func:`~repro.models.transformer.packable` config
+    prefills through :func:`~repro.models.transformer.prefill_padded`: rows
+    are right-padded to ``S`` (pad id 0 — any in-vocab id works, the pad
+    tail is causally invisible and its cache rows are masked dead); any
+    other config prefills one unpadded prompt (``P = 1``, ``S`` its length).
+    The splice loop unrolls over the static pack size with traced slot
+    indices, so one compiled program serves every slot assignment. The
+    engine donates ``pool`` and the slot-state vectors: the splice lands in
+    place. ``fused`` picks the numerics as the engine's do."""
+    padded = tf.packable(cfg)
 
     def admit(params, prompts, true_lens, slots, pool, tok, pos, live,
               library=None):
-        numerics = get_numerics(cfg, library, fused=_interp(cfg))
-        logits, cache_p, _ = tf.prefill_padded(params, prompts, true_lens,
-                                               cfg, numerics, cache_len)
-        firsts = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)  # (P,)
+        numerics = get_numerics(cfg, library, fused=fused and _interp(cfg))
+        if padded:
+            logits, cache_p, _ = tf.prefill_padded(
+                params, prompts, true_lens, cfg, numerics, cache_len)
+        else:
+            logits, cache_p, _ = tf.prefill(params, prompts, cfg, numerics,
+                                            cache_len)
+        firsts = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)  # (P,)
         for i in range(pack):
             one = tf.extract_cache_row(cfg, cache_p, i)
             pool = tf.splice_cache(cfg, pool, one, slots[i])
@@ -288,11 +279,12 @@ class ServeEngine:
 
     ``fused`` (default): each engine tick is ONE donated-buffer dispatch
     covering up to ``horizon`` decode steps (``make_engine_tick``); interp
-    numerics run the library-bound fused kernels. ``fused=False`` keeps the
-    ISSUE-3/4 serial path — one decode dispatch plus a host argmax round-
-    trip per token — as the oracle and benchmark baseline. ``self.stats``
-    counts host→device program dispatches and device→host transfers either
-    way (the numbers ``benchmarks/decode_fused.py`` reports).
+    numerics run the library-bound fused kernels. ``fused=False`` decodes
+    one dispatch per token plus a host argmax round-trip, on unfused
+    numerics — the serial rung the degradation ladder falls to. Either way
+    admission is one dispatch of :func:`make_engine_admit` per group of
+    requests (:meth:`_admit`). ``self.stats`` counts host→device program
+    dispatches and device→host transfers.
 
     Robustness knobs (ISSUE 7, DESIGN.md §14):
 
@@ -330,12 +322,17 @@ class ServeEngine:
                          ROM(s) replicated, slot-state batch-sharded over
                          ``data`` (the AOT fixed point). ``None`` = single
                          host (legacy).
-    ``aot_buckets``      AOT warm-up: ``True`` (default bucket table
-                         clipped to ``cache_len``), a tuple of prefill
-                         bucket lengths, or ``None`` (lazy jit, legacy).
-                         Short prompts pack into one padded bucketed
-                         prefill dispatch; longer-than-every-bucket prompts
-                         fall back to exact-length admission
+    ``aot_buckets``      prefill bucket table (fused engines only):
+                         ``True`` (default table clipped to ``cache_len``),
+                         a tuple of bucket lengths, or ``None`` (no table:
+                         every request is admitted at its own length and
+                         programs compile on first use). With a table the
+                         tick is compiled at construction; on a packable
+                         config every prompt is padded to its bucket,
+                         same-bucket prompts pack into one dispatch, and
+                         each (bucket, pack) admission program is compiled
+                         at construction too. A prompt longer than every
+                         bucket is admitted at its own length
                          (``stats["aot_fallbacks"]``).
     ``max_pack``         largest packed-admission group compiled (grouping
                          uses powers of two up to ``min(max_pack, slots)``).
@@ -467,14 +464,6 @@ class ServeEngine:
             # retirement and chunk sizing cannot read len(Request.out) once
             # the async pipeline extends it from the worker
             self._emitted = np.zeros(slots, np.int64)
-            # bucketed (padded) prefill packing is only sound for pure
-            # attention-cache decoders: SSM state is cumulative, windowed
-            # caches wrap, encoder/frontend extras carry no per-row length
-            self._packable = (
-                cfg.sliding_window is None and cfg.encoder is None
-                and cfg.frontend is None
-                and not any(k.mixer == "ssm" for seg in tf.layer_plan(cfg)
-                            for k in seg.pattern))
             if aot_buckets is None:
                 self.aot_buckets = None
             elif aot_buckets is True:
@@ -570,8 +559,9 @@ class ServeEngine:
     def _warm_aot(self) -> None:
         """AOT warm-up (DESIGN.md §17): compile every steady-state program —
         the fused tick at each power-of-two chunk size up to ``horizon``,
-        plus a packed bucketed-admission program per (bucket, pack-size)
-        pair — at construction, so no request ever pays a compile.
+        plus, on a packable config, the admission program per (bucket,
+        pack-size) pair — at construction, so no request ever pays a
+        compile.
         ``stats["aot_compiles"]`` counts fresh compiles (reconstructed
         engines hit the shared executable cache and count nothing)."""
         if self.aot_buckets is None:
@@ -592,7 +582,7 @@ class ServeEngine:
                         (self.params, self._tok_dev, self._pos_dev,
                          self._live_dev, self.caches),
                         {"library": self.library}, self._spans)
-            if not self._packable:
+            if not tf.packable(self.cfg):
                 return
             for bucket in self.aot_buckets.buckets:
                 for pk in self._pack_sizes:
@@ -609,7 +599,7 @@ class ServeEngine:
                     with sp("engine.aot.program",
                             key=f"admit_packed/{bucket}/{pk}"):
                         aot_mod.compile_cached(
-                            key, self._packed_jit(pk),
+                            key, self._admit_jit(pk),
                             (self.params, prompts, lens, slots0,
                              self.caches, self._tok_dev, self._pos_dev,
                              self._live_dev),
@@ -621,19 +611,15 @@ class ServeEngine:
         # made inside its axis-rules context and must never be confused with
         # a single-host engine's traces for the same frozen cfg
         cfg, cache_len, mk = self.cfg, self.cache_len, self._mesh_key
-        self._prefill1 = _cached_jit(("prefill", cfg, cache_len, mk),
-                                     lambda: make_prefill(cfg, cache_len))
-        self._decode = _cached_jit(("decode", cfg, mk),
-                                   lambda: make_serve_step(cfg))
-        # fused-numerics twins of prefill/decode for resume replay: the
-        # teacher-forced rebuild must re-run the exact float path the fused
-        # admission/tick ran pre-crash (DESIGN.md §14)
-        self._prefill_fnum = _cached_jit(
-            ("prefill-fnum", cfg, cache_len, mk),
-            lambda: make_prefill(cfg, cache_len, fused=_interp(cfg)))
-        self._decode_fnum = _cached_jit(
-            ("decode-fnum", cfg, mk),
-            lambda: make_serve_step(cfg, fused=_interp(cfg)))
+        # prefill/decode on the engine's numerics: resume replay's teacher-
+        # forced rebuild re-runs the float path admission and the tick ran
+        # pre-crash (DESIGN.md §14); the serial tick decodes through _decode
+        fnum = self.fused and _interp(cfg)
+        self._prefill = _cached_jit(
+            ("prefill", cfg, cache_len, fnum, mk),
+            lambda: make_prefill(cfg, cache_len, fused=fnum))
+        self._decode = _cached_jit(("decode", cfg, fnum, mk),
+                                   lambda: make_serve_step(cfg, fused=fnum))
         # serial-path argmax + watchdog sentinel in one program: same
         # dispatch/transfer budget as the bare argmax it replaces
         self._argmax_ok = _cached_jit(
@@ -641,18 +627,12 @@ class ServeEngine:
             lambda: (lambda logits: (
                 jnp.argmax(logits[:, 0], -1).astype(jnp.int32),
                 jnp.all(jnp.isfinite(logits[:, 0]), axis=-1))))
-        # admission splice: donate the pool so slot insertion is in place
+        # replay splice: donate the pool so slot insertion is in place
         self._splice = _cached_jit(
             ("splice", cfg, mk),
             lambda: (lambda pool, one, slot:
                      tf.splice_cache(cfg, pool, one, slot)),
             donate_argnums=(0,))
-        # fused admission: prefill + splice + first-token argmax + slot
-        # state, one dispatch, pool and slot-state buffers donated
-        self._admit_fused = _cached_jit(
-            ("admit", cfg, cache_len, mk),
-            lambda: make_engine_admit(cfg, cache_len),
-            donate_argnums=(2, 4, 5, 6))
         # retire flips one slot's liveness (traced index: one trace total,
         # unlike the eager .at[].set which recompiles per concrete index)
         self._set_live = _cached_jit(
@@ -673,13 +653,14 @@ class ServeEngine:
                            lambda: make_engine_tick(self.cfg, steps),
                            donate_argnums=(1, 2, 4))
 
-    def _packed_jit(self, pack: int) -> Callable:
-        """Jitted packed bucketed admission for a static pack size (the
-        bucket length is a shape, not a key — one jit object, one trace per
-        bucket); pool + slot-state donated like the single admit."""
+    def _admit_jit(self, pack: int) -> Callable:
+        """Jitted admission for a static pack size (the prompt length is a
+        shape, not a key — one jit object, one trace per length); pool +
+        slot-state donated."""
+        cfg, fnum = self.cfg, self.fused and _interp(self.cfg)
         return _cached_jit(
-            ("admit_packed", self.cfg, self.cache_len, pack, self._mesh_key),
-            lambda: make_engine_admit_packed(self.cfg, self.cache_len, pack),
+            ("admit", cfg, self.cache_len, pack, fnum, self._mesh_key),
+            lambda: make_engine_admit(cfg, self.cache_len, pack, fnum),
             donate_argnums=(4, 5, 6, 7))
 
     def _tick_fn(self, steps: int) -> Callable:
@@ -699,8 +680,10 @@ class ServeEngine:
         self.stats["aot_misses"] += 1
         return jit_fn
 
-    def _packed_fn(self, bucket: int, pack: int) -> Callable:
-        jit_fn = self._packed_jit(pack)
+    def _admit_fn(self, bucket: int, pack: int) -> Callable:
+        """The warmed admission executable for a bucket, counted like
+        :meth:`_tick_fn`."""
+        jit_fn = self._admit_jit(pack)
         exe = aot_mod.lookup(self._aot_key("admit_packed", bucket, pack))
         if exe is not None:
             self.stats["aot_hits"] += 1
@@ -957,13 +940,6 @@ class ServeEngine:
     def _expired(self, r: Request) -> bool:
         return r.deadline is not None and self.clock() > r.deadline
 
-    def _admit(self):
-        if (self.aot_buckets is not None and self.fused
-                and self._packable):
-            self._admit_bucketed()
-        else:
-            self._admit_legacy()
-
     def _fail_expired_queued(self, r: Request) -> None:
         """Expired while queued: fail without burning a prefill."""
         r.error = "deadline_exceeded"
@@ -972,85 +948,17 @@ class ServeEngine:
         self.stats["expired"] += 1
         self._journal("fail", r.rid, r.error)
 
-    def _admit_legacy(self):
-        for s in range(self.slots):
-            while self.req[s] is None and self.queue:
-                r = self.queue.popleft()
-                if self._expired(r):
-                    # keep draining into this slot
-                    self._fail_expired_queued(r)
-                    continue
-                if r.out:  # resumed mid-stream: rebuild, emit nothing
-                    self._admit_replay(r, s)
-                    break
-                self._admit_one(r, s)
-                break
-
-    def _admit_one(self, r: Request, s: int):
-        """Exact-length admission of one request into slot ``s`` (the PR-5
-        path; also the bucketed path's fallback for prompts longer than
-        every bucket)."""
-        self.stats["admit_dispatches"] += 1
-        with self._spans.span("engine.prefill", bucket=len(r.prompt), pack=1,
-                              rids=(r.rid,)):
-            tok = self._prefill_one(r, s)
-        self._firsts_now.append(r)
-        if tok is None:
-            return
-        r.out.append(tok)
-        self.cur[s] = tok
-        if self.journal is not None:
-            self.journal.emit(r.rid, [tok])
-            crashpoint("serve.admit.emitted")
-
-    def _prefill_one(self, r: Request, s: int) -> int | None:
-        """The dispatch of :meth:`_admit_one`; returns the first token, or
-        None where the async pipeline downloads it."""
-        r.admitted_at = self.clock()
-        if self.fused:
-            # one dispatch: prefill + in-place pool splice + greedy
-            # first token + slot-state update (donated buffers)
-            with self._ctx():
-                (first, self.caches, self._tok_dev, self._pos_dev,
-                 self._live_dev) = self._admit_fused(
-                    self.params, r.prompt[None, :], self.caches, s,
-                    self._tok_dev, self._pos_dev, self._live_dev,
-                    library=self.library)
-            self.req[s] = r
-            self.pos[s] = len(r.prompt)
-            self._emitted[s] = 1
-            if self.pipeline is not None:
-                # first-token download + journal emit happen on the worker,
-                # in order with every other journal write
-                self.pipeline.emit_admit(((0, r),), first)
-                return None
-            with self._spans.span("engine.sync") as sync:
-                tok = int(first)
-        else:
-            with self._ctx():
-                logits, cache1, _ = self._prefill1(
-                    self.params, r.prompt[None, :], library=self.library)
-                # splice this request's cache rows into slot s of the
-                # pool (batch axis differs per segment: tf.splice_cache
-                # knows the stacked-layer layout); the pool buffer is
-                # donated — the insertion is in place, not a pool copy
-                self.caches = self._splice(self.caches, cache1, s)
-                with self._spans.span("engine.sync") as sync:
-                    tok = int(jnp.argmax(logits[0, -1]))
-            self.req[s] = r
-            self.pos[s] = len(r.prompt)
-            self._emitted[s] = 1
-        r.first_token_at = sync.span.t1
-        return tok
-
-    def _admit_bucketed(self):
-        """Bucketed admission (DESIGN.md §17): drain the queue front into
-        free slots in ascending order exactly like the legacy loop — the
-        (request, slot) mapping is fixed *before* grouping, so packing never
-        reorders admissions — then group same-bucket admissions and dispatch
-        each group as one padded packed prefill."""
+    def _admit(self):
+        """Drain the queue front into free slots in ascending order. The
+        (request, slot) mapping is fixed as the queue drains, so grouping
+        never reorders admissions. A request is admitted at its bucket where
+        the engine pads (fused, a bucket table set, a packable config), else
+        at its own prompt length; own-length requests dispatch at once, and
+        same-bucket requests then pack into one dispatch per group."""
+        pads = (self.fused and self.aot_buckets is not None
+                and tf.packable(self.cfg))
         free = [s for s in range(self.slots) if self.req[s] is None]
-        packed: list[tuple[Request, int, int]] = []
+        by_bucket: dict[int, list] = {}
         while free and self.queue:
             r = self.queue.popleft()
             if self._expired(r):
@@ -1060,87 +968,82 @@ class ServeEngine:
             if r.out:  # resumed mid-stream: rebuild, emit nothing
                 self._admit_replay(r, s)
                 continue
-            b = self.aot_buckets.bucket_for(len(r.prompt))
+            b = self.aot_buckets.bucket_for(len(r.prompt)) if pads else None
             if b is None:
-                # longer than every bucket: exact-length compile, counted
-                self.stats["aot_fallbacks"] += 1
-                self._admit_one(r, s)
+                if pads:  # longer than every bucket: counted
+                    self.stats["aot_fallbacks"] += 1
+                self._admit_group([(r, s)], len(r.prompt), bucketed=False)
                 continue
-            packed.append((r, s, b))
-        by_bucket: dict[int, list] = {}
-        for r, s, b in packed:
             by_bucket.setdefault(b, []).append((r, s))
         for b in sorted(by_bucket):
             group = by_bucket[b]
             while group:
-                pk = 1
-                for cand in self._pack_sizes:
-                    if cand <= len(group):
-                        pk = cand
+                pk = max(c for c in self._pack_sizes if c <= len(group))
                 sub, group = group[:pk], group[pk:]
-                self._admit_packed(sub, b)
+                self._admit_group(sub, b, bucketed=True)
 
-    def _admit_packed(self, sub: list, bucket: int) -> None:
-        """One padded prefill dispatch admitting ``len(sub)`` requests."""
-        with self._spans.span("engine.prefill", bucket=bucket, pack=len(sub),
+    def _admit_group(self, sub: list, length: int, bucketed: bool) -> None:
+        """One admission dispatch for ``sub`` ((request, slot), ...), every
+        prompt right-padded to ``length``; a ``bucketed`` group runs the
+        warmed bucket program. The first tokens come down in one transfer,
+        or ride the async pipeline."""
+        pk = len(sub)
+        with self._spans.span("engine.prefill", bucket=length, pack=pk,
                               rids=tuple(r.rid for r, _s in sub)):
-            vals = self._prefill_packed(sub, bucket)
+            prompts = np.zeros((pk, length), np.int32)
+            lens = np.zeros(pk, np.int32)
+            slot_ix = np.zeros(pk, np.int32)
+            for i, (r, s) in enumerate(sub):
+                n = len(r.prompt)
+                prompts[i, :n] = r.prompt
+                lens[i] = n
+                slot_ix[i] = s
+            fn = (self._admit_fn(length, pk) if bucketed
+                  else self._admit_jit(pk))
+            args = (jnp.asarray(prompts), jnp.asarray(lens),
+                    jnp.asarray(slot_ix))
+            if self.mesh is not None:
+                # AOT executables pin input shardings: host-built admission
+                # arrays must arrive committed-replicated like the lowering
+                # saw
+                rep = shlib.replicated(self.mesh)
+                args = tuple(jax.device_put(a, rep) for a in args)
+            t = self.clock()
+            for r, _s in sub:
+                r.admitted_at = t
+            with self._ctx():
+                (firsts, self.caches, self._tok_dev, self._pos_dev,
+                 self._live_dev) = fn(
+                    self.params, *args, self.caches, self._tok_dev,
+                    self._pos_dev, self._live_dev, library=self.library)
+            self.stats["admit_dispatches"] += 1
+            if bucketed:
+                self.stats["packed_admits"] += 1
+                self.stats["packed_requests"] += pk
+            for r, s in sub:
+                self.req[s] = r
+                self.pos[s] = len(r.prompt)
+                self._emitted[s] = 1
+            if self.pipeline is None:
+                with self._spans.span("engine.sync") as sync:
+                    vals = np.asarray(jax.device_get(firsts)).reshape(-1)
+            else:
+                # first-token download + journal emits happen on the
+                # worker, in order with every other journal write
+                self.pipeline.emit_admit(
+                    tuple((i, r) for i, (r, _s) in enumerate(sub)), firsts)
         self._firsts_now.extend(r for r, _s in sub)
-        if vals is None:
+        if self.pipeline is not None:
             return
         for i, (r, s) in enumerate(sub):
             tok = int(vals[i])
+            r.first_token_at = sync.span.t1
             r.out.append(tok)
             self.cur[s] = tok
             if self.journal is not None:
                 self.journal.emit(r.rid, [tok])
         if self.journal is not None:
             crashpoint("serve.admit.emitted")
-
-    def _prefill_packed(self, sub: list, bucket: int):
-        """The dispatch of :meth:`_admit_packed`; returns the first tokens
-        (P,), or None where the async pipeline downloads them."""
-        pk = len(sub)
-        prompts = np.zeros((pk, bucket), np.int32)
-        lens = np.zeros(pk, np.int32)
-        slot_ix = np.zeros(pk, np.int32)
-        for i, (r, s) in enumerate(sub):
-            n = len(r.prompt)
-            prompts[i, :n] = r.prompt
-            lens[i] = n
-            slot_ix[i] = s
-        fn = self._packed_fn(bucket, pk)
-        args = (jnp.asarray(prompts), jnp.asarray(lens),
-                jnp.asarray(slot_ix))
-        if self.mesh is not None:
-            # AOT executables pin input shardings: host-built admission
-            # arrays must arrive committed-replicated like the lowering saw
-            rep = shlib.replicated(self.mesh)
-            args = tuple(jax.device_put(a, rep) for a in args)
-        t = self.clock()
-        for r, _s in sub:
-            r.admitted_at = t
-        with self._ctx():
-            (firsts, self.caches, self._tok_dev, self._pos_dev,
-             self._live_dev) = fn(
-                self.params, *args, self.caches, self._tok_dev,
-                self._pos_dev, self._live_dev, library=self.library)
-        self.stats["admit_dispatches"] += 1
-        self.stats["packed_admits"] += 1
-        self.stats["packed_requests"] += pk
-        for i, (r, s) in enumerate(sub):
-            self.req[s] = r
-            self.pos[s] = len(r.prompt)
-            self._emitted[s] = 1
-        if self.pipeline is not None:
-            self.pipeline.emit_admit(
-                tuple((i, r) for i, (r, _s) in enumerate(sub)), firsts)
-            return None
-        with self._spans.span("engine.sync") as sync:
-            vals = np.asarray(jax.device_get(firsts)).reshape(-1)
-        for r, _s in sub:
-            r.first_token_at = sync.span.t1
-        return vals
 
     def _admit_replay(self, r: Request, s: int):
         """Re-admit a journal-recovered in-flight request at its recorded
@@ -1151,18 +1054,16 @@ class ServeEngine:
         per-slot independence the solo-oracle tests pin makes the B=1
         rebuild equal to the original pooled decode). Nothing is re-emitted
         and nothing is re-journaled."""
-        prefill = self._prefill_fnum if self.fused else self._prefill1
-        decode = self._decode_fnum if self.fused else self._decode
         r.admitted_at = self.clock()
         with self._ctx():
-            _logits, cache1, _ = prefill(self.params, r.prompt[None, :],
-                                         library=self.library)
+            _logits, cache1, _ = self._prefill(self.params, r.prompt[None, :],
+                                               library=self.library)
             start = len(r.prompt)
             for i, t in enumerate(r.out[:-1]):
                 tok1 = jnp.asarray([[t]], jnp.int32)
                 pos1 = jnp.asarray([start + i], jnp.int32)
-                _logits, cache1 = decode(self.params, tok1, pos1, cache1,
-                                         library=self.library)
+                _logits, cache1 = self._decode(self.params, tok1, pos1,
+                                               cache1, library=self.library)
                 self.stats["resume_replay_steps"] += 1
             self.caches = self._splice(self.caches, cache1, s)
         self.req[s] = r
@@ -1321,10 +1222,10 @@ class ServeEngine:
             self._retire()
 
     def _step_serial(self):
-        """The ISSUE-3/4 per-op tick: token upload, one decode dispatch, a
-        host argmax round-trip — kept as the fused path's oracle/baseline.
-        The watchdog sentinel rides the argmax program: same dispatch and
-        transfer budget as the bare argmax it replaced."""
+        """The serial rung's per-op tick: token upload, one decode
+        dispatch, a host argmax round-trip. The watchdog sentinel rides the
+        argmax program: same dispatch and transfer budget as a bare
+        argmax."""
         toks = jnp.asarray(np.maximum(self.cur, 0)[:, None], jnp.int32)
         pos = jnp.asarray(self.pos, jnp.int32)
         self.stats["transfers"] += 2  # token + position upload

@@ -109,6 +109,29 @@ def test_mixed_bucket_admission_matches_solo_oracle(setup):
         assert got[i] == solo[0], f"request {i} (len {len(p)}) diverged"
 
 
+@pytest.mark.parametrize("arch", ["mamba2_130m", "deepseek_moe_16b"])
+def test_unpackable_config_admits_each_request_at_its_own_length(arch):
+    """A config that cannot be padded (cumulative SSM state; MoE capacity
+    that grows with the padded length) ignores the bucket table at
+    admission: one own-length dispatch per request, nothing packed or
+    counted as a fallback, and the streams of one-request-at-a-time
+    engines."""
+    cfg = get_smoke_config(arch)
+    assert not tf.packable(cfg)
+    params = tf.init_params(jax.random.key(0), cfg)
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 11, 3, 7)]
+    got, eng = _serve(cfg, params, prompts, slots=2, aot_buckets=(8, 16))
+    assert eng.stats["packed_admits"] == 0
+    assert eng.stats["packed_requests"] == 0
+    assert eng.stats["aot_fallbacks"] == 0
+    assert eng.stats["admit_dispatches"] == len(prompts)
+    for i, p in enumerate(prompts):
+        solo, _ = _serve(cfg, params, [p], slots=1)
+        assert got[i] == solo[0], f"request {i} (len {len(p)}) diverged"
+
+
 def test_warm_engine_steady_state_has_zero_misses(setup):
     cfg, params = setup
     rng = np.random.default_rng(6)

@@ -21,30 +21,37 @@ from repro.serve.engine import Request, ServeEngine
 MAX_NEW = 5
 
 
-def _solo_decode(cfg, params, rid, prompt, cache_len):
-    eng = ServeEngine(cfg, params, slots=1, cache_len=cache_len)
+def _solo_decode(cfg, params, rid, prompt, cache_len, fused=True):
+    eng = ServeEngine(cfg, params, slots=1, cache_len=cache_len, fused=fused)
     eng.submit(Request(rid, prompt, max_new=MAX_NEW))
     (done,) = eng.run()
     return done.out
 
 
-@pytest.mark.parametrize("arch", ["yi_6b", "minicpm3_4b"])
-def test_mixed_length_batching_matches_one_at_a_time(arch):
+@pytest.mark.parametrize("arch,fused", [
+    pytest.param("yi_6b", True, id="yi_6b"),
+    pytest.param("minicpm3_4b", True, id="minicpm3_4b"),
+    pytest.param("yi_6b", False, id="yi_6b-serial"),
+    pytest.param("minicpm3_4b", False, id="minicpm3_4b-serial"),
+])
+def test_mixed_length_batching_matches_one_at_a_time(arch, fused):
     """Two slots, three requests of different prompt lengths: admission at
     staggered positions (the third request lands in a freed slot while the
-    other slot is mid-decode at a higher position)."""
+    other slot is mid-decode at a higher position). A serial engine admits
+    through the same admission program as a fused one."""
     cfg = get_smoke_config(arch)
     params = tf.init_params(jax.random.key(0), cfg)
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (5, 11, 3)]
-    eng = ServeEngine(cfg, params, slots=2, cache_len=48)
+    eng = ServeEngine(cfg, params, slots=2, cache_len=48, fused=fused)
     for i, p in enumerate(prompts):
         eng.submit(Request(i, p, max_new=MAX_NEW))
     done = {r.rid: r.out for r in eng.run()}
     assert set(done) == {0, 1, 2}
+    assert eng.stats["admit_dispatches"] == len(prompts)
     for i, p in enumerate(prompts):
-        ref = _solo_decode(cfg, params, i, p, cache_len=48)
+        ref = _solo_decode(cfg, params, i, p, cache_len=48, fused=fused)
         assert done[i] == ref, f"request {i} (len {len(p)}) diverged"
 
 
